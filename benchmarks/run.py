@@ -52,6 +52,9 @@ def main() -> None:
     ap.add_argument("--json", default=None, help="also dump rows as JSONL")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (  # deferred: jax import cost
         bench_completion,
         bench_controlplane,

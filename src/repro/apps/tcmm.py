@@ -14,8 +14,9 @@ Two jobs, exactly as the paper wires them (§4.1):
 
 The nearest-micro-cluster search is the measured hot spot ("the
 micro-clusters size grows over time and decelerates the
-micro-clustering") — it runs on the ``tcmm_assign`` Pallas kernel
-(interpret on CPU, native on TPU) or its jnp oracle.
+micro-clustering") — it runs on the compiled ``tcmm_assign`` Pallas
+kernel on a TPU and on its jnp oracle elsewhere
+(``repro.kernels.platform.compiled_kernels``).
 
 Micro-cluster state is a cluster-feature vector (n, linear sum, square
 sum) per cluster: associative and mergeable, so restarts reconstruct it
@@ -33,6 +34,8 @@ import numpy as np
 
 from repro.configs.tcmm import TCMMConfig
 from repro.core.messages import Message
+from repro.kernels.platform import compiled_kernels
+from repro.kernels.tcmm_assign.ops import tcmm_assign
 from repro.kernels.tcmm_assign.ref import tcmm_assign_ref
 
 
@@ -77,24 +80,17 @@ class MicroClusterState:
             self.ss[i] += float(p @ p)
         self.processed += 1
 
-    def ingest(self, point: np.ndarray, use_pallas: bool = False) -> Dict[str, Any]:
+    def ingest(self, point: np.ndarray) -> Dict[str, Any]:
         """Assign a point; returns the change event (already applied)."""
         if self.num_active == 0:
             ev = {"kind": "new", "cluster": 0, "point": point.tolist()}
             self.apply_event(ev)
             return ev
-        if use_pallas:
-            from repro.kernels.tcmm_assign.ops import tcmm_assign
-
-            idx, d2 = tcmm_assign(
-                jnp.asarray(point[None]), jnp.asarray(self.centroids()),
-                jnp.asarray(self.valid()), interpret=True,
-            )
-        else:
-            idx, d2 = tcmm_assign_ref(
-                jnp.asarray(point[None]), jnp.asarray(self.centroids()),
-                jnp.asarray(self.valid()),
-            )
+        assign = tcmm_assign if compiled_kernels() else tcmm_assign_ref
+        idx, d2 = assign(
+            jnp.asarray(point[None]), jnp.asarray(self.centroids()),
+            jnp.asarray(self.valid()),
+        )
         i, dist2 = int(idx[0]), float(d2[0])
         if dist2 <= self.cfg.distance_threshold ** 2:
             ev = {"kind": "merge", "cluster": i, "point": point.tolist()}
@@ -118,13 +114,12 @@ class MicroClusterJob:
     [change event payloads]. Stateful; state is event-sourced by design
     (its outputs ARE its change log)."""
 
-    def __init__(self, cfg: TCMMConfig, use_pallas: bool = False) -> None:
+    def __init__(self, cfg: TCMMConfig) -> None:
         self.state = MicroClusterState(cfg)
-        self.use_pallas = use_pallas
 
     def __call__(self, msg: Message) -> List[Any]:
         point = np.asarray(msg.payload, dtype=np.float32)
-        return [self.state.ingest(point, use_pallas=self.use_pallas)]
+        return [self.state.ingest(point)]
 
 
 def kmeans(

@@ -23,7 +23,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -90,8 +89,8 @@ def ring_all_reduce(
         return _ring_all_gather(reduced, axis_name)
 
     spec = P(*([None] * x.ndim))
-    return shard_map(
-        body, mesh=mesh, in_specs=spec, out_specs=spec, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )(x)
 
 

@@ -5,7 +5,9 @@ Each kernel package has:
   ops.py    -- jit'd public wrapper (shape checks, dtype policy, interpret flag)
   ref.py    -- pure-jnp oracle used by the allclose test sweeps
 
-This container is CPU-only: kernels are validated in interpret=True mode
-(the kernel body executes in Python per block) against the oracles; the
-dry-run lowers the pure-jnp model path (see DESIGN.md s5).
+The kernels compile for TPU only; ``platform.py`` decides where they run.
+On the CPU the model takes the jnp reference paths, and tests check each
+kernel against its oracle in Pallas's interpreter (``interpret`` passed
+explicitly); ``tests/test_tpu_compile.py`` compiles the served ones for a
+described v5e.
 """

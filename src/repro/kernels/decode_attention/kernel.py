@@ -21,7 +21,11 @@ reserving max_len rows per slot.  The page table and kv_len ride in as
 scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``) so the
 BlockSpec index maps gather the right K/V page for every grid step —
 the gather happens in the DMA schedule, never as a materialized
-``k_pages[page_table]`` copy.  ``paged_kv_append`` writes one new
+``k_pages[page_table]`` copy.  Grid = (B, pages per sequence), and one
+K/V block is a whole page with every kv head, ``(1, page, Hkv, D)``:
+Mosaic only tiles a block whose last two dims are (8, 128)-aligned or
+whole, and a single-head slice ``(1, page, 1, D)`` of the pool is
+neither.  ``paged_kv_append`` writes one new
 token's K/V into its page in place (``input_output_aliases``), so the
 per-tick cache update is O(1) rows, not an O(S) re-materialization.
 The dense kernel above stays the bitwise reference path (the
@@ -149,21 +153,23 @@ def decode_attention_fwd(
 def _paged_decode_kernel(
     pt_ref,      # scalar prefetch [B, n_pages] int32 page table
     kv_len_ref,  # scalar prefetch [B] int32
-    q_ref,       # [1, 1, G, d]
-    k_ref,       # [1, page, 1, d]  (page selected by the index map)
-    v_ref,       # [1, page, 1, d]
-    o_ref,       # [1, 1, G, d]
-    m_ref,       # scratch [G, 1] f32
-    l_ref,       # scratch [G, 1] f32
-    acc_ref,     # scratch [G, d] f32
+    q_ref,       # [1, G, Hkv, d]
+    k_ref,       # [1, page, Hkv, d]  (page selected by the index map)
+    v_ref,       # [1, page, Hkv, d]
+    o_ref,       # [1, G, Hkv, d]
+    m_ref,       # scratch [G, Hkv, 1] f32
+    l_ref,       # scratch [G, Hkv, 1] f32
+    acc_ref,     # scratch [G, Hkv, d] f32
     *,
     sm_scale: float,
     window: int,
     page_size: int,
     kv_steps: int,
+    groups: int,
 ):
+    del pt_ref  # consumed by the index maps
     ib = pl.program_id(0)
-    ik = pl.program_id(2)
+    ik = pl.program_id(1)
 
     @pl.when(ik == 0)
     def _init():
@@ -174,36 +180,36 @@ def _paged_decode_kernel(
     kv_len = kv_len_ref[ib]
 
     # Pages at or past the valid length are fully masked; skip their
-    # flash update entirely (the DMA still lands — the index map clamps
-    # unallocated table entries to a valid page id on the host side).
+    # flash update entirely (the DMA still lands — the wrapper clamps
+    # unallocated table entries to a valid page id).
     @pl.when(ik * page_size < kv_len)
     def _update():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)  # [G, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-        s = jnp.dot(q, k.T) * sm_scale  # [G, page]
-
+        k = k_ref[0].astype(jnp.float32)  # [page, Hkv, d]
+        v = v_ref[0].astype(jnp.float32)
         k_pos = ik * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
+            jnp.int32, (page_size, 1, 1), 0
         )
         mask = k_pos < kv_len
         if window > 0:
             mask = mask & (k_pos > kv_len - 1 - window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(p, v)
-        m_ref[:, 0] = m_cur
+        # One page holds every kv head, so each head's scores are a
+        # lane reduction over d on the VPU (decode is a GEMV per head).
+        for g in range(groups):
+            q = q_ref[0, g].astype(jnp.float32)  # [Hkv, d]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * sm_scale
+            s = jnp.where(mask, s, NEG_INF)  # [page, Hkv, 1]
+            m_prev = m_ref[g]  # [Hkv, 1]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.where(mask, jnp.exp(s - m_cur[None]), 0.0)
+            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
+            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
+            m_ref[g] = m_cur
 
     @pl.when(ik == kv_steps - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-        o_ref[0, 0, :, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_decode_attention_fwd(
@@ -221,7 +227,9 @@ def paged_decode_attention_fwd(
     n_pages = page_table.shape[1]
     g = h // hkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    qg = q.reshape(b, hkv, g, d)
+    # Head h belongs to kv-head h // g: [B, H, d] -> [B, G, Hkv, d], so a
+    # block's last two dims are the pool's own (Hkv, d), as Mosaic needs.
+    qg = q.reshape(b, hkv, g, d).swapaxes(1, 2)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -229,42 +237,44 @@ def paged_decode_attention_fwd(
         window=window,
         page_size=page_size,
         kv_steps=n_pages,
+        groups=g,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_pages),
+        grid=(b, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, h_, ik, pt, kl: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, g, hkv, d), lambda b_, ik, pt, kl: (b_, 0, 0, 0)),
             # The page-table gather: logical page ik of sequence b_ lives
             # in pool page pt[b_, ik] — resolved at DMA-schedule time.
+            # One block carries all kv heads of the page.
             pl.BlockSpec(
-                (1, page_size, 1, d),
-                lambda b_, h_, ik, pt, kl: (pt[b_, ik], 0, h_, 0),
+                (1, page_size, hkv, d),
+                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, 1, d),
-                lambda b_, h_, ik, pt, kl: (pt[b_, ik], 0, h_, 0),
+                (1, page_size, hkv, d),
+                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, g, d), lambda b_, h_, ik, pt, kl: (b_, h_, 0, 0)
+            (1, g, hkv, d), lambda b_, ik, pt, kl: (b_, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, d), jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, hkv, d), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qg,
       k_pages, v_pages)
-    return out.reshape(b, h, d)
+    return out.swapaxes(1, 2).reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ from repro.kernels.decode_attention.kernel import (
     paged_decode_attention_fwd,
     paged_kv_append_fwd,
 )
+from repro.kernels.platform import resolve_interpret
 
 LANE = 128
 
@@ -98,7 +99,7 @@ def decode_attention(
     window: int = 0,
     sm_scale: Optional[float] = None,
     block_k: int = 256,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     if q.ndim != 3:
         raise ValueError("q must be [B, H, D] (one token per sequence)")
@@ -111,21 +112,14 @@ def decode_attention(
     bk = align_block_k(block_k, s)
     return _decode_attention_jit(
         q, k_cache, v_cache, kv_len,
-        window=window, sm_scale=sm_scale, block_k=bk, interpret=interpret,
+        window=window, sm_scale=sm_scale, block_k=bk,
+        interpret=resolve_interpret(interpret),
     )
 
 
 # ---------------------------------------------------------------------------
 # paged wrappers
 # ---------------------------------------------------------------------------
-
-
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    """Paged serving paths run everywhere the suite runs: interpret mode
-    is the CPU fallback, compiled Pallas on TPU."""
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -169,7 +163,7 @@ def paged_decode_attention(
     return _paged_decode_jit(
         q, k_pages, v_pages, page_table, kv_len,
         window=window, sm_scale=sm_scale,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -208,5 +202,5 @@ def paged_kv_append(
     page_table = jnp.clip(page_table, 0, k_pages.shape[0] - 1)
     return _kv_append_jit(
         k_new, v_new, k_pages, v_pages, page_table, pos,
-        interpret=_auto_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )

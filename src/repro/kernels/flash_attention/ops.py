@@ -8,6 +8,7 @@ from typing import Optional
 import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.platform import resolve_interpret
 
 
 @functools.partial(
@@ -27,7 +28,7 @@ def flash_attention(
     q_offset: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
         raise ValueError("q,k,v must be [B, T|S, H|Hkv, D]")
@@ -40,5 +41,5 @@ def flash_attention(
     return flash_attention_fwd(
         q, k, v,
         causal=causal, window=window, sm_scale=sm_scale, q_offset=q_offset,
-        block_q=bq, block_k=bk, interpret=interpret,
+        block_q=bq, block_k=bk, interpret=resolve_interpret(interpret),
     )

@@ -3,12 +3,13 @@
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.moe_gating.kernel import moe_gating_fwd
+from repro.kernels.platform import resolve_interpret
 
 
 @functools.partial(
@@ -19,7 +20,7 @@ def moe_gating(
     top_k: int,
     capacity: int,
     block_n: int = 256,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Returns (expert_idx [N,k] i32, gates [N,k] f32 renormalized,
     capacity positions [N,k] i32, keep [N,k] bool)."""
@@ -31,5 +32,6 @@ def moe_gating(
         bn //= 2
     bn = max(bn, 1)
     return moe_gating_fwd(
-        logits, top_k=top_k, capacity=capacity, block_n=bn, interpret=interpret
+        logits, top_k=top_k, capacity=capacity, block_n=bn,
+        interpret=resolve_interpret(interpret),
     )

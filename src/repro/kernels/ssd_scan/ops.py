@@ -8,6 +8,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.ssd_scan.kernel import ssd_chunked_fwd
 
 
@@ -19,12 +20,14 @@ def ssd_chunked(
     C: jax.Array,  # [B, T, N]
     chunk: int,
     initial_state: Optional[jax.Array] = None,  # [B, H, N, P]
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (y [B,T,H,P] f32, final_state [B,H,N,P] f32)."""
     if x.shape[1] % chunk != 0:
         raise ValueError(f"T={x.shape[1]} must be a multiple of chunk={chunk}")
-    y, final = ssd_chunked_fwd(x, a, B, C, chunk, interpret=interpret)
+    y, final = ssd_chunked_fwd(
+        x, a, B, C, chunk, interpret=resolve_interpret(interpret)
+    )
     if initial_state is not None:
         # Fold a nonzero initial state in linearly (the scan is linear in
         # the state): y += C_t * decay_to_t * S0, S_final += decay_T * S0.

@@ -3,11 +3,12 @@
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.tcmm_assign.kernel import tcmm_assign_fwd
 
 
@@ -17,7 +18,7 @@ def tcmm_assign(
     centroids: jax.Array,  # [M, F]
     valid: jax.Array,      # [M] bool
     block_n: int = 512,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     n, f = points.shape
     bn = min(block_n, n)
@@ -25,5 +26,6 @@ def tcmm_assign(
         bn //= 2
     bn = max(bn, 1)
     return tcmm_assign_fwd(
-        points, centroids, valid, block_n=bn, interpret=interpret
+        points, centroids, valid, block_n=bn,
+        interpret=resolve_interpret(interpret),
     )

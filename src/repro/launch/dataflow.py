@@ -39,6 +39,7 @@ from repro.launch.chaos import (
     apply_arrival_flags,
     build_cluster,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_graph(args, cluster=None) -> StageGraph:
@@ -111,6 +112,7 @@ def main(argv=None) -> int:
     ap.add_argument("--spill-dir", default=None)
     ap.add_argument("--max-ticks", type=int, default=100_000)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cluster, engine, injector = build_cluster(args)
     graph = build_graph(args, cluster=cluster)

@@ -15,6 +15,8 @@ Two admission modes:
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
       --requests 32 --slots 4
+  PYTHONPATH=src python -m repro.launch.serve --arch minicpm-2b \
+      --full-size --paged --slots 8 --max-len 1024   # published widths, bf16
   PYTHONPATH=src python -m repro.launch.serve --stub --spike  # fast demo
   PYTHONPATH=src python -m repro.launch.serve --stub --log-backed \
       --kill-replica 0                        # chaos over the log
@@ -46,19 +48,60 @@ import numpy as np
 from repro.config import get_arch
 from repro.core.elastic import AutoscalerConfig
 from repro.launch.chaos import add_chaos_flags, build_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.zoo import build_model
 from repro.serving import ElasticServingPool, Request, ServingJob
 
 
 def build(args):
+    """(model, params, vocab) for the served config.  ``--full-size``
+    serves the published widths in bf16, weights included (MiniCPM-2B's
+    2.7 B params in float32 would take 10.9 GB of a 16 GB v5e); the
+    smoke config stays float32 for the CPU.  Params are made by one
+    jitted init, so the device never holds a second, unstacked copy."""
     if args.stub:
         from repro.models.stub import StubModel
 
         model = StubModel()
         return model, model.init(jax.random.PRNGKey(args.seed)), 90
-    cfg = get_arch(args.arch, smoke=True)
-    model = build_model(cfg, compute_dtype=jnp.float32)
-    return model, model.init(jax.random.PRNGKey(args.seed)), cfg.vocab_size
+    cfg = get_arch(args.arch, smoke=not args.full_size)
+    dtype = jnp.bfloat16 if args.full_size else jnp.float32
+    model = build_model(cfg, compute_dtype=dtype, param_dtype=dtype)
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
+    return model, params, cfg.vocab_size
+
+
+def paged_spec(args):
+    """The replica's page pool for ``--paged`` (None without it)."""
+    if not args.paged:
+        return None
+    from repro.models.layers import PagedSpec
+
+    per_slot = -(-args.max_len // args.page_size)
+    return PagedSpec(num_pages=args.pages or 1 + args.slots * per_slot,
+                     page_size=args.page_size)
+
+
+def pool_kwargs(args, cluster=None) -> dict:
+    """``ElasticServingPool`` / ``ServingJob`` keyword arguments from the
+    command line."""
+    return dict(
+        paged=paged_spec(args),
+        admission=args.admission,
+        cluster=cluster,
+        restart_cost=(args.restart_cost if cluster is not None else 0.0),
+        slots_per_replica=args.slots,
+        max_len=args.max_len,
+        temperature=args.temperature,
+        max_replicas=args.max_replicas,
+        initial_units=1 if args.spike else args.slots,
+        ingress_capacity=args.ingress_capacity,
+        overflow=args.overflow,
+        policy=args.policy,
+        autoscaler=AutoscalerConfig(high_watermark=4.0, low_watermark=0.5,
+                                    cooldown=0.0, step_fraction=1.0),
+        heartbeat_timeout=5.0,
+    )
 
 
 def run_fleet(args) -> int:
@@ -117,9 +160,12 @@ def run_fleet(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
+    ap.add_argument("--full-size", action="store_true",
+                    help="published config in bf16 (default: smoke config, "
+                         "float32, CPU-sized)")
     ap.add_argument("--stub", action="store_true",
                     help="arithmetic stub model (no weights, instant)")
     ap.add_argument("--requests", type=int, default=32)
@@ -180,46 +226,28 @@ def main(argv=None) -> int:
                          "or static per-tenant partitions (A/B baseline)")
     add_chaos_flags(ap, fail_interval=15.0, fail_restart=8.0)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = make_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.tenants:
         return run_fleet(args)
 
     cluster, engine, injector = build_cluster(args)
     model, params, vocab = build(args)
-    paged = None
-    if args.paged:
-        from repro.models.layers import PagedSpec
-
-        pages = args.pages or (
-            1 + args.slots * (-(-args.max_len // args.page_size))
-        )
-        paged = PagedSpec(num_pages=pages, page_size=args.page_size)
-    pool_kwargs = dict(
-        paged=paged,
-        admission=args.admission,
-        cluster=cluster,
-        restart_cost=(args.restart_cost if cluster is not None else 0.0),
-        slots_per_replica=args.slots,
-        max_len=args.max_len,
-        temperature=args.temperature,
-        max_replicas=args.max_replicas,
-        initial_units=1 if args.spike else args.slots,
-        ingress_capacity=args.ingress_capacity,
-        overflow=args.overflow,
-        policy=args.policy,
-        autoscaler=AutoscalerConfig(high_watermark=4.0, low_watermark=0.5,
-                                    cooldown=0.0, step_fraction=1.0),
-        heartbeat_timeout=5.0,
-    )
+    kwargs = pool_kwargs(args, cluster)
+    paged = kwargs["paged"]
     if args.log_backed:
         job = ServingJob(model, params, spill_dir=args.spill_dir,
                          partitions=args.partitions,
-                         split_prefill=args.split_prefill, **pool_kwargs)
+                         split_prefill=args.split_prefill, **kwargs)
         pool = job.pool
     else:
         job = None
-        pool = ElasticServingPool(model, params, **pool_kwargs)
+        pool = ElasticServingPool(model, params, **kwargs)
 
     rng = np.random.default_rng(args.seed)
 

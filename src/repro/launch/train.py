@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.config import TrainingConfig, get_arch
 from repro.core.elastic import AutoscalerConfig
 from repro.data.pipeline import build_token_log
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.zoo import build_model
 from repro.telemetry.metrics import MetricsHub
 from repro.training.job import TrainingJob
@@ -111,6 +112,7 @@ def main(argv=None) -> int:
     ap.add_argument("--queues", type=int, default=None, help=argparse.SUPPRESS)
     ap.add_argument("--scheduler", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch, smoke=not args.full_size)
     tcfg = TrainingConfig(

@@ -6,8 +6,10 @@ Pure functions over param pytrees.  Activations are annotated with
 device, resolved to physical mesh axes by the launcher's rule set.
 
 Dtype policy: params are created in ``param_dtype``; compute runs in
-``compute_dtype`` (bf16 on TPU); softmax/normalization statistics and the
-final logits are fp32.
+``compute_dtype`` (bf16 on TPU): each weight is cast to the activation's
+dtype where the two meet, so the residual stream and the KV caches stay
+in the compute dtype whatever the params are held in.  Softmax /
+normalization statistics and the final logits are fp32.
 """
 
 from __future__ import annotations
@@ -21,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.config.base import ArchConfig, AttentionKind, LayerSpec
 from repro.distributed.sharding import shard
+from repro.kernels import platform
 from repro.kernels.decode_attention import (
     gather_pages,
     paged_decode_attention,
@@ -165,7 +168,6 @@ def attention(
     spec: LayerSpec,
     cache: Optional[Params] = None,  # {"k","v": [B, Tkv, Hkv, hd], "pos": [B]}
     kv_x: Optional[jax.Array] = None,  # cross-attention source [B, Tkv, D]
-    use_pallas: bool = False,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """GQA attention with optional sliding window, KV cache, cross-attn.
 
@@ -177,11 +179,12 @@ def attention(
     b, tq, _ = x.shape
     cross = spec.attention == AttentionKind.CROSS and kv_x is not None
 
-    q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
+    dt = x.dtype
+    q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(dt))
     q = shard(q, "batch", "seq_inner", "heads", "head_dim")
     src = kv_x if cross else x
-    k = jnp.einsum("btd,dhk->bthk", src, params["wk"])
-    v = jnp.einsum("btd,dhk->bthk", src, params["wv"])
+    k = jnp.einsum("btd,dhk->bthk", src, params["wk"].astype(dt))
+    v = jnp.einsum("btd,dhk->bthk", src, params["wv"].astype(dt))
     k = shard(k, "batch", "seq_inner", "kv_heads", "kv_head_dim")
     v = shard(v, "batch", "seq_inner", "kv_heads", "kv_head_dim")
 
@@ -194,11 +197,9 @@ def attention(
         # Paged KV cache (serving hot path): K/V live in a shared page
         # pool indexed through per-slot page tables; the dense [B, S]
         # cache is never materialized on the decode fast path.
-        out, new_cache = _paged_attention(
-            q, k, v, positions, cfg, spec, cache, use_pallas
-        )
+        out, new_cache = _paged_attention(q, k, v, positions, cfg, spec, cache)
         out = out.reshape(b, tq, h, hd)
-        y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
+        y = jnp.einsum("bthk,hkd->btd", out, params["wo"].astype(dt))
         return shard(y, "batch", "seq_inner", "embed"), new_cache
     if cache is not None and not cross and "slot_pos" in cache:
         # Ring-buffer cache (sliding-window layers): W slots, token at
@@ -282,7 +283,7 @@ def attention(
             qg, k, v, positions, kv_pos, valid, cfg, window, causal
         )
     out = out.reshape(b, tq, h, hd)
-    y = jnp.einsum("bthk,hkd->btd", out, params["wo"])
+    y = jnp.einsum("bthk,hkd->btd", out, params["wo"].astype(dt))
     return shard(y, "batch", "seq_inner", "embed"), new_cache
 
 
@@ -383,16 +384,16 @@ def _paged_attention(
     cfg: ArchConfig,
     spec: LayerSpec,
     cache: Params,
-    use_pallas: bool,
 ) -> Tuple[jax.Array, Params]:
     """Attention against a paged KV cache.
 
-    Decode (Tq == 1) with ``use_pallas`` runs the fused Pallas path:
-    in-place kv-append into the page the slot's table points at, then
+    Decode (Tq == 1) on a TPU runs the fused Pallas path: in-place
+    kv-append into the page the slot's table points at, then
     flash-decoding whose KV gather follows the page table inside the
-    kernel's DMA schedule.  Prefill (Tq > 1), and models with a logit
-    softcap (the kernel does not implement it), scatter into the pool
-    and attend over the gathered dense view — the reference semantics.
+    kernel's DMA schedule.  Prefill (Tq > 1), decode on a backend without
+    compiled kernels, and models with a logit softcap (the kernel does
+    not implement it) scatter into the pool and attend over the gathered
+    dense view — the reference semantics.
     """
     b, tq, h, hd = q.shape
     hkv = k.shape[2]
@@ -404,7 +405,7 @@ def _paged_attention(
     window = spec.window if spec.attention == AttentionKind.SLIDING else 0
     kv_len = cache_pos + tq
 
-    if tq == 1 and use_pallas and cfg.logit_softcap == 0:
+    if tq == 1 and platform.compiled_kernels() and cfg.logit_softcap == 0:
         k_pages, v_pages = paged_kv_append(
             k[:, 0], v[:, 0], k_pages, v_pages, page_table, cache_pos
         )
@@ -414,7 +415,7 @@ def _paged_attention(
         out = out[:, None].astype(v.dtype)  # [B, 1, H, hd]
     else:
         # Scatter the chunk through the page tables (prefill, or the
-        # softcap / non-pallas fallback), then attend over the gathered
+        # softcap / jnp decode path), then attend over the gathered
         # dense view of each slot's pages.
         rows = jnp.arange(b)
         pos_bt = cache_pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
@@ -497,11 +498,12 @@ def init_mlp(rng: jax.Array, cfg: ArchConfig, dtype) -> Params:
 
 
 def mlp(params: Params, x: jax.Array) -> jax.Array:
-    gate = jnp.einsum("btd,df->btf", x, params["w_gate"])
-    up = jnp.einsum("btd,df->btf", x, params["w_up"])
+    dt = x.dtype
+    gate = jnp.einsum("btd,df->btf", x, params["w_gate"].astype(dt))
+    up = jnp.einsum("btd,df->btf", x, params["w_up"].astype(dt))
     h = jax.nn.silu(gate) * up
     h = shard(h, "batch", "seq_inner", "ffn")
-    return jnp.einsum("btf,fd->btd", h, params["w_down"])
+    return jnp.einsum("btf,fd->btd", h, params["w_down"].astype(dt))
 
 
 # ---------------------------------------------------------------------------
@@ -526,16 +528,17 @@ def embed(params: Params, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
 
 
 def unembed(params: Params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
-    # native-dtype operands, f32 accumulation: upcasting the embedding
+    # compute-dtype operands, f32 accumulation: upcasting the embedding
     # table would materialize an f32 copy of the largest matrix in the
     # model (gemma3: 262k x 2560).
     if cfg.tie_embeddings:
         logits = jnp.einsum(
-            "btd,vd->btv", x, params["tok"], preferred_element_type=jnp.float32
+            "btd,vd->btv", x, params["tok"].astype(x.dtype),
+            preferred_element_type=jnp.float32,
         )
     else:
         logits = jnp.einsum(
-            "btd,dv->btv", x, params["unembed"],
+            "btd,dv->btv", x, params["unembed"].astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
     if cfg.logit_softcap > 0:

@@ -188,7 +188,6 @@ def mamba_block(
     u: jax.Array,  # [B, T, D]
     cfg: ArchConfig,
     cache: Optional[Params] = None,
-    use_pallas: bool = False,
 ) -> Tuple[jax.Array, Optional[Params]]:
     """Full Mamba-2 block. cache = {"conv": [B,K-1,C], "ssm": [B,H,N,P]}."""
     m = cfg.mamba
@@ -196,10 +195,14 @@ def mamba_block(
     d_in, h, p, n = _dims(cfg)
     bsz, t, _ = u.shape
 
-    proj = jnp.einsum("btd,de->bte", u, params["in_proj"])
+    cdt = u.dtype  # weights meet activations in the compute dtype
+    proj = jnp.einsum("btd,de->bte", u, params["in_proj"].astype(cdt))
     z, xbc, dt = _split_proj(cfg, proj)
     conv_state = cache["conv"] if cache is not None else None
-    xbc, new_conv = _causal_conv(xbc, params["conv_w"], params["conv_b"], conv_state)
+    xbc, new_conv = _causal_conv(
+        xbc, params["conv_w"].astype(cdt), params["conv_b"].astype(cdt),
+        conv_state,
+    )
     x, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
     x = x.reshape(bsz, t, h, p)
     x = shard(x, "batch", "seq_inner", "mamba_heads", None)
@@ -231,14 +234,11 @@ def mamba_block(
             a_c = jnp.pad(a, ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
             B_c = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
             C_c = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-        if use_pallas:
-            from repro.kernels.ssd_scan.ops import ssd_chunked
-
-            y, final_state = ssd_chunked(x_c, a_c, B_c, C_c, m.chunk_size, ssm_state)
-        else:
-            y, final_state = ssd_chunked_ref(
-                x_c, a_c, B_c, C_c, m.chunk_size, ssm_state
-            )
+        # The jnp path on every backend: kernels/ssd_scan does not lower
+        # for TPU yet (ROADMAP B2).
+        y, final_state = ssd_chunked_ref(
+            x_c, a_c, B_c, C_c, m.chunk_size, ssm_state
+        )
         if pad:
             y = y[:, :t]
 
@@ -249,7 +249,7 @@ def mamba_block(
     var = jnp.mean(jnp.square(y.astype(jnp.float32)), axis=-1, keepdims=True)
     y = (y.astype(jnp.float32) * jax.lax.rsqrt(var + 1e-6)).astype(u.dtype)
     y = y * (1.0 + params["norm_w"].astype(u.dtype))
-    out = jnp.einsum("bte,ed->btd", y, params["out_proj"])
+    out = jnp.einsum("bte,ed->btd", y, params["out_proj"].astype(cdt))
 
     new_cache = None
     if cache is not None:

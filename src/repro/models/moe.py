@@ -135,11 +135,15 @@ def moe_ffn_scatter(
     expert_in = buffers[: e * cap].reshape(e, cap, d)
     expert_in = shard(expert_in, "expert", "capacity", "embed")
 
-    gate = jnp.einsum("ecd,edf->ecf", expert_in, params["w_gate"])
-    up = jnp.einsum("ecd,edf->ecf", expert_in, params["w_up"])
+    gate = jnp.einsum(
+        "ecd,edf->ecf", expert_in, params["w_gate"].astype(expert_in.dtype)
+    )
+    up = jnp.einsum(
+        "ecd,edf->ecf", expert_in, params["w_up"].astype(expert_in.dtype)
+    )
     h = jax.nn.silu(gate) * up
     h = shard(h, "expert", "capacity", "expert_ffn")
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
+    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(h.dtype))
     expert_out = shard(expert_out, "expert", "capacity", "embed")
 
     # gather back and combine
@@ -201,11 +205,15 @@ def moe_ffn(
     # all-to-all happens here under EP sharding
     expert_in = jnp.einsum("nec,nd->ecd", disp, xf)
     expert_in = shard(expert_in, "expert", "capacity", "embed")
-    gate = jnp.einsum("ecd,edf->ecf", expert_in, params["w_gate"])
-    up = jnp.einsum("ecd,edf->ecf", expert_in, params["w_up"])
+    gate = jnp.einsum(
+        "ecd,edf->ecf", expert_in, params["w_gate"].astype(expert_in.dtype)
+    )
+    up = jnp.einsum(
+        "ecd,edf->ecf", expert_in, params["w_up"].astype(expert_in.dtype)
+    )
     h = jax.nn.silu(gate) * up
     h = shard(h, "expert", "capacity", "expert_ffn")
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"])
+    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(h.dtype))
     expert_out = shard(expert_out, "expert", "capacity", "embed")
 
     y = jnp.einsum("nec,ecd->nd", combine.astype(expert_out.dtype), expert_out)

@@ -92,7 +92,6 @@ def apply_block(
     cfg: ArchConfig,
     cache: Optional[Params],
     enc_out: Optional[jax.Array],
-    use_pallas: bool,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
     """Returns (x', cache', aux_loss)."""
     aux = jnp.zeros((), dtype=jnp.float32)
@@ -104,7 +103,6 @@ def apply_block(
         y, mc = M.mamba_block(
             params["mamba"], h, cfg,
             cache=cache.get("mamba") if cache else None,
-            use_pallas=use_pallas,
         )
         x = x + rs * y
         new_cache = {"mamba": mc} if mc is not None else None
@@ -118,7 +116,7 @@ def apply_block(
         )
         y, ac = L.attention(
             params["attn"], h, positions, cfg, self_spec,
-            cache=attn_cache, use_pallas=use_pallas,
+            cache=attn_cache,
         )
         if cfg.parallel_block:
             # command-r style: attn and FFN both read the same normed input.
@@ -132,7 +130,7 @@ def apply_block(
             h = L.rms_norm(x, params["norm_cross"], cfg.norm_eps)
             y, _ = L.attention(
                 params["cross"], h, positions, cfg, spec,
-                kv_x=enc_out, use_pallas=use_pallas,
+                kv_x=enc_out,
             )
             x = x + rs * y
 
@@ -171,10 +169,9 @@ def init_params(rng: jax.Array, cfg: ArchConfig, dtype=jnp.float32) -> Params:
                 return init_block(r, cfg, spec, dtype)
 
             ks = jax.random.split(jax.random.fold_in(keys[1], pos), n_periods)
-            stacked = jax.tree.map(
-                lambda *leaves: jnp.stack(leaves), *[init_one(k) for k in ks]
-            )
-            period_params.append(stacked)
+            # vmap, not a Python loop + stack: one batched init per
+            # pattern position keeps a jitted init's program O(pattern)
+            period_params.append(jax.vmap(init_one)(ks))
         params["periods"] = period_params
     if remainder > 0:
         params["remainder"] = [
@@ -238,8 +235,7 @@ def init_cache(
     return cache
 
 
-def _encode(params: Params, cfg: ArchConfig, frames: jax.Array,
-            use_pallas: bool) -> jax.Array:
+def _encode(params: Params, cfg: ArchConfig, frames: jax.Array) -> jax.Array:
     """Bidirectional encoder over stubbed frame embeddings [B, S_enc, D]."""
     b, s, _ = frames.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -257,7 +253,7 @@ def _encode(params: Params, cfg: ArchConfig, frames: jax.Array,
         h = L.rms_norm(x, layer_params["norm_attn"], cfg.norm_eps)
         y, _ = L.attention(
             layer_params["attn"], h, positions, cfg, enc_spec,
-            kv_x=h, use_pallas=use_pallas,
+            kv_x=h,
         )
         x = x + y
         h = L.rms_norm(x, layer_params["norm_ffn"], cfg.norm_eps)
@@ -275,7 +271,6 @@ def forward(
     cache: Optional[Params] = None,
     frontend: Optional[jax.Array] = None,  # [B, F, D] patch/frame embeds
     start_pos: Optional[jax.Array] = None,  # [B] decode positions
-    use_pallas: bool = False,
     compute_dtype=jnp.bfloat16,
     logits_positions: str = "all",  # "all" | "last"
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
@@ -285,9 +280,8 @@ def forward(
     Decode: T==1 with a populated cache and start_pos.
     ``logits_positions="last"`` unembeds only the final position — the
     serving-prefill path. This is not a micro-optimization: unembedding
-    (and replicating) 32k positions x a 100k+ vocab was the dominant
-    collective in every prefill cell of the baseline roofline table
-    (EXPERIMENTS.md §Perf cell A).
+    (and replicating) 32k positions x a 100k+ vocab would be the largest
+    tensor and the largest collective of a long prefill.
     """
     n_periods, remainder = _period_counts(cfg)
     b, t = tokens.shape
@@ -297,7 +291,7 @@ def forward(
     enc_out = None
     n_front = 0
     if cfg.encoder_layers > 0 and frontend is not None:
-        enc_out = _encode(params, cfg, frontend.astype(compute_dtype), use_pallas)
+        enc_out = _encode(params, cfg, frontend.astype(compute_dtype))
     elif frontend is not None and cfg.frontend_tokens > 0 and cache is None:
         # VLM: prepend patch embeddings as prefix tokens (train/prefill only;
         # during decode they already live in the cache).
@@ -329,7 +323,7 @@ def forward(
                 cache_entry = None if layer_cs is None else layer_cs[pos]
                 x, nc, a = apply_block(
                     layer_ps[pos], spec, x, positions, cfg,
-                    cache_entry, enc_out, use_pallas,
+                    cache_entry, enc_out,
                 )
                 aux = aux + a
                 new_cs.append(nc)
@@ -346,7 +340,7 @@ def forward(
                 for pos, spec in enumerate(cfg.pattern):
                     x, _, a = apply_block(
                         layer_ps[pos], spec, x, positions, cfg,
-                        None, enc_out, use_pallas,
+                        None, enc_out,
                     )
                     aux = aux + a
                 return (x, aux), None
@@ -367,7 +361,7 @@ def forward(
             spec = cfg.layer_spec(base + i)
             x, nc, a = apply_block(
                 params["remainder"][i], spec, x, positions, cfg,
-                rem_caches[i], enc_out, use_pallas,
+                rem_caches[i], enc_out,
             )
             aux_total = aux_total + a
             new_remainder.append(nc)

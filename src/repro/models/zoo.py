@@ -23,7 +23,6 @@ class Model:
     cfg: ArchConfig
     compute_dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    use_pallas: bool = False
 
     # -- params / cache -----------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
@@ -46,7 +45,6 @@ class Model:
             self.cfg,
             batch["tokens"],
             frontend=batch.get("frontend"),
-            use_pallas=self.use_pallas,
             compute_dtype=self.compute_dtype,
         )
         return logits, aux
@@ -65,7 +63,6 @@ class Model:
             cache=cache,
             frontend=batch.get("frontend"),
             start_pos=jnp.zeros((batch["tokens"].shape[0],), dtype=jnp.int32),
-            use_pallas=self.use_pallas,
             compute_dtype=self.compute_dtype,
             logits_positions="last" if last_only else "all",
         )
@@ -86,7 +83,6 @@ class Model:
             cache=cache,
             frontend=frontend,
             start_pos=positions,
-            use_pallas=self.use_pallas,
             compute_dtype=self.compute_dtype,
         )
         return logits, cache
@@ -111,14 +107,8 @@ def build_model(
     cfg: ArchConfig,
     compute_dtype=jnp.bfloat16,
     param_dtype=jnp.float32,
-    use_pallas: bool = False,
 ) -> Model:
-    return Model(
-        cfg=cfg,
-        compute_dtype=compute_dtype,
-        param_dtype=param_dtype,
-        use_pallas=use_pallas,
-    )
+    return Model(cfg=cfg, compute_dtype=compute_dtype, param_dtype=param_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,9 @@ def make_prefill_step(model: Model) -> Callable:
     def prefill_step(
         params: Params, batch: Dict[str, jax.Array], cache: Params
     ) -> Tuple[jax.Array, Params]:
-        # last_only: unembed a single position, not the whole prompt (the
-        # full-prompt logits were the dominant collective in the baseline
-        # prefill roofline cells — see EXPERIMENTS.md §Perf).
+        # last_only: unembed a single position, not the whole prompt
+        # (the [T, vocab] logits of a long prompt are the largest tensor
+        # prefill would otherwise build).
         logits, cache = model.prefill(params, batch, cache, last_only=True)
         next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         return next_tok, cache

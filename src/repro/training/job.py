@@ -53,6 +53,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.handoff import StateHandoffChannel, WorkerHandoffChannel
 from repro.checkpoint.store import CheckpointStore
@@ -616,11 +618,21 @@ class TrainingJob:
                     continue
                 self._arrived[key] = d
 
-    def _run_step(self, jb: Dict[str, jax.Array]):
-        if self.mesh is not None:
-            with self.mesh, axis_rules(self.rules):
-                return self._jit(self.state, jb)
-        return self._jit(self.state, jb)
+    def batch_sharding(self) -> Optional[NamedSharding]:
+        """Layout of a global batch on the mesh: rows split over the data
+        axis, so each DP replica receives only its own rows (None
+        without a mesh)."""
+        if self.mesh is None:
+            return None
+        return NamedSharding(self.mesh, P(self.rules["batch"]))
+
+    def _run_step(self, rows: np.ndarray):
+        jb = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
+        if self.mesh is None:
+            return self._jit(self.state, jax.tree.map(jnp.asarray, jb))
+        jb = jax.device_put(jb, self.batch_sharding())
+        with self.mesh, axis_rules(self.rules):
+            return self._jit(self.state, jb)
 
     def _fire_barriers(self, now: float) -> int:
         """Apply every optimizer step whose DP shards have all arrived,
@@ -641,11 +653,7 @@ class TrainingJob:
                 (self._arrived.pop(k) for k in keys), key=lambda d: d["start"]
             )
             arr = np.concatenate([d["rows"] for d in parts], axis=0)
-            jb = {
-                "tokens": jnp.asarray(arr[:, :-1]),
-                "labels": jnp.asarray(arr[:, 1:]),
-            }
-            self.state, m = self._run_step(jb)
+            self.state, m = self._run_step(arr)
             self._applied = nxt
             del self._batch_meta[nxt]
             loss = float(m["loss"])

@@ -93,8 +93,9 @@ def test_prefill_step_returns_argmax(setup):
 
 def test_paged_batcher_matches_dense_on_real_model(setup):
     """Device-side paging on a real transformer: the paged batcher (page
-    pool + page tables + Pallas paged decode) produces exactly the tokens
-    the dense full-forward reference does, and returns every page."""
+    pool + page tables + the jnp paged decode the CPU serves with)
+    produces exactly the tokens the dense full-forward reference does,
+    and returns every page."""
     from repro.serving.kv_cache import PagedSpec
 
     cfg, model, params = setup
@@ -110,6 +111,36 @@ def test_paged_batcher_matches_dense_on_real_model(setup):
     for p in prompts:
         assert by_prompt[tuple(p)] == greedy_reference(model, params, p, n_new)
     assert b.page_pool.in_use == 0
+    assert b.page_pool.leaked() == 0
+
+
+def test_paged_batcher_through_pallas_kernels_matches_dense(setup,
+                                                           monkeypatch):
+    """The TPU branch of the paged decode (Pallas kv-append + paged
+    flash-decoding inside the jitted step), run in Pallas's interpreter:
+    the same tokens as the dense full-forward reference."""
+    from repro.kernels import platform
+    from repro.kernels.decode_attention import ops
+    from repro.serving.kv_cache import PagedSpec
+
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    monkeypatch.setattr(ops, "resolve_interpret", lambda interpret: True)
+    cfg, model, params = setup
+    prompts = [[5, 9, 2], [7, 1, 1, 3]]
+    n_new = 4
+    paged = PagedSpec(num_pages=1 + 2 * 4, page_size=8)
+    b = ContinuousBatcher(model, params, slots=2, max_len=32, paged=paged)
+    jaxpr = jax.make_jaxpr(b.decode_step)(
+        params, jnp.zeros((2, 1), jnp.int32), b.cache,
+        jnp.zeros((2,), jnp.int32), b.rng,
+    )
+    assert "pallas_call" in str(jaxpr)
+    for p in prompts:
+        b.submit(Request(prompt=p, max_new_tokens=n_new))
+    b.run_until_drained()
+    by_prompt = {tuple(r.prompt): r.output for r in b.completed}
+    for p in prompts:
+        assert by_prompt[tuple(p)] == greedy_reference(model, params, p, n_new)
     assert b.page_pool.leaked() == 0
 
 

@@ -1,0 +1,205 @@
+"""Compiles for a described TPU v5e, no chip attached.
+
+The TPU compiler is installed with JAX, so the main path's kernels and
+MiniCPM-2B's full-width paged decode step can be compiled for a v5e that
+is described and not attached: Mosaic's block-tiling checks and the
+chip's memory bound run here, where interpret mode checks neither.  The
+topology is described inside a module fixture (only the worker that runs
+this file loads the TPU library), and JAX's persistent compilation cache
+is off around these compiles, since entries written for a described chip
+cannot be read back without one.
+
+The last test is the CPU half of the same path: with bf16 compute and
+float32 params (``build_model``'s defaults) every architecture traces its
+prefill, its decode over dense and paged caches, and its train step.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.config import TrainingConfig, get_arch, list_archs
+from repro.kernels import platform
+from repro.kernels.decode_attention.kernel import (
+    paged_decode_attention_fwd,
+    paged_kv_append_fwd,
+)
+from repro.kernels.tcmm_assign.kernel import tcmm_assign_fwd
+from repro.models.layers import PagedSpec
+from repro.models.zoo import build_model
+from repro.serving.serve_step import make_decode_step
+from repro.training.train_step import init_train_state, make_train_step
+
+HBM_BYTES = 16e9  # one v5e chip
+MINICPM = dict(slots=8, max_len=1024, page=16, pages=513)  # chip_smoke.py
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("hkv,groups", [(36, 1), (8, 4)])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups):
+    """MiniCPM's MHA widths (36 kv heads) and a GQA layout, head_dim 64,
+    pages of 16: each block is a whole page with every kv head."""
+    b, d, page = MINICPM["slots"], 64, MINICPM["page"]
+    pool = (MINICPM["pages"], page, hkv, d)
+    n = MINICPM["max_len"] // page
+    _compile(
+        paged_decode_attention_fwd,
+        _sds(one_chip, (b, hkv * groups, d), jnp.bfloat16),
+        _sds(one_chip, pool, jnp.bfloat16),
+        _sds(one_chip, pool, jnp.bfloat16),
+        _sds(one_chip, (b, n), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32),
+    )
+
+
+def test_paged_kv_append_compiles_for_v5e(one_chip):
+    b, hkv, d, page = MINICPM["slots"], 36, 64, MINICPM["page"]
+    pool = (MINICPM["pages"], page, hkv, d)
+    n = MINICPM["max_len"] // page
+    _compile(
+        paged_kv_append_fwd,
+        _sds(one_chip, (b, hkv, d), jnp.bfloat16),
+        _sds(one_chip, (b, hkv, d), jnp.bfloat16),
+        _sds(one_chip, pool, jnp.bfloat16),
+        _sds(one_chip, pool, jnp.bfloat16),
+        _sds(one_chip, (b, n), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("centroids", [256, 512])
+def test_tcmm_assign_compiles_for_v5e(one_chip, centroids):
+    """One trajectory point against the micro-cluster table, as
+    ``apps/tcmm.py`` calls it (512 = ``TCMMConfig.max_micro_clusters``)."""
+    _compile(
+        lambda p, c, v: tcmm_assign_fwd(p, c, v, block_n=1),
+        _sds(one_chip, (1, 4), jnp.float32),
+        _sds(one_chip, (centroids, 4), jnp.float32),
+        _sds(one_chip, (centroids,), jnp.bool_),
+    )
+
+
+def test_minicpm_full_width_paged_decode_step_compiles_for_v5e(
+    one_chip, monkeypatch
+):
+    """The served decode step at MiniCPM-2B's published widths, bf16,
+    8 slots x 1024 tokens in pages of 16: it holds the Pallas kernels and
+    fits one chip.  The described chip is not the default backend, so the
+    test steers the platform check to the TPU branch itself."""
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    cfg = get_arch("minicpm-2b")
+    model = build_model(cfg, compute_dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: _sds(one_chip, x.shape, x.dtype), tree
+        )
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PagedSpec(num_pages=MINICPM["pages"], page_size=MINICPM["page"])
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(MINICPM["slots"], MINICPM["max_len"],
+                                 paged=spec)
+    ))
+    b = MINICPM["slots"]
+    compiled = make_decode_step(model).lower(
+        params,
+        _sds(one_chip, (b, 1), jnp.int32),
+        cache,
+        _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (2,), jnp.uint32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one v5e"
+
+
+@pytest.mark.parametrize("arch", list_archs())
+def test_bf16_compute_f32_params_trace(arch):
+    """build_model's default dtypes (bf16 compute, float32 params) trace
+    for prefill, decode over a dense and a paged cache, and the train
+    step; the residual stream and the caches stay bf16."""
+    cfg = get_arch(arch, smoke=True)
+    model = build_model(cfg)
+    assert model.compute_dtype == jnp.bfloat16
+    assert model.param_dtype == jnp.float32
+    key = jax.random.PRNGKey(0)
+    b, t = 2, 8
+    tokens = jax.ShapeDtypeStruct((b, t), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+    frontend = None
+    if cfg.encoder_layers > 0:
+        frontend = jax.ShapeDtypeStruct((b, cfg.encoder_seq, cfg.d_model),
+                                        jnp.bfloat16)
+    elif cfg.frontend_tokens > 0:
+        frontend = jax.ShapeDtypeStruct((b, cfg.frontend_tokens, cfg.d_model),
+                                        jnp.bfloat16)
+    if frontend is not None:
+        batch["frontend"] = frontend
+
+    tcfg = TrainingConfig()
+    state = jax.eval_shape(lambda k: init_train_state(model, tcfg, k), key)
+    _, metrics = jax.eval_shape(make_train_step(model, tcfg), state, batch)
+    assert metrics["loss"].dtype == jnp.float32
+
+    params = jax.eval_shape(model.init, key)
+    prompt = {k: v for k, v in batch.items() if k != "labels"}
+    cross = frontend if cfg.encoder_layers > 0 else None
+    for paged in (None, PagedSpec(num_pages=9, page_size=8)):
+        cache = jax.eval_shape(lambda: model.init_cache(b, 32, paged=paged))
+        _, cache = jax.eval_shape(
+            lambda p, x, c: model.prefill(p, x, c, last_only=True),
+            params, prompt, cache,
+        )
+        logits, cache2 = jax.eval_shape(
+            lambda p, tk, c, pos, f: model.decode_step(p, tk, c, pos,
+                                                       frontend=f),
+            params, jax.ShapeDtypeStruct((b, 1), jnp.int32), cache,
+            jax.ShapeDtypeStruct((b,), jnp.int32), cross,
+        )
+        assert logits.shape == (b, 1, cfg.vocab_size)
+        for before, after in zip(jax.tree.leaves(cache),
+                                 jax.tree.leaves(cache2)):
+            assert before.dtype == after.dtype
