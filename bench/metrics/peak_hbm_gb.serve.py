@@ -1,0 +1,6 @@
+"""Peak bytes in use on the fullest chip after the window, in GB."""
+
+
+def read(ctx):
+    b = ctx.get("memory_peak_bytes")
+    return b / 1e9 if b else None
