@@ -67,6 +67,7 @@ from repro.core.messages import Mailbox, Message
 from repro.core.scheduler import LoadView, Scheduler, make_scheduler
 from repro.core.supervision import HeartbeatDetector, Supervisor
 from repro.telemetry.metrics import MetricsReplica
+from repro.telemetry.profile import span
 
 
 class PoolWorker(Protocol):
@@ -433,6 +434,10 @@ class ElasticPool:
         self._m_readmitted = f"{metric_prefix}.readmitted"
         self._m_dispatched = f"{metric_prefix}.dispatched"
         self._m_dispatch_rounds = f"{metric_prefix}.dispatch_rounds"
+        # Span names of the tick and its phases (``telemetry.profile``).
+        self.tick_span = f"{metric_prefix}.tick"
+        self._s_dispatch = f"{metric_prefix}.dispatch"
+        self._s_autoscale = f"{metric_prefix}.autoscale"
         self.metrics = metrics or MetricsReplica(name)
         # Dead/retired workers fold their replicas here — the lossless
         # half of merged_metrics() that survives any chaos kill.
@@ -1165,13 +1170,22 @@ class ElasticPool:
 
     # -- main loop ---------------------------------------------------------------
     def step(self, now: float = 0.0) -> int:
+        """One pool round inside the ``<prefix>.tick`` span.  Returns
+        total work units done."""
+        with span(self.tick_span, tick=self.steps):
+            return self.round(now)
+
+    def round(self, now: float = 0.0) -> int:
         """One pool round: reap drained, dispatch, step workers, collect,
-        supervise, autoscale.  Returns total work units done."""
+        supervise, autoscale.  Returns total work units done.  Opens no
+        tick span: an owner whose tick holds more than the pool's round
+        (the training job's assembly and barriers) opens it itself."""
         self._now = max(self._now, now)
         if self.retire_mode == "drain":
             self._reap_drained()
         if self.ingress is not None:
-            self._dispatch()
+            with span(self._s_dispatch):
+                self._dispatch()
         worked = 0
         if self._plain:
             for worker in self.workers:
@@ -1201,6 +1215,14 @@ class ElasticPool:
             # path replaces the worker object, and anything harvestable
             # must be off it by then.
             self.collect(now)
+        with span(self._s_autoscale):
+            self._supervise_and_scale(now)
+        self.steps += 1
+        return worked
+
+    def _supervise_and_scale(self, now: float) -> None:
+        """Heartbeats and the supervisor's check, then the autoscaler's
+        observation, the throttle, actuation and reconciliation."""
         for worker in self.workers:
             if worker.alive and self._placement_up(worker):
                 self.supervisor.heartbeat(worker.name, now)
@@ -1219,7 +1241,6 @@ class ElasticPool:
             # that lag via note_rejected, and it must reach the
             # controller exactly as a bounded ingress's overflow does.
             depths = [w.mailbox.depth() for w in self.workers]
-            signal = sum(depths) + self._rejected_since_observe
             if self._rejected_since_observe and depths:
                 extra = self._rejected_since_observe / len(depths)
                 depths = [d + extra for d in depths]
@@ -1253,11 +1274,7 @@ class ElasticPool:
                 or self.controller.target_size != old_target
             ):
                 self._reconcile(now)
-        self.metrics.gauge(f"{self._px}.queue_depth", signal, timestamp=now)
-        self.metrics.gauge(f"{self._px}.occupancy", self.occupancy(), timestamp=now)
         self.occupancy_log.append(
             (now, self.controller.target_size, self.occupancy(),
              len(self.active_workers()))
         )
-        self.steps += 1
-        return worked

@@ -41,6 +41,7 @@ from repro.core.messages import Mailbox, Message
 from repro.models.zoo import Model
 from repro.serving.kv_cache import PagedSpec, PagePool
 from repro.serving.serve_step import make_decode_step, make_prefill_step
+from repro.telemetry.profile import span
 
 _req_ids = itertools.count()
 
@@ -201,37 +202,23 @@ class ContinuousBatcher:
     def _admit(self, slot: int, req: Request) -> bool:
         """Prefill ``req`` into slot ``slot``.  Returns False when paged
         mode cannot grant the prompt's pages (caller stalls the request;
-        slot state is untouched)."""
+        slot state is untouched).  The ``serve.admit`` span ends once the
+        first token is on the host."""
+        ids = None
         if self.paged is not None:
-            next_tok = self._prefill_paged(slot, req)
-            if next_tok is None:
+            ids = self._alloc_prompt_pages(req)
+            if ids is None:
                 return False
-        else:
-            prompt = jnp.asarray(req.prompt, dtype=jnp.int32)[None, :]
-            row_cache = self.model.init_cache(1, self.max_len)
-            next_tok, row_cache = self.prefill_step(
-                self.params, {"tokens": prompt}, row_cache
+        with span("serve.admit", req=req.req_id, prompt_len=len(req.prompt),
+                  slot=slot):
+            if ids is None:
+                next_tok = self._prefill_dense(slot, req)
+            else:
+                next_tok = self._prefill_paged(slot, req, ids)
+            first = (
+                req.first_token if req.first_token is not None
+                else int(next_tok[0])
             )
-            # Write the prefilled row into the shared cache at index
-            # `slot`.  Leaves under "periods" are stacked
-            # [n_periods, B, ...] (batch is axis 1); everything else
-            # leads with batch.
-            from jax.tree_util import DictKey, tree_map_with_path
-
-            def write_row(path, full, row):
-                in_periods = any(
-                    isinstance(p, DictKey) and p.key == "periods"
-                    for p in path[:1]
-                )
-                if in_periods:
-                    return full.at[:, slot].set(row[:, 0])
-                return full.at[slot].set(row[0])
-
-            self.cache = tree_map_with_path(write_row, self.cache, row_cache)
-        first = (
-            req.first_token if req.first_token is not None
-            else int(next_tok[0])
-        )
         self.active[slot] = req
         self.positions[slot] = len(req.prompt)
         self.budgets[slot] = req.max_new_tokens - 1
@@ -239,45 +226,78 @@ class ContinuousBatcher:
         self.outputs[slot] = [first]
         return True
 
-    def _prefill_paged(self, slot: int, req: Request) -> Optional[jax.Array]:
-        """Paged admission: allocate the prompt's pages, prefill into a
-        single-row scratch pool, then copy the filled pages into the
-        shared pool at the granted ids.  Returns the first decoded token,
-        or None when the pool cannot grant the pages right now."""
+    def _prefill_dense(self, slot: int, req: Request) -> jax.Array:
+        """Dense admission: prefill a single-row cache, then write it into
+        the shared cache at row ``slot``.  Returns the first decoded
+        token (on the device)."""
+        from jax.tree_util import DictKey, tree_map_with_path
+
+        with span("serve.prefill"):
+            prompt = jnp.asarray(req.prompt, dtype=jnp.int32)[None, :]
+            row_cache = self.model.init_cache(1, self.max_len)
+            next_tok, row_cache = self.prefill_step(
+                self.params, {"tokens": prompt}, row_cache
+            )
+
+        # Leaves under "periods" are stacked [n_periods, B, ...] (batch is
+        # axis 1); everything else leads with batch.
+        def write_row(path, full, row):
+            in_periods = any(
+                isinstance(p, DictKey) and p.key == "periods"
+                for p in path[:1]
+            )
+            if in_periods:
+                return full.at[:, slot].set(row[:, 0])
+            return full.at[slot].set(row[0])
+
+        with span("serve.merge"):
+            self.cache = tree_map_with_path(write_row, self.cache, row_cache)
+        return next_tok
+
+    def _alloc_prompt_pages(self, req: Request) -> Optional[List[int]]:
+        """The shared pool's pages for ``req``'s prompt, or None when the
+        pool cannot grant them right now."""
         assert self.paged is not None and self.page_pool is not None
-        need = self.page_pool.pages_for(len(req.prompt))
-        ids = self.page_pool.alloc(need)
+        ids = self.page_pool.alloc(self.page_pool.pages_for(len(req.prompt)))
         if ids is None:
             self._note("serve.page_alloc_failures")
             return None
         self._note_page_peak()
-        prompt = jnp.asarray(req.prompt, dtype=jnp.int32)[None, :]
-        # Scratch pool: page 0 reserved + exactly the prompt's pages,
-        # mapped 1:1 onto temp ids 1..need.
-        row_spec = PagedSpec(num_pages=need + 1, page_size=self.paged.page_size)
-        row_cache = self.model.init_cache(1, self.max_len, paged=row_spec)
-        from jax.tree_util import DictKey, tree_map_with_path
+        return ids
 
-        tmp_table = np.zeros((1, row_spec.pages_per_slot(self.max_len)),
-                             dtype=np.int32)
-        tmp_table[0, :need] = np.arange(1, need + 1)
-        tmp_dev = jnp.asarray(tmp_table)
+    def _prefill_paged(self, slot: int, req: Request,
+                       ids: List[int]) -> jax.Array:
+        """Paged admission: prefill into a single-row scratch pool, then
+        copy the filled pages into the shared pool at the granted
+        ``ids``.  Returns the first decoded token (on the device)."""
+        from jax.tree_util import DictKey, tree_map_with_path
 
         def leaf_key(path) -> Optional[str]:
             last = path[-1]
             return last.key if isinstance(last, DictKey) else None
 
-        def set_tmp_table(path, leaf):
-            if leaf_key(path) == "page_table":
-                return jnp.broadcast_to(tmp_dev, leaf.shape).astype(leaf.dtype)
-            return leaf
+        need = len(ids)
+        # Scratch pool: page 0 reserved + exactly the prompt's pages,
+        # mapped 1:1 onto temp ids 1..need.
+        row_spec = PagedSpec(num_pages=need + 1, page_size=self.paged.page_size)
+        tmp_table = np.zeros((1, row_spec.pages_per_slot(self.max_len)),
+                             dtype=np.int32)
+        tmp_table[0, :need] = np.arange(1, need + 1)
 
-        row_cache = tree_map_with_path(set_tmp_table, row_cache)
-        next_tok, row_cache = self.prefill_step(
-            self.params, {"tokens": prompt}, row_cache
-        )
+        with span("serve.prefill"):
+            prompt = jnp.asarray(req.prompt, dtype=jnp.int32)[None, :]
+            row_cache = self.model.init_cache(1, self.max_len, paged=row_spec)
+            tmp_dev = jnp.asarray(tmp_table)
 
-        ids_arr = jnp.asarray(ids, dtype=jnp.int32)
+            def set_tmp_table(path, leaf):
+                if leaf_key(path) == "page_table":
+                    return jnp.broadcast_to(tmp_dev, leaf.shape).astype(leaf.dtype)
+                return leaf
+
+            row_cache = tree_map_with_path(set_tmp_table, row_cache)
+            next_tok, row_cache = self.prefill_step(
+                self.params, {"tokens": prompt}, row_cache
+            )
 
         def merge(path, full, row):
             key = leaf_key(path)
@@ -296,11 +316,13 @@ class ContinuousBatcher:
                 return full.at[:, slot].set(row[:, 0])
             return full.at[slot].set(row[0])
 
-        self.cache = tree_map_with_path(merge, self.cache, row_cache)
-        self.slot_pages[slot] = list(ids)
-        self._page_table[slot] = 0
-        self._page_table[slot, :need] = ids
-        self._table_dirty = True
+        with span("serve.merge"):
+            ids_arr = jnp.asarray(ids, dtype=jnp.int32)
+            self.cache = tree_map_with_path(merge, self.cache, row_cache)
+            self.slot_pages[slot] = list(ids)
+            self._page_table[slot] = 0
+            self._page_table[slot, :need] = ids
+            self._table_dirty = True
         return next_tok
 
     def _release_pages(self, slot: int) -> None:
@@ -417,14 +439,16 @@ class ContinuousBatcher:
 
     def _finish(self, slot: int, now: float) -> None:
         req = self.active[slot]
-        if req is not None:
-            req.output = list(self.outputs[slot])
-            req.completed_at = now
-            self.completed.append(req)
-        self.active[slot] = None
-        self.outputs[slot] = []
-        self.budgets[slot] = 0
-        self._release_pages(slot)
+        with span("serve.finish", req=req.req_id if req is not None else -1,
+                  tokens=len(self.outputs[slot])):
+            if req is not None:
+                req.output = list(self.outputs[slot])
+                req.completed_at = now
+                self.completed.append(req)
+            self.active[slot] = None
+            self.outputs[slot] = []
+            self.budgets[slot] = 0
+            self._release_pages(slot)
 
     def _next_message(self) -> Optional[Message]:
         """Stalled requests (blocked on pages earlier) go first, keeping
@@ -491,17 +515,22 @@ class ContinuousBatcher:
             return 0
 
         # Grant each slot the page its next token lands in (may preempt).
-        self._ensure_pages()
-        if self.occupancy() == 0:
+        with span("serve.pages"):
+            self._ensure_pages()
+            rows = self.occupancy()
+            if rows:
+                self._sync_page_table()
+        if rows == 0:
             return 0
-        self._sync_page_table()
 
-        tokens = jnp.asarray(self.cur_tokens)
-        positions = jnp.asarray(self.positions)
-        next_tok, self.cache, self.rng = self.decode_step(
-            self.params, tokens, self.cache, positions, self.rng
-        )
-        next_np = np.asarray(next_tok)
+        with span("serve.decode", rows=rows):
+            tokens = jnp.asarray(self.cur_tokens)
+            positions = jnp.asarray(self.positions)
+            next_tok, self.cache, self.rng = self.decode_step(
+                self.params, tokens, self.cache, positions, self.rng
+            )
+        with span("serve.token_wait"):
+            next_np = np.asarray(next_tok)
         decoded = 0
         for slot in range(self.slots):
             if self.active[slot] is None:

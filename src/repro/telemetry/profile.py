@@ -1,5 +1,15 @@
-"""Lightweight control-plane profiling (the vectorized-dispatch
-refactor's observability satellite).
+"""Control-plane profiling: named spans in the profiler's trace, and a
+wall-time accumulator.
+
+``span(name, **meta)`` is ``jax.profiler.TraceAnnotation``: a named host
+span written into the profiler's own trace, on the host plane of the
+same ``.xplane.pb`` as the device planes, so on the device's clock.
+Keyword metadata comes back as the event's stats.  It records only while
+a profiler session runs and costs about a microsecond otherwise, so the
+serving tick and the training step carry their spans unconditionally
+(``serve.*`` in ``core.pool`` and ``serving.batcher``, ``train.*`` in
+``training.job``).  Names that repeat every tick are built once, by the
+owner's constructor, not per call.
 
 ``StepTimer`` accumulates per-name wall-time and call counts — the
 "where do the step() milliseconds go" question that previously required
@@ -18,6 +28,10 @@ from __future__ import annotations
 import time
 from contextlib import contextmanager
 from typing import Callable, Dict, Iterator
+
+from jax.profiler import TraceAnnotation
+
+span = TraceAnnotation
 
 
 class StepTimer:

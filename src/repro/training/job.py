@@ -72,6 +72,7 @@ from repro.distributed.elastic_mesh import (
 )
 from repro.distributed.param_shardings import make_rules
 from repro.distributed.sharding import axis_rules
+from repro.telemetry.profile import span
 from repro.training.train_step import init_train_state, make_train_step
 
 _worker_ids = itertools.count()
@@ -119,17 +120,20 @@ class TokenIngestStage:
         """One training round: assemble shard messages from the ordered
         stream, report stream backlog as rejected demand, run the pool
         (dispatch/process/collect/supervise/autoscale), then fire every
-        complete barrier.  Returns optimizer steps applied."""
+        complete barrier.  Returns optimizer steps applied.  The whole
+        round is the pool's tick span (``train.tick``)."""
         job = self.job
-        job._now = max(job._now, now)
-        job._drain_commit_gate(now)  # land any newly durable commits
-        job._assemble(now)
-        if job.pool.elastic:
-            lag_batches = job.pipeline.lag() // job.batch_size
-            if lag_batches:
-                job.pool.note_rejected(min(lag_batches, job.autoscale_lag_cap))
-        job.pool.step(now)
-        return job._fire_barriers(now)
+        with span(job.pool.tick_span, tick=job.pool.steps):
+            job._now = max(job._now, now)
+            job._drain_commit_gate(now)  # land any newly durable commits
+            with span("train.assemble"):
+                job._assemble(now)
+            if job.pool.elastic:
+                lag_batches = job.pipeline.lag() // job.batch_size
+                if lag_batches:
+                    job.pool.note_rejected(min(lag_batches, job.autoscale_lag_cap))
+            job.pool.round(now)
+            return job._fire_barriers(now)
 
 
 class TrainerWorker(WorkerBase):
@@ -649,66 +653,77 @@ class TrainingJob:
             keys = [(nxt, s) for s in range(meta["shards"])]
             if any(k not in self._arrived for k in keys):
                 break
-            parts = sorted(
-                (self._arrived.pop(k) for k in keys), key=lambda d: d["start"]
-            )
-            arr = np.concatenate([d["rows"] for d in parts], axis=0)
-            self.state, m = self._run_step(arr)
-            self._applied = nxt
-            del self._batch_meta[nxt]
-            loss = float(m["loss"])
-            self.losses.append(loss)
-            self.pool.metrics.incr("train.steps")
-            self.pool.metrics.gauge("train.loss", loss, timestamp=now)
-            # Advance the applied-step stream cursor (what snapshots and
-            # handoffs pair with the state).
-            for p, o in meta["offsets"].items():
-                self._cursor_offsets[str(p)] = o
-            self._cursor_rr = meta["rr"]
-            # Durable journal FIRST...
-            if self.store is not None:
-                self.store.record_step(
-                    nxt, offsets=meta["offsets"], metrics={"loss": loss}
-                )
-            do_snap = (
-                self.store is not None
-                and self.checkpoint_every
-                and nxt % self.checkpoint_every == 0
-            )
-            if self._async:
-                # ...then the offsets commit when the journal line (and,
-                # on snapshot steps, the manifest — same FIFO, so later)
-                # lands durably: the gate replaces the synchronous write.
-                ticket = (
-                    self.store.last_write_ticket()
-                    if self.store is not None else None
-                )
-                if do_snap:
-                    ticket = self.save_checkpoint() or ticket
-                self._pending_commits.append(
-                    (nxt, meta["offsets"], meta["rr"], ticket)
-                )
-                self._drain_commit_gate(now)
-            else:
-                # ...then the token offsets may commit.
-                self.pipeline.commit(meta["offsets"], now=now, rr=meta["rr"])
-                self.step_offsets[nxt] = dict(meta["offsets"])
-                if do_snap:
-                    self.save_checkpoint()
-            if self.handoff is not None and self.handoff_every:
-                if nxt % self.handoff_every == 0:
-                    self._publish_handoff()
-                else:
-                    self.handoff.publish_delta(
-                        nxt,
-                        {"offsets": {str(p): o
-                                     for p, o in meta["offsets"].items()},
-                         "rr": meta["rr"]},
+            with span("train.step", step=nxt):
+                with span("train.upload"):
+                    parts = sorted(
+                        (self._arrived.pop(k) for k in keys),
+                        key=lambda d: d["start"],
                     )
+                    arr = np.concatenate([d["rows"] for d in parts], axis=0)
+                    self.state, m = self._run_step(arr)
+                self._applied = nxt
+                del self._batch_meta[nxt]
+                with span("train.loss_wait"):
+                    loss = float(m["loss"])
+                self.losses.append(loss)
+                self.pool.metrics.incr("train.steps")
+                with span("train.commit"):
+                    self._commit_step(nxt, meta, loss, now)
             if self.on_step is not None:
                 self.on_step(nxt, m)
             fired += 1
         return fired
+
+    def _commit_step(self, nxt: int, meta: Dict, loss: float,
+                     now: float) -> None:
+        """After optimizer step ``nxt``: advance the stream cursor, journal
+        the step, commit its offsets (or queue them behind the journal's
+        write), snapshot and hand off on their cadence."""
+        # Advance the applied-step stream cursor (what snapshots and
+        # handoffs pair with the state).
+        for p, o in meta["offsets"].items():
+            self._cursor_offsets[str(p)] = o
+        self._cursor_rr = meta["rr"]
+        # Durable journal FIRST...
+        if self.store is not None:
+            self.store.record_step(
+                nxt, offsets=meta["offsets"], metrics={"loss": loss}
+            )
+        do_snap = (
+            self.store is not None
+            and self.checkpoint_every
+            and nxt % self.checkpoint_every == 0
+        )
+        if self._async:
+            # ...then the offsets commit when the journal line (and,
+            # on snapshot steps, the manifest — same FIFO, so later)
+            # lands durably: the gate replaces the synchronous write.
+            ticket = (
+                self.store.last_write_ticket()
+                if self.store is not None else None
+            )
+            if do_snap:
+                ticket = self.save_checkpoint() or ticket
+            self._pending_commits.append(
+                (nxt, meta["offsets"], meta["rr"], ticket)
+            )
+            self._drain_commit_gate(now)
+        else:
+            # ...then the token offsets may commit.
+            self.pipeline.commit(meta["offsets"], now=now, rr=meta["rr"])
+            self.step_offsets[nxt] = dict(meta["offsets"])
+            if do_snap:
+                self.save_checkpoint()
+        if self.handoff is not None and self.handoff_every:
+            if nxt % self.handoff_every == 0:
+                self._publish_handoff()
+            else:
+                self.handoff.publish_delta(
+                    nxt,
+                    {"offsets": {str(p): o
+                                 for p, o in meta["offsets"].items()},
+                     "rr": meta["rr"]},
+                )
 
     def _actuate_scale(self, old_units: int, new_units: int) -> None:
         """The pool's scale decision becomes a physical re-layout:
