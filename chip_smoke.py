@@ -94,7 +94,7 @@ def check_paged_kernel(seed: int, batch: int, heads: int, head_dim: int,
 
     n_slot = max_len // page
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    shape = (num_pages, page, heads, head_dim)
+    shape = (num_pages, page, heads * head_dim)  # lane-dense pool
     q = jax.random.normal(ks[0], (batch, heads, head_dim), jnp.bfloat16)
     k_pages = jax.random.normal(ks[1], shape, jnp.bfloat16)
     v_pages = jax.random.normal(ks[2], shape, jnp.bfloat16)

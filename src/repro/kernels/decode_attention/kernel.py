@@ -14,7 +14,7 @@ kv_len masking handles ragged batches (continuous batching feeds
 sequences of different lengths).
 
 The **paged** variants replace the per-sequence dense cache
-``[B, S, Hkv, D]`` with a shared page pool ``[P, page_size, Hkv, D]``
+``[B, S, Hkv, D]`` with a shared page pool ``[P, page_size, Hkv*D]``
 plus a per-sequence page table ``[B, pages_per_seq]`` — the serving
 layer allocates pages per token tick (continuous batching) instead of
 reserving max_len rows per slot.  The page table and kv_len ride in as
@@ -22,14 +22,22 @@ scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``) so the
 BlockSpec index maps gather the right K/V page for every grid step —
 the gather happens in the DMA schedule, never as a materialized
 ``k_pages[page_table]`` copy.  Grid = (B, pages per sequence), and one
-K/V block is a whole page with every kv head, ``(1, page, Hkv, D)``:
-Mosaic only tiles a block whose last two dims are (8, 128)-aligned or
-whole, and a single-head slice ``(1, page, 1, D)`` of the pool is
-neither.  ``paged_kv_append`` writes one new
-token's K/V into its page in place (``input_output_aliases``), so the
-per-tick cache update is O(1) rows, not an O(S) re-materialization.
-The dense kernel above stays the bitwise reference path (the
-``vectorize=False`` pattern of the vectorized control plane).
+K/V block is a whole page, ``(1, page, Hkv*D)``.
+
+The pool is **lane-dense**: a token's row holds every kv head's D
+lanes side by side.  With D = 64 a ``[.., page, Hkv, D]`` pool would
+fill half of each 128-lane tile, so XLA laid it out with the page axis
+minor-most instead, and every call transposed the whole pool into the
+kernel's row-major blocks and back.  ``[P, page, Hkv*D]`` fills whole
+tiles row-major (Hkv*D is a multiple of 128 at every served width), so
+XLA keeps the layout the kernels read.  Inside the decode kernel each
+head's ``q.k`` is a matmul of the page against a block-diagonal copy of
+q (see ``_paged_decode_kernel``), and the online softmax keeps its
+statistics per lane.  ``paged_kv_append`` writes one new token's K/V
+row into its page in place (``input_output_aliases``), so the per-tick
+cache update is O(1) rows, not an O(S) re-materialization.  The dense
+kernel above stays the bitwise reference path (the ``vectorize=False``
+pattern of the vectorized control plane).
 """
 
 from __future__ import annotations
@@ -44,6 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANE = 128  # lanes of a vreg
 
 
 def _decode_kernel(
@@ -150,32 +159,61 @@ def decode_attention_fwd(
 # ---------------------------------------------------------------------------
 
 
+def _head_chunk(head_dim: int, width: int) -> int:
+    """Lanes of a pool row the paged decode kernel takes at a time: the
+    fewest whole heads that fill whole 128-lane vregs (two heads of 64,
+    one of 128), or the whole row where such chunks do not tile it
+    (small interpret-mode widths)."""
+    chunk = math.lcm(head_dim, LANE)
+    return chunk if width % chunk == 0 else width
+
+
 def _paged_decode_kernel(
     pt_ref,      # scalar prefetch [B, n_pages] int32 page table
     kv_len_ref,  # scalar prefetch [B] int32
-    q_ref,       # [1, G, Hkv, d]
-    k_ref,       # [1, page, Hkv, d]  (page selected by the index map)
-    v_ref,       # [1, page, Hkv, d]
-    o_ref,       # [1, G, Hkv, d]
-    m_ref,       # scratch [G, Hkv, 1] f32
-    l_ref,       # scratch [G, Hkv, 1] f32
-    acc_ref,     # scratch [G, Hkv, d] f32
+    q_ref,       # [1, G, Hkv*d]
+    k_ref,       # [1, page, Hkv*d]  (page selected by the index map)
+    v_ref,       # [1, page, Hkv*d]
+    o_ref,       # [1, G, Hkv*d]
+    qbd_ref,     # scratch [G * n_chunks, chunk, chunk] block-diagonal q
+    m_ref,       # scratch [G, Hkv*d] f32, each head's stat on its d lanes
+    l_ref,       # scratch [G, Hkv*d] f32
+    acc_ref,     # scratch [G, Hkv*d] f32
     *,
     sm_scale: float,
     window: int,
     page_size: int,
     kv_steps: int,
     groups: int,
+    head_dim: int,
+    chunk: int,
 ):
     del pt_ref  # consumed by the index maps
     ib = pl.program_id(0)
     ik = pl.program_id(1)
+    n_chunks = acc_ref.shape[1] // chunk
 
     @pl.when(ik == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        # qbd[j, l] = q[j] where lanes j and l hold the same head, else 0:
+        # ``k_chunk @ qbd`` then gives every lane of a head that head's
+        # q.k, so the sum over d runs on the MXU and never leaves the
+        # lanes.  Built once per sequence as diag(q) @ same (exact: one
+        # nonzero term per output), with no transpose.
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        same = (row // head_dim == col // head_dim).astype(jnp.float32)
+        for g in range(groups):
+            for c in range(n_chunks):
+                q = q_ref[0, g:g + 1, c * chunk:(c + 1) * chunk]
+                diag = jnp.where(row == col, q.astype(jnp.float32), 0.0)
+                qbd_ref[g * n_chunks + c] = jnp.dot(
+                    diag, same, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                ).astype(qbd_ref.dtype)
 
     kv_len = kv_len_ref[ib]
 
@@ -184,27 +222,39 @@ def _paged_decode_kernel(
     # unallocated table entries to a valid page id).
     @pl.when(ik * page_size < kv_len)
     def _update():
-        k = k_ref[0].astype(jnp.float32)  # [page, Hkv, d]
-        v = v_ref[0].astype(jnp.float32)
         k_pos = ik * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1, 1), 0
+            jnp.int32, (page_size, 1), 0
         )
         mask = k_pos < kv_len
         if window > 0:
             mask = mask & (k_pos > kv_len - 1 - window)
-        # One page holds every kv head, so each head's scores are a
-        # lane reduction over d on the VPU (decode is a GEMV per head).
-        for g in range(groups):
-            q = q_ref[0, g].astype(jnp.float32)  # [Hkv, d]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * sm_scale
-            s = jnp.where(mask, s, NEG_INF)  # [page, Hkv, 1]
-            m_prev = m_ref[g]  # [Hkv, 1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            alpha = jnp.exp(m_prev - m_cur)
-            p = jnp.where(mask, jnp.exp(s - m_cur[None]), 0.0)
-            l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
-            acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
-            m_ref[g] = m_cur
+        # bf16 products are exact in the f32 accumulator; f32 operands
+        # need the MXU's multi-pass precision to stay float32.
+        precision = (jax.lax.Precision.HIGHEST
+                     if qbd_ref.dtype == jnp.float32 else None)
+        # Every statistic is held per lane, each head's on its own d
+        # lanes, so the online softmax is elementwise over [page, chunk]
+        # tiles and p.v a sum over the page's rows.
+        for c in range(n_chunks):
+            lanes = slice(c * chunk, (c + 1) * chunk)
+            k = k_ref[0, :, lanes].astype(qbd_ref.dtype)  # [page, chunk]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            for g in range(groups):
+                s = jnp.dot(
+                    k, qbd_ref[g * n_chunks + c],
+                    preferred_element_type=jnp.float32, precision=precision,
+                ) * sm_scale
+                s = jnp.where(mask, s, NEG_INF)
+                rows = (slice(g, g + 1), lanes)
+                m_prev = m_ref[rows]  # [1, chunk]
+                m_cur = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+                alpha = jnp.exp(m_prev - m_cur)
+                p = jnp.where(mask, jnp.exp(s - m_cur), 0.0)
+                l_ref[rows] = l_ref[rows] * alpha + jnp.sum(
+                    p, axis=0, keepdims=True)
+                acc_ref[rows] = acc_ref[rows] * alpha + jnp.sum(
+                    p * v, axis=0, keepdims=True)
+                m_ref[rows] = m_cur
 
     @pl.when(ik == kv_steps - 1)
     def _finalize():
@@ -214,8 +264,8 @@ def _paged_decode_kernel(
 
 def paged_decode_attention_fwd(
     q: jax.Array,           # [B, H, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv, D] shared page pool
-    v_pages: jax.Array,     # [P, page_size, Hkv, D]
+    k_pages: jax.Array,     # [P, page_size, Hkv*D] shared page pool
+    v_pages: jax.Array,     # [P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32 (page id per logical page)
     kv_len: jax.Array,      # [B] int32
     window: int = 0,
@@ -223,13 +273,15 @@ def paged_decode_attention_fwd(
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
-    page_size, hkv = k_pages.shape[1], k_pages.shape[2]
+    page_size, width = k_pages.shape[1], k_pages.shape[2]
+    hkv = width // d
     n_pages = page_table.shape[1]
     g = h // hkv
+    chunk = _head_chunk(d, width)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    # Head h belongs to kv-head h // g: [B, H, d] -> [B, G, Hkv, d], so a
-    # block's last two dims are the pool's own (Hkv, d), as Mosaic needs.
-    qg = q.reshape(b, hkv, g, d).swapaxes(1, 2)
+    # Head h belongs to kv-head h // g: [B, H, d] -> [B, G, Hkv*d], so
+    # group g's row lines its heads up with the pool's lanes.
+    qg = q.reshape(b, hkv, g, d).swapaxes(1, 2).reshape(b, g, width)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -238,43 +290,48 @@ def paged_decode_attention_fwd(
         page_size=page_size,
         kv_steps=n_pages,
         groups=g,
+        head_dim=d,
+        chunk=chunk,
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_pages),
         in_specs=[
-            pl.BlockSpec((1, g, hkv, d), lambda b_, ik, pt, kl: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, g, width), lambda b_, ik, pt, kl: (b_, 0, 0)),
             # The page-table gather: logical page ik of sequence b_ lives
             # in pool page pt[b_, ik] — resolved at DMA-schedule time.
-            # One block carries all kv heads of the page.
+            # One block is a whole page row-major, every kv head on the
+            # lanes: the pool's own layout, so nothing is transposed.
             pl.BlockSpec(
-                (1, page_size, hkv, d),
-                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0, 0),
+                (1, page_size, width),
+                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0),
             ),
             pl.BlockSpec(
-                (1, page_size, hkv, d),
-                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0, 0),
+                (1, page_size, width),
+                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, g, hkv, d), lambda b_, ik, pt, kl: (b_, 0, 0, 0)
+            (1, g, width), lambda b_, ik, pt, kl: (b_, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, hkv, 1), jnp.float32),
-            pltpu.VMEM((g, hkv, 1), jnp.float32),
-            pltpu.VMEM((g, hkv, d), jnp.float32),
+            pltpu.VMEM((g * (width // chunk), chunk, chunk),
+                       jnp.promote_types(q.dtype, k_pages.dtype)),
+            pltpu.VMEM((g, width), jnp.float32),
+            pltpu.VMEM((g, width), jnp.float32),
+            pltpu.VMEM((g, width), jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, g, hkv, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, width), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qg,
       k_pages, v_pages)
-    return out.swapaxes(1, 2).reshape(b, h, d)
+    return out.reshape(b, g, hkv, d).swapaxes(1, 2).reshape(b, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -285,10 +342,10 @@ def paged_decode_attention_fwd(
 def _kv_append_kernel(
     pt_ref,      # scalar prefetch [B, n_pages] int32
     pos_ref,     # scalar prefetch [B] int32 (write position per sequence)
-    k_new_ref,   # [1, Hkv, D]
-    v_new_ref,   # [1, Hkv, D]
-    k_page_ref,  # [1, page, Hkv, D] aliased in/out (the target page)
-    v_page_ref,  # [1, page, Hkv, D] aliased in/out
+    k_new_ref,   # [1, 1, Hkv*D]
+    v_new_ref,   # [1, 1, Hkv*D]
+    k_page_ref,  # [1, page, Hkv*D] aliased in/out (the target page)
+    v_page_ref,  # [1, page, Hkv*D] aliased in/out
     ko_ref,
     vo_ref,
     *,
@@ -300,27 +357,29 @@ def _kv_append_kernel(
     # initialization: on TPU the Mosaic output windows are write-only and
     # start undefined (interpret mode happens to seed them from the
     # donated input, which is why tests alone cannot catch this).  The
-    # whole page block must therefore be written — copy the co-mapped
-    # input page first, then overwrite the one row this token owns.
-    ko_ref[...] = k_page_ref[...]
-    vo_ref[...] = v_page_ref[...]
-    off = pos_ref[ib] % page_size
-    ko_ref[0, pl.ds(off, 1), :, :] = k_new_ref[0][None]
-    vo_ref[0, pl.ds(off, 1), :, :] = v_new_ref[0][None]
+    # whole page block must therefore be written: the co-mapped input
+    # page, with the one row this token owns replaced.
+    row = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
+    mine = row == pos_ref[ib] % page_size
+    ko_ref[0] = jnp.where(mine, k_new_ref[0], k_page_ref[0])
+    vo_ref[0] = jnp.where(mine, v_new_ref[0], v_page_ref[0])
 
 
 def paged_kv_append_fwd(
     k_new: jax.Array,       # [B, Hkv, D] this tick's keys
     v_new: jax.Array,       # [B, Hkv, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv, D]
-    v_pages: jax.Array,     # [P, page_size, Hkv, D]
+    k_pages: jax.Array,     # [P, page_size, Hkv*D]
+    v_pages: jax.Array,     # [P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32
     pos: jax.Array,         # [B] int32 write positions (== kv_len pre-append)
     interpret: bool = False,
 ) -> "tuple[jax.Array, jax.Array]":
-    b, hkv, d = k_new.shape
-    page_size = k_pages.shape[1]
+    b = k_new.shape[0]
+    page_size, width = k_pages.shape[1], k_pages.shape[2]
     n_pages = page_table.shape[1]
+    # One lane-dense row per sequence, in the pool's dtype.
+    k_row = k_new.reshape(b, 1, width).astype(k_pages.dtype)
+    v_row = v_new.reshape(b, 1, width).astype(v_pages.dtype)
 
     kernel = functools.partial(_kv_append_kernel, page_size=page_size)
     # One grid step per sequence; the index map routes both the aliased
@@ -334,21 +393,21 @@ def paged_kv_append_fwd(
     # lands in the slot's own last table entry (the scratch page 0 for
     # an idle, all-zero table row).
     page_idx = lambda b_, pt, ps: (
-        pt[b_, jnp.minimum(ps[b_] // page_size, n_pages - 1)], 0, 0, 0
+        pt[b_, jnp.minimum(ps[b_] // page_size, n_pages - 1)], 0, 0
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, hkv, d), lambda b_, pt, ps: (b_, 0, 0)),
-            pl.BlockSpec((1, hkv, d), lambda b_, pt, ps: (b_, 0, 0)),
-            pl.BlockSpec((1, page_size, hkv, d), page_idx),
-            pl.BlockSpec((1, page_size, hkv, d), page_idx),
+            pl.BlockSpec((1, 1, width), lambda b_, pt, ps: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, width), lambda b_, pt, ps: (b_, 0, 0)),
+            pl.BlockSpec((1, page_size, width), page_idx),
+            pl.BlockSpec((1, page_size, width), page_idx),
         ],
         out_specs=[
-            pl.BlockSpec((1, page_size, hkv, d), page_idx),
-            pl.BlockSpec((1, page_size, hkv, d), page_idx),
+            pl.BlockSpec((1, page_size, width), page_idx),
+            pl.BlockSpec((1, page_size, width), page_idx),
         ],
     )
 
@@ -364,4 +423,4 @@ def paged_kv_append_fwd(
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      k_new, v_new, k_pages, v_pages)
+      k_row, v_row, k_pages, v_pages)

@@ -27,13 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.decode_attention.kernel import (
+    LANE,
     decode_attention_fwd,
     paged_decode_attention_fwd,
     paged_kv_append_fwd,
 )
 from repro.kernels.platform import resolve_interpret
-
-LANE = 128
 
 
 def _require_int(name: str, arr: jax.Array) -> jax.Array:
@@ -135,17 +134,24 @@ def _paged_decode_jit(q, k_pages, v_pages, page_table, kv_len, window,
 
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv, D]
-    v_pages: jax.Array,     # [P, page_size, Hkv, D]
+    k_pages: jax.Array,     # [P, page_size, Hkv*D]
+    v_pages: jax.Array,     # [P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32
     kv_len: jax.Array,      # [B]
     window: int = 0,
     sm_scale: Optional[float] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
+    """One query token per sequence against the lane-dense page pool:
+    each pool row holds every kv head's D lanes side by side."""
     if q.ndim != 3:
         raise ValueError("q must be [B, H, D] (one token per sequence)")
-    if q.shape[1] % k_pages.shape[2] != 0:
+    if k_pages.ndim != 3 or k_pages.shape[2] % q.shape[2] != 0:
+        raise ValueError(
+            f"k_pages must be [P, page_size, Hkv*D] with D = {q.shape[2]}, "
+            f"got {k_pages.shape}"
+        )
+    if q.shape[1] % (k_pages.shape[2] // q.shape[2]) != 0:
         raise ValueError("num_heads must be a multiple of num_kv_heads")
     if page_table.ndim != 2 or page_table.shape[0] != q.shape[0]:
         raise ValueError(
@@ -179,14 +185,20 @@ def _kv_append_jit(k_new, v_new, k_pages, v_pages, page_table, pos,
 def paged_kv_append(
     k_new: jax.Array,       # [B, Hkv, D]
     v_new: jax.Array,       # [B, Hkv, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv, D]
-    v_pages: jax.Array,     # [P, page_size, Hkv, D]
+    k_pages: jax.Array,     # [P, page_size, Hkv*D]
+    v_pages: jax.Array,     # [P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32
     pos: jax.Array,         # [B] write positions (kv_len before append)
     interpret: Optional[bool] = None,
 ) -> "tuple[jax.Array, jax.Array]":
+    """Write each sequence's new K/V as one lane-dense row of its page."""
     if k_new.ndim != 3:
         raise ValueError("k_new must be [B, Hkv, D] (one token per sequence)")
+    if k_pages.shape[-1] != k_new.shape[1] * k_new.shape[2]:
+        raise ValueError(
+            f"k_pages must be [P, page_size, Hkv*D] for k_new "
+            f"{k_new.shape}, got {k_pages.shape}"
+        )
     n_pages, page_size = page_table.shape[1], k_pages.shape[1]
     pos = _require_int("pos", pos)
     page_table = _require_int("page_table", page_table)

@@ -47,26 +47,28 @@ def decode_attention_ref(
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Materialize the dense per-sequence cache a page table describes.
 
-    pages [P, page, Hkv, D] + table [B, n] -> [B, n*page, Hkv, D].  This
+    pages [P, page, Hkv*D] + table [B, n] -> [B, n*page, Hkv*D].  This
     is the *reference* semantics of the paged kernel's DMA gather — the
     kernel never builds this array."""
     b, n = page_table.shape
     page = pages.shape[1]
-    dense = pages[page_table]  # [B, n, page, Hkv, D]
-    return dense.reshape(b, n * page, *pages.shape[2:])
+    dense = pages[page_table]  # [B, n, page, Hkv*D]
+    return dense.reshape(b, n * page, pages.shape[2])
 
 
 def paged_decode_attention_ref(
     q: jax.Array,           # [B, H, D]
-    k_pages: jax.Array,     # [P, page, Hkv, D]
-    v_pages: jax.Array,     # [P, page, Hkv, D]
+    k_pages: jax.Array,     # [P, page, Hkv*D]
+    v_pages: jax.Array,     # [P, page, Hkv*D]
     page_table: jax.Array,  # [B, n] int32
     kv_len: jax.Array,      # [B]
     window: int = 0,
     sm_scale: Optional[float] = None,
 ) -> jax.Array:
-    k_dense = gather_pages(k_pages, page_table)
-    v_dense = gather_pages(v_pages, page_table)
+    b, _, d = q.shape
+    s = page_table.shape[1] * k_pages.shape[1]
+    k_dense = gather_pages(k_pages, page_table).reshape(b, s, -1, d)
+    v_dense = gather_pages(v_pages, page_table).reshape(b, s, -1, d)
     return decode_attention_ref(
         q, k_dense, v_dense, kv_len, window=window, sm_scale=sm_scale
     )
@@ -75,8 +77,8 @@ def paged_decode_attention_ref(
 def paged_kv_append_ref(
     k_new: jax.Array,       # [B, Hkv, D]
     v_new: jax.Array,       # [B, Hkv, D]
-    k_pages: jax.Array,     # [P, page, Hkv, D]
-    v_pages: jax.Array,     # [P, page, Hkv, D]
+    k_pages: jax.Array,     # [P, page, Hkv*D]
+    v_pages: jax.Array,     # [P, page, Hkv*D]
     page_table: jax.Array,  # [B, n] int32
     pos: jax.Array,         # [B] write positions
 ) -> "tuple[jax.Array, jax.Array]":
@@ -87,6 +89,6 @@ def paged_kv_append_ref(
     target_page = page_table[rows, pos // page]  # [B]
     offset = pos % page
     return (
-        k_pages.at[target_page, offset].set(k_new),
-        v_pages.at[target_page, offset].set(v_new),
+        k_pages.at[target_page, offset].set(k_new.reshape(b, -1)),
+        v_pages.at[target_page, offset].set(v_new.reshape(b, -1)),
     )

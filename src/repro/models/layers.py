@@ -37,6 +37,10 @@ Params = Dict[str, Any]
 class PagedSpec:
     """Layout of the shared KV page pool (per attention layer).
 
+    Each attention layer's ``k_pages`` and ``v_pages`` are ``[num_pages,
+    page_size, Hkv * head_dim]``: one row per token with every kv head's
+    lanes side by side, row-major, as the paged kernels read them.
+
     ``num_pages`` counts the whole pool including page 0, which is
     reserved as a scratch page: inactive batcher slots keep an all-zero
     page table, so their masked-out garbage writes land in page 0 and can
@@ -369,10 +373,9 @@ def _scatter_to_pages(pages: jax.Array, new: jax.Array,
                       flat_idx: jax.Array) -> jax.Array:
     """Write token rows into a page pool at flat (page*size+offset) slots.
 
-    pages [P, page, Hkv, hd], new [N, Hkv, hd], flat_idx [N]."""
-    p, page = pages.shape[0], pages.shape[1]
-    flat = pages.reshape((p * page,) + pages.shape[2:])
-    flat = flat.at[flat_idx].set(new)
+    pages [P, page, Hkv*hd], new [N, Hkv*hd], flat_idx [N]."""
+    p, page, width = pages.shape
+    flat = pages.reshape(p * page, width).at[flat_idx].set(new)
     return flat.reshape(pages.shape)
 
 
@@ -427,13 +430,13 @@ def _paged_attention(
         )
         flat_idx = (page_ids * page + pos_bt % page).reshape(-1)
         k_pages = _scatter_to_pages(
-            k_pages, k.reshape(b * tq, hkv, hd), flat_idx
+            k_pages, k.reshape(b * tq, hkv * hd), flat_idx
         )
         v_pages = _scatter_to_pages(
-            v_pages, v.reshape(b * tq, hkv, hd), flat_idx
+            v_pages, v.reshape(b * tq, hkv * hd), flat_idx
         )
-        k_dense = gather_pages(k_pages, page_table)
-        v_dense = gather_pages(v_pages, page_table)
+        k_dense = gather_pages(k_pages, page_table).reshape(b, s_slot, hkv, hd)
+        v_dense = gather_pages(v_pages, page_table).reshape(b, s_slot, hkv, hd)
         kv_pos = jnp.broadcast_to(
             jnp.arange(s_slot, dtype=positions.dtype)[None, :], (b, s_slot)
         )
@@ -464,7 +467,9 @@ def init_attention_cache(
     hd = cfg.resolved_head_dim
     if paged is not None:
         n_slot = paged.pages_per_slot(max_len)
-        pool = (paged.num_pages, paged.page_size, cfg.num_kv_heads, hd)
+        # Lane-dense rows: a token's every kv head side by side, the
+        # layout the paged kernels read and write in place.
+        pool = (paged.num_pages, paged.page_size, cfg.num_kv_heads * hd)
         return {
             "k_pages": jnp.zeros(pool, dtype=dtype),
             "v_pages": jnp.zeros(pool, dtype=dtype),

@@ -217,42 +217,60 @@ def test_decode_attention_wrapper_validation():
 # ---------------------------------------------------------------------------
 
 
-def _random_paged_cache(seed, b, n_slot_pages, page, hkv, d, pool_pages):
-    """Pool tensors + a page table of distinct ids >= 1 (page 0 is the
-    reserved scratch page — real slots never map to it)."""
+def _random_paged_cache(seed, b, n_slot_pages, page, hkv, d, pool_pages,
+                        dtype=jnp.float32):
+    """Lane-dense pool tensors ``[P, page, Hkv*d]`` + a page table of
+    distinct ids >= 1 (page 0 is the reserved scratch page — real slots
+    never map to it)."""
     ks = jax.random.split(K(seed), 3)
-    k_pages = jax.random.normal(ks[0], (pool_pages, page, hkv, d))
-    v_pages = jax.random.normal(ks[1], (pool_pages, page, hkv, d))
+    shape = (pool_pages, page, hkv * d)
+    k_pages = jax.random.normal(ks[0], shape, dtype)
+    v_pages = jax.random.normal(ks[1], shape, dtype)
     perm = jax.random.permutation(ks[2], jnp.arange(1, pool_pages))
     table = perm[: b * n_slot_pages].reshape(b, n_slot_pages)
     return k_pages, v_pages, table.astype(jnp.int32)
 
 
+def _dense(pages, table, d):
+    """The gathered dense cache ``[B, S, Hkv, d]`` the dense kernel reads."""
+    dense = gather_pages(pages, table)
+    return dense.reshape(dense.shape[:2] + (-1, d))
+
+
 @pytest.mark.parametrize(
-    "kv_len,window",
+    "kv_len,window,h,hkv,d,dtype",
     [
-        ([32, 9, 0], 0),   # full budget / crossing page 1->2 / fresh slot
-        ([32, 17, 8], 6),  # sliding window straddling the 16-boundary
+        # full budget / crossing page 1->2 / fresh slot; G = 2
+        pytest.param([32, 9, 0], 0, 4, 2, 64, jnp.float32, id="kv_len0-0"),
+        # sliding window straddling the 16-boundary
+        pytest.param([32, 17, 8], 6, 4, 2, 64, jnp.float32, id="kv_len1-6"),
+        # MHA, two heads of 64 to a 128-lane chunk
+        pytest.param([32, 9, 1], 0, 4, 4, 64, jnp.float32, id="mha"),
+        pytest.param([31, 16, 3], 0, 8, 2, 128, jnp.float32, id="gqa4-d128"),
+        # a 64-lane row: the whole row is one chunk
+        pytest.param([32, 24, 5], 0, 8, 2, 32, jnp.float32, id="d32"),
+        pytest.param([32, 9, 2], 5, 8, 2, 64, jnp.bfloat16, id="bf16"),
     ],
 )
-def test_paged_decode_matches_dense_gather(kv_len, window):
+def test_paged_decode_matches_dense_gather(kv_len, window, h, hkv, d,
+                                           dtype):
     """Paged kernel == dense kernel == oracle over the gathered cache.
     The table is a random permutation, so a row's pages are scattered
     through the pool (the gather really is exercised)."""
-    b, h, hkv, d, page, n = 3, 4, 2, 64, 8, 4  # n*page = 32 tokens/slot
-    kp, vp, table = _random_paged_cache(23, b, n, page, hkv, d, 1 + b * n)
-    q = jax.random.normal(K(24), (b, h, d))
+    b, page, n = 3, 8, 4  # n*page = 32 tokens/slot
+    kp, vp, table = _random_paged_cache(23, b, n, page, hkv, d, 1 + b * n,
+                                        dtype)
+    q = jax.random.normal(K(24), (b, h, d), dtype)
     kv = jnp.asarray(kv_len, dtype=jnp.int32)
     out = paged_decode_attention(q, kp, vp, table, kv, window=window,
                                  interpret=True)
-    k_dense, v_dense = gather_pages(kp, table), gather_pages(vp, table)
-    dense = decode_attention(q, k_dense, v_dense, kv, window=window,
-                             block_k=128, interpret=True)
+    dense = decode_attention(q, _dense(kp, table, d), _dense(vp, table, d),
+                             kv, window=window, block_k=128, interpret=True)
     ref = paged_decode_attention_ref(q, kp, vp, table, kv, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
-                               rtol=1e-5, atol=1e-5)
+    tol = TOLS[dtype]
+    f32 = lambda x: np.asarray(x, dtype=np.float32)
+    np.testing.assert_allclose(f32(out), f32(ref), **tol)
+    np.testing.assert_allclose(f32(out), f32(dense), **tol)
 
 
 @settings(max_examples=8, deadline=None)
@@ -269,34 +287,45 @@ def test_paged_vs_dense_decode_property(seed):
     q = jax.random.normal(K(seed % 997), (b, h, d))
     out = paged_decode_attention(q, kp, vp, table, kv, window=window,
                                  interpret=True)
-    dense = decode_attention(q, gather_pages(kp, table),
-                             gather_pages(vp, table), kv, window=window,
-                             interpret=True)
+    dense = decode_attention(q, _dense(kp, table, d), _dense(vp, table, d),
+                             kv, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=1e-5, atol=1e-5)
 
 
 def test_paged_kv_append_matches_ref_at_page_boundaries():
-    """Append at a page's first row, last row, and mid-page; everything
-    not written stays bitwise identical (in-place aliasing is exact)."""
-    b, hkv, d, page, n = 3, 2, 64, 8, 3
-    kp, vp, table = _random_paged_cache(25, b, n, page, hkv, d, 1 + b * n)
-    ks = jax.random.split(K(26), 2)
-    kn = jax.random.normal(ks[0], (b, hkv, d))
-    vn = jax.random.normal(ks[1], (b, hkv, d))
-    pos = jnp.asarray([0, 7, 8], dtype=jnp.int32)  # start / last-of-0 / first-of-1
-    # ref first: the kernel donates (aliases) the pool buffers.
-    rk, rv = paged_kv_append_ref(kn, vn, kp, vp, table, pos)
-    k2, v2 = paged_kv_append(kn, vn, kp, vp, table, pos, interpret=True)
-    np.testing.assert_array_equal(np.asarray(k2), np.asarray(rk))
-    np.testing.assert_array_equal(np.asarray(v2), np.asarray(rv))
-    k2 = np.asarray(k2)
-    tab = np.asarray(table)
-    for row in range(b):
-        p = int(pos[row])
-        np.testing.assert_array_equal(
-            k2[tab[row, p // page], p % page], np.asarray(kn)[row]
-        )
+    """Append at a page's first row, last row, and mid-page; each new
+    row is written whole into its page, and every other row of that page
+    and every other page stays bitwise identical (in-place aliasing is
+    exact).  Rows of two heads of 64, one of 128 and three of 32."""
+    b, page, n = 4, 8, 3
+    # start / last-of-0 / first-of-1 / mid-page of 2
+    pos = jnp.asarray([0, 7, 8, 19], dtype=jnp.int32)
+    for hkv, d in [(2, 64), (1, 128), (3, 32)]:
+        kp, vp, table = _random_paged_cache(25, b, n, page, hkv, d,
+                                            1 + b * n)
+        before = {"k": np.asarray(kp), "v": np.asarray(vp)}
+        ks = jax.random.split(K(26), 2)
+        new = {"k": jax.random.normal(ks[0], (b, hkv, d)),
+               "v": jax.random.normal(ks[1], (b, hkv, d))}
+        # ref first: the kernel donates (aliases) the pool buffers.
+        rk, rv = paged_kv_append_ref(new["k"], new["v"], kp, vp, table, pos)
+        k2, v2 = paged_kv_append(new["k"], new["v"], kp, vp, table, pos,
+                                 interpret=True)
+        np.testing.assert_array_equal(np.asarray(k2), np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(v2), np.asarray(rv))
+        tab = np.asarray(table)
+        for name, after in (("k", np.asarray(k2)), ("v", np.asarray(v2))):
+            written = np.zeros(after.shape[:2], dtype=bool)
+            for row in range(b):
+                p = int(pos[row])
+                pid, off = tab[row, p // page], p % page
+                written[pid, off] = True
+                np.testing.assert_array_equal(
+                    after[pid, off], np.asarray(new[name])[row].reshape(-1)
+                )
+            np.testing.assert_array_equal(after[~written],
+                                          before[name][~written])
 
 
 def test_paged_kv_append_traced_oob_pos_lands_in_own_last_page():
@@ -321,7 +350,8 @@ def test_paged_kv_append_traced_oob_pos_lands_in_own_last_page():
     k2 = np.asarray(k2)
     tab = np.asarray(table)
     # live row 0: written exactly where expected
-    np.testing.assert_array_equal(k2[tab[0, 0], 2], np.asarray(kn)[0])
+    np.testing.assert_array_equal(k2[tab[0, 0], 2],
+                                  np.asarray(kn)[0].reshape(-1))
     # idle row 1: only the scratch page may have changed — every other
     # pool page is bitwise identical apart from row 0's single write
     untouched = [
@@ -348,11 +378,20 @@ def test_paged_wrapper_validation():
         paged_decode_attention(q, kp, vp, bad, kv, interpret=True)
     with pytest.raises(ValueError, match="page_table must be"):
         paged_decode_attention(q, kp, vp, table[0], kv, interpret=True)
+    with pytest.raises(ValueError, match=r"Hkv\*D"):
+        # the retired [P, page, Hkv, D] pool
+        paged_decode_attention(q, kp.reshape(-1, page, hkv, d),
+                               vp.reshape(-1, page, hkv, d), table, kv,
+                               interpret=True)
+    kn = jax.random.normal(K(29), (b, hkv, d))
     with pytest.raises(ValueError, match="exceeds the cache"):
         # concrete append position past the slot's table capacity
-        kn = jax.random.normal(K(29), (b, hkv, d))
         paged_kv_append(kn, kn, kp, vp, table,
                         jnp.asarray([0, n * page]), interpret=True)
+    with pytest.raises(ValueError, match=r"Hkv\*D"):
+        # a new row as wide as one head, not the pool's row
+        paged_kv_append(kn[:, :1], kn[:, :1], kp, vp, table,
+                        jnp.asarray([0, 1]), interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ prefill, its decode over dense and paged caches, and its train step.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,12 +75,17 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("hkv,groups", [(36, 1), (8, 4)])
-def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups):
-    """MiniCPM's MHA widths (36 kv heads) and a GQA layout, head_dim 64,
-    pages of 16: each block is a whole page with every kv head."""
-    b, d, page = MINICPM["slots"], 64, MINICPM["page"]
-    pool = (MINICPM["pages"], page, hkv, d)
+@pytest.mark.parametrize("hkv,groups,d", [
+    pytest.param(36, 1, 64, id="36-1"),
+    pytest.param(8, 4, 64, id="8-4"),
+    pytest.param(8, 4, 128, id="8-4-128"),
+])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups, d):
+    """MiniCPM's MHA widths (36 kv heads of 64) and GQA layouts with
+    head_dim 64 and 128, pages of 16: each block is a whole lane-dense
+    page, every kv head side by side."""
+    b, page = MINICPM["slots"], MINICPM["page"]
+    pool = (MINICPM["pages"], page, hkv * d)
     n = MINICPM["max_len"] // page
     _compile(
         paged_decode_attention_fwd,
@@ -93,7 +99,7 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups):
 
 def test_paged_kv_append_compiles_for_v5e(one_chip):
     b, hkv, d, page = MINICPM["slots"], 36, 64, MINICPM["page"]
-    pool = (MINICPM["pages"], page, hkv, d)
+    pool = (MINICPM["pages"], page, hkv * d)
     n = MINICPM["max_len"] // page
     _compile(
         paged_kv_append_fwd,
@@ -118,14 +124,11 @@ def test_tcmm_assign_compiles_for_v5e(one_chip, centroids):
     )
 
 
-def test_minicpm_full_width_paged_decode_step_compiles_for_v5e(
-    one_chip, monkeypatch
-):
+def _minicpm_decode_step(one_chip, pages: int):
     """The served decode step at MiniCPM-2B's published widths, bf16,
-    8 slots x 1024 tokens in pages of 16: it holds the Pallas kernels and
-    fits one chip.  The described chip is not the default backend, so the
-    test steers the platform check to the TPU branch itself."""
-    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    8 slots x 1024 tokens in pages of 16, compiled for one v5e.  The
+    described chip is not the default backend, so the platform check is
+    steered to the TPU branch by the calling test."""
     cfg = get_arch("minicpm-2b")
     model = build_model(cfg, compute_dtype=jnp.bfloat16,
                         param_dtype=jnp.bfloat16)
@@ -136,7 +139,7 @@ def test_minicpm_full_width_paged_decode_step_compiles_for_v5e(
         )
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    spec = PagedSpec(num_pages=MINICPM["pages"], page_size=MINICPM["page"])
+    spec = PagedSpec(num_pages=pages, page_size=MINICPM["page"])
     cache = on_chip(jax.eval_shape(
         lambda: model.init_cache(MINICPM["slots"], MINICPM["max_len"],
                                  paged=spec)
@@ -150,10 +153,58 @@ def test_minicpm_full_width_paged_decode_step_compiles_for_v5e(
         _sds(one_chip, (2,), jnp.uint32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return cfg, compiled
+
+
+def test_minicpm_full_width_paged_decode_step_compiles_for_v5e(
+    one_chip, monkeypatch
+):
+    """The decode step holds the Pallas kernels and fits one chip at
+    ``chip_smoke.py``'s 513 pages."""
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    _, compiled = _minicpm_decode_step(one_chip, MINICPM["pages"])
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one v5e"
+
+
+def test_minicpm_decode_step_keeps_the_pool_row_major(one_chip,
+                                                      monkeypatch):
+    """At the serving benchmark's 385 pages, the stacked page pools enter
+    the decode step row-major, the layout the paged kernels read, and no
+    layer's pool slice is copied or transposed on its way to or from
+    them.  A pool ``[.., P, page, Hkv, D]`` with D = 64 took the page
+    axis as its minor-most dim instead, and every layer paid a transpose
+    of its slice each way around the kernels."""
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    pages, page = 385, MINICPM["page"]
+    cfg, compiled = _minicpm_decode_step(one_chip, pages)
+    cache_formats = compiled.input_formats[0][2]
+    pools = [
+        (path, fmt) for path, fmt
+        in jax.tree_util.tree_flatten_with_path(cache_formats)[0]
+        if path[-1].key in ("k_pages", "v_pages")
+    ]
+    assert len(pools) == 2
+    for path, fmt in pools:
+        m2m = fmt.layout.major_to_minor
+        assert m2m == tuple(range(len(m2m))), (path, m2m)
+    hkv, d = cfg.num_kv_heads, cfg.resolved_head_dim
+    pool_slice = re.compile(
+        rf"\[(?:1,)?{pages},{page},(?:{hkv * d}|{hkv},{d})\]"
+    )
+    op = re.compile(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w-]+)\(")
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = op.match(line)
+        if m is None or not pool_slice.search(m.group(2)):
+            continue
+        name, kind = m.group(1), m.group(3)
+        if (kind in ("copy", "copy-start", "transpose")
+                or name.startswith(("copy", "transpose"))):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("arch", list_archs())
