@@ -1,13 +1,16 @@
 """What every cell shares: the manifest, the chip check, the compile
-cache, the compile clock, the peaks table and the metric readers."""
+cache, the compile clock, the peaks table, the architectures and the
+metric readers."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import math
 import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, List, Optional
 
 BENCH = Path(__file__).resolve().parent
@@ -119,6 +122,13 @@ def percentile(values: List[float], q: float) -> float:
     return v[lo] + (v[hi] - v[lo]) * (k - lo)
 
 
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str) -> Callable[[dict], Optional[float]]:
     """The reader of a per-layer metric: ``bench/metrics/<name>.py``'s
     ``read(ctx)``, which returns the value or None where it finds
@@ -126,10 +136,42 @@ def reader(metric: str) -> Callable[[dict], Optional[float]]:
     path = BENCH / "metrics" / f"{metric}.py"
     if not path.exists():
         raise BenchError(f"no reader {path} for per-layer metric {metric!r}")
-    spec = importlib.util.spec_from_file_location(f"metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"metric_{metric}").read
+
+
+def arch(conf: dict) -> ModuleType:
+    """The architecture of a configuration: ``bench/archs/<arch>.py``,
+    named by the file's ``arch`` key (the program's preset).  Everything
+    that depends on the model's equations lives there; the drivers call
+    only this interface:
+
+    - ``sizes(conf) -> dict`` with at least ``vocab``; ``program_arch(conf)``,
+      the program's config, refused where its sizes differ from the file's;
+    - ``program_tree(key, s, dtype)``, every weight in the program's layout
+      from ``weights.base_key(seed)`` (called under a ``jit``), and
+      ``leaf_name(path)``, a leaf's name alike in that layout and the
+      reference's;
+    - serving: ``hidden(seed, s, tokens, dtype, lowp=False)``, the plain
+      reference's final hidden states ``[N, T, D]`` and unembedding table
+      ``[V, D]`` in float32; ``decode_flops(s, rows, kv_tokens)``;
+      ``kernel_counters(s, rows, kv_tokens) -> dict``, the operations and
+      bytes its kernels' roofline readers take;
+    - training: ``train_params(seed, s)``, the reference's float32 weights;
+      ``nll_sum(params, tokens, labels, sk, lowp)``, the summed loss that
+      ``reference.train_steps`` differentiates (``sk`` is
+      ``reference.sizes_key(s)``); ``train_flops_per_token(s, seq_len)``.
+
+    One module object per file and process, so its jitted functions
+    compile once."""
+    path = BENCH / "archs" / f"{conf['arch']}.py"
+    if not path.exists():
+        raise BenchError(f"no architecture {path} for configuration {conf['name']!r}")
+    return _arch_module(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _arch_module(path: Path) -> ModuleType:
+    return _load(path, f"arch_{path.stem}")
 
 
 def log(**fields) -> None:

@@ -61,9 +61,9 @@ def train_seed(c, seed, control):
     if control:
         import reference
 
-        batches = cell.batches(D.CHECK_STEPS)
-        low = reference.train_steps(seed, cell.s, c["config"]["training"], batches, lowp=True)
-        half = reference.train_steps(seed, cell.s, c["config"]["training"], batches, half=True)
+        batches, t = cell.batches(D.CHECK_STEPS), c["config"]["training"]
+        low = reference.train_steps(cell.arch, seed, cell.s, t, batches, lowp=True)
+        half = reference.train_steps(cell.arch, seed, cell.s, t, batches, half=True)
         out["control"] = D.gaps(low, ref)
         out["fault_half_batch"] = D.gaps(half, ref)
     return out

@@ -1,4 +1,5 @@
-"""A serving cell: MiniCPM-2B behind ``ElasticServingPool`` on one chip.
+"""A serving cell: the configuration's model behind ``ElasticServingPool``
+on one chip.
 
 Set-up makes the weights on the device from the seed, builds the pool
 as the serving launcher builds it, and sends one request of each prompt
@@ -23,8 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-import costs
-import model as M
+import common
 import reference
 import traffic
 import weights as W
@@ -51,17 +51,18 @@ class ServeCell:
 
         conf, self.mix = c["config"], c["traffic"]
         self.c, self.seed, self.origin = c, seed, origin
-        self.s = M.sizes(conf)
+        self.arch = common.arch(conf)
+        self.s = self.arch.sizes(conf)
         sv = conf["serving"]
         dtype = jnp.dtype(sv["dtype"])
-        arch = M.program_arch(conf)
-        self.model = build_model(arch, compute_dtype=dtype, param_dtype=dtype)
-        params = W.program_params(seed, self.s, dtype)
+        program = self.arch.program_arch(conf)
+        self.model = build_model(program, compute_dtype=dtype, param_dtype=dtype)
+        params = W.program_params(self.arch, seed, self.s, dtype)
         want = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
         if jax.tree.structure(want) != jax.tree.structure(params) or any(
                 a.shape != b.shape for a, b in zip(jax.tree.leaves(want),
                                                    jax.tree.leaves(params))):
-            raise BenchError("bench/weights.py's layout is not the program's")
+            raise BenchError(f"bench/archs/{conf['arch']}.py's layout is not the program's")
         argv = ["--arch", conf["arch"], "--paged", "--slots", str(sv["slots"]),
                 "--max-len", str(sv["max_len"]), "--page-size", str(sv["page_size"]),
                 "--pages", str(sv["pages"]), "--max-replicas", str(sv["replicas"]),
@@ -212,7 +213,8 @@ def sample(w: dict, mix: dict, seed: int) -> List[Track]:
 
 def check(w: dict, picked: List[Track], c: dict, seed: int, control=False) -> dict:
     """The numbers that decide ``correct``, each with its limit."""
-    s = M.sizes(c["config"])
+    arch = common.arch(c["config"])
+    s = arch.sizes(c["config"])
     dtype = jnp.dtype(c["config"]["serving"]["dtype"])
     lim = c["limits"]
     done = [tr for tr in w["tracks"].values() if tr.output is not None]
@@ -221,7 +223,7 @@ def check(w: dict, picked: List[Track], c: dict, seed: int, control=False) -> di
     for j in range(0, len(picked), 8):
         part = picked[j:j + 8]
         gaps += reference.served_gaps(
-            seed, s, [np.concatenate([tr.prompt, tr.output]) for tr in part],
+            arch, seed, s, [np.concatenate([tr.prompt, tr.output]) for tr in part],
             [tr.plen for tr in part], dtype, control=control)
     served = [np.concatenate([tr.prompt[-1:], tr.output]) for tr in picked]
     log(phase="check_sample", requests=len(picked), gaps=gaps,
@@ -238,16 +240,16 @@ def check(w: dict, picked: List[Track], c: dict, seed: int, control=False) -> di
 
 
 def counters(w: dict, c: dict) -> dict:
-    """What the per-layer readers take from a serving window."""
-    s = M.sizes(c["config"])
+    """What the per-layer readers take from a serving window, the
+    architecture's kernel counters among them."""
+    arch = common.arch(c["config"])
+    s = arch.sizes(c["config"])
     return {
         "queue_wait_s": w["queue_wait_s"],
         "decode_steps": w["decode_steps"],
         "decode_rows": w["decode_rows"],
         "kv_tokens": w["kv_tokens"],
         "prefill_tokens": w["prefill_tokens"],
-        "decode_flops": costs.decode_flops(s, w["decode_rows"], w["kv_tokens"]),
-        "attn_flops": costs.paged_attention_flops(s, w["kv_tokens"]) * s["layers"],
-        "attn_bytes": costs.paged_attention_bytes(
-            s, w["kv_tokens"], w["decode_rows"], 2) * s["layers"],
+        "decode_flops": arch.decode_flops(s, w["decode_rows"], w["kv_tokens"]),
+        **arch.kernel_counters(s, w["decode_rows"], w["kv_tokens"]),
     }

@@ -1,5 +1,5 @@
-"""A training cell: MiniCPM-2B's widths cut in depth, trained by
-``TrainingJob`` over a token log, at the data parallelism of the mix.
+"""A training cell: the configuration's model, trained by ``TrainingJob``
+over a token log, at the data parallelism of the mix.
 
 Set-up writes the token log from the seed, builds the job with the
 seeded weights, and runs its first three steps through ``job.run``
@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-import model as M
+import common
 import reference
 import traffic
 import weights as W
@@ -39,14 +39,15 @@ class TrainCell:
 
         conf, mix = c["config"], c["traffic"]
         t = conf["training"]
-        self.c, self.seed, self.s = c, seed, M.sizes(conf)
+        self.arch = common.arch(conf)
+        self.c, self.seed, self.s = c, seed, self.arch.sizes(conf)
         self.dp = mix["dp"]
         if len(jax.devices()) < self.dp:
             raise BenchError(f"dp {self.dp} needs {self.dp} devices")
         self.rows = t["rows_per_chip"] * self.dp
         self.seq = t["seq_len"]
-        arch = M.program_arch(conf)
-        s, wseed = self.s, seed
+        program = self.arch.program_arch(conf)
+        s, wseed, bench_arch = self.s, seed, self.arch
 
         @dataclasses.dataclass(frozen=True)
         class SeededModel(Model):
@@ -54,9 +55,9 @@ class TrainCell:
 
             def init(self, rng):
                 del rng
-                return W.program_params(wseed, s, jnp.dtype(t["param_dtype"]))
+                return W.program_params(bench_arch, wseed, s, jnp.dtype(t["param_dtype"]))
 
-        self.model = SeededModel(arch, compute_dtype=jnp.dtype(t["compute_dtype"]),
+        self.model = SeededModel(program, compute_dtype=jnp.dtype(t["compute_dtype"]),
                                  param_dtype=jnp.dtype(t["param_dtype"]))
         self.tcfg = TrainingConfig(
             learning_rate=t["learning_rate"], weight_decay=t["weight_decay"],
@@ -74,22 +75,22 @@ class TrainCell:
         log.create_topic("tokens", 1)
         for r in self.log_rows:
             log.publish("tokens", payload=r)
-        self.job = TrainingJob(self.model, arch, self.tcfg, log,
+        self.job = TrainingJob(self.model, program, self.tcfg, log,
                                batch_size=self.rows, seq_len=self.seq, dp=self.dp,
                                max_dp=self.dp, use_mesh=True, seed=0)
         self.readings = self._first_steps()
 
     def _first_steps(self) -> dict:
-        job, b1 = self.job, self.tcfg.beta1
+        job, b1, A = self.job, self.tcfg.beta1, self.arch
         job.run(1)
         grads = jax.jit(lambda mu: {k: v / (1.0 - b1)
-                                    for k, v in W.leaf_norms(mu).items()})(
+                                    for k, v in W.leaf_norms(mu, A.leaf_name).items()})(
             job.state.opt.mu)
         job.run(CHECK_STEPS)
         key, s = W.base_key(self.seed), self.s
         pdt = jnp.dtype(self.c["config"]["training"]["param_dtype"])
         delta = jax.jit(lambda p, k: W.leaf_norms(jax.tree.map(
-            jnp.subtract, p, W.program_tree(k, s, pdt))))(job.state.params, key)
+            jnp.subtract, p, A.program_tree(k, s, pdt)), A.leaf_name))(job.state.params, key)
         offsets = {k: dict(v) for k, v in job.step_offsets.items()}
         want = {k: {0: k * self.rows} for k in range(1, CHECK_STEPS + 1)}
         return {"losses": list(job.losses[:CHECK_STEPS]),
@@ -141,7 +142,7 @@ def gaps(prog: dict, ref: dict) -> dict:
 
 
 def check(cell: "TrainCell", w: dict, c: dict, control=False, half=False) -> dict:
-    ref = reference.train_steps(cell.seed, cell.s, c["config"]["training"],
+    ref = reference.train_steps(cell.arch, cell.seed, cell.s, c["config"]["training"],
                                 cell.batches(CHECK_STEPS), lowp=control, half=half)
     g = gaps(cell.readings, ref)
     lim = c["limits"]

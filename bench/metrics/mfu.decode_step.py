@@ -1,5 +1,6 @@
-"""Model operations of the decoded rows (``costs.decode_flops``) over the
-decode-step program's device time at the chip's peak bf16 FLOP/s."""
+"""Model operations of the decoded rows (the architecture's
+``decode_flops``) over the decode-step program's device time at the
+chip's peak bf16 FLOP/s."""
 
 
 def read(ctx):

@@ -1,6 +1,7 @@
-"""Forward and backward operations per token (``costs``) times the
-tokens trained in the traced window, over the window's seconds, the
-chips and the chip's peak bf16 FLOP/s."""
+"""Forward and backward operations per token (the architecture's
+``train_flops_per_token``) times the tokens trained in the traced
+window, over the window's seconds, the chips and the chip's peak bf16
+FLOP/s."""
 
 
 def read(ctx):

@@ -1,8 +1,8 @@
 """The paged decode-attention kernel's share of its roofline: the least
 time the chip needs for the work the algorithm requires (live K/V tokens
-read once, q.k and p.v; ``costs.py``), the larger of operations over
-peak FLOP/s and bytes over peak bandwidth, over the kernel's own device
-time.  At decode the bytes bound it."""
+read once, q.k and p.v; the architecture's ``kernel_counters``), the
+larger of operations over peak FLOP/s and bytes over peak bandwidth,
+over the kernel's own device time.  At decode the bytes bound it."""
 
 KERNEL = r"paged_decode(?!.*append)"
 

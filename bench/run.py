@@ -70,7 +70,6 @@ def read_per_layer(c: dict, ctx: dict) -> dict:
 
 
 def run(c: dict, seed: int, seconds: float, trace: bool, dev: dict) -> dict:
-    import costs
     import traces as T
 
     chips = c["workload"]["chips"]
@@ -124,7 +123,7 @@ def run(c: dict, seed: int, seconds: float, trace: bool, dev: dict) -> dict:
         log(phase="check", **gaps)
         e2e = {"train_tokens_per_s": w["tokens"] / w["window_s"]}
         ctx["counters"] = {"steps": w["steps"], "tokens": w["tokens"],
-                           "flops_per_token": costs.train_flops_per_token(cell.s, cell.seq)}
+                           "flops_per_token": cell.arch.train_flops_per_token(cell.s, cell.seq)}
         attempted, failed = w["steps"], w["nonfinite"]
     else:
         raise BenchError(f"no driver for configuration kind {kind!r}")

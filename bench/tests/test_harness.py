@@ -4,15 +4,20 @@ requests, cells added by data alone, per-layer readers, whole runs."""
 import json
 import shutil
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import TraceAnnotation
 
 import smoke
 import common
+import program_spans as P
 import run
 import traffic
 import traces as T
 from serve import ServeCell, Track, end_to_end
+from test_span_readers import cpu_lines, serve_ticks, train_ticks
 
 SEED = 2**31 + 11
 
@@ -67,30 +72,53 @@ def test_tails_cover_every_request_with_unfinished_as_missing():
     assert e["output_tokens_per_s"] == pytest.approx(1.0)
 
 
-def fake_ctx():
-    ms = 1_000_000
-    dev = T.Device(ops=[("_paged_decode_kernel.3", 0, 2 * ms), ("fusion.1", 2 * ms, 3 * ms),
-                        ("all-reduce.1", 6 * ms, 1 * ms)],
-                   modules=[("jit_decode_step(9)", 0, 5 * ms), ("jit_prefill_step(2)", 5 * ms, 1 * ms),
-                            ("jit_train_step(3)", 6 * ms, 2 * ms)])
-    s = T.Summary(window=(0, 10 * ms), devices=[dev], spans=[])
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """A trace recorded on the CPU whose window holds serving ticks and
+    training steps under the program's span names."""
+    d = str(tmp_path_factory.mktemp("trace"))
+    f = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    with T.capture(d):
+        with TraceAnnotation(T.WINDOW):
+            serve_ticks(f, x, 3)
+            train_ticks(f, x, 2)
+    return d, T.reduce(d, classify=cpu_lines)
+
+
+@pytest.fixture
+def fake_ctx(recorded, monkeypatch):
+    """The recorded window and its program spans, with the device's
+    kernels and programs made by hand at its start (the CPU runs none of
+    the chip's), and counters as a window would give them."""
+    trace_dir, tr = recorded
+    monkeypatch.setattr(P, "TRACES", trace_dir)
+    ms, lo = 1_000_000, tr.window[0]
+    at = lambda evs: [(n, lo + s, d) for n, s, d in evs]  # noqa: E731
+    dev = T.Device(ops=at([("_paged_decode_kernel.3", 0, 2 * ms), ("fusion.1", 2 * ms, 3 * ms),
+                           ("all-reduce.1", 6 * ms, 1 * ms)]),
+                   modules=at([("jit_decode_step(9)", 0, 5 * ms),
+                               ("jit_prefill_step(2)", 5 * ms, 1 * ms),
+                               ("jit_train_step(3)", 6 * ms, 2 * ms)]))
+    s = T.Summary(window=tr.window, devices=[dev], spans=tr.spans)
     return {"trace": s, "peaks": common.peaks("TPU v5 lite"), "chips": 1, "compile_s": 3.0,
-            "memory_peak_bytes": 13e9, "window_s": 0.01, "end_to_end": {"ttft_p95_ms": 690.0},
+            "memory_peak_bytes": 13e9, "window_s": tr.window_s,
+            "end_to_end": {"ttft_p95_ms": 690.0},
             "counters": {"queue_wait_s": [0.1, 0.2, 0.3], "decode_steps": 2, "decode_rows": 10,
                          "prefill_tokens": 500, "decode_flops": 1e9, "attn_flops": 1e6,
                          "attn_bytes": 1e6, "steps": 1, "tokens": 2048, "flops_per_token": 1e7}}
 
 
-def test_every_per_layer_metric_has_a_reader_that_reads():
+def test_every_per_layer_metric_has_a_reader_that_reads(fake_ctx):
     man = common.manifest()
-    ctx = fake_ctx()
     for m in man["per_layer"]:
-        v = common.reader(m["name"])(ctx)
+        v = common.reader(m["name"])(fake_ctx)
         assert isinstance(v, float) and v > 0, m["name"]
 
 
-def test_readers_find_nothing_in_an_empty_window():
-    ctx = fake_ctx()
+def test_readers_find_nothing_in_an_empty_window(fake_ctx):
+    ctx = fake_ctx
     ctx["trace"] = T.Summary(window=(0, 1), devices=[T.Device(ops=[("x", 0, 1)])], spans=[])
     ctx["counters"], ctx["end_to_end"] = {}, {}
     for name in ("decode_step_ms", "prefill_ms_per_ktok", "paged_decode_attention_roofline",

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 import smoke  # noqa: F401  (puts bench/ and src/ on the path)
-import model as M
+import common
 import reference
 import weights as W
 
@@ -21,57 +21,59 @@ def setup():
     from repro.models.zoo import build_model
 
     c = smoke.cell("minicpm-2b-train.dp1")
-    s = M.sizes(c["config"])
-    arch = M.program_arch(c["config"])
-    prog = build_model(arch, compute_dtype=jnp.float32, param_dtype=jnp.float32)
-    params = W.program_params(SEED, s, jnp.float32)
+    A = common.arch(c["config"])
+    s = A.sizes(c["config"])
+    prog = build_model(A.program_arch(c["config"]), compute_dtype=jnp.float32,
+                       param_dtype=jnp.float32)
+    params = W.program_params(A, SEED, s, jnp.float32)
     tokens = np.random.default_rng(0).integers(3, s["vocab"], (2, 40)).astype(np.int32)
-    return s, prog, params, tokens
+    return A, s, prog, params, tokens
 
 
 def test_layout_matches_program(setup):
-    s, prog, params, _ = setup
+    _, s, prog, params, _ = setup
     want = jax.eval_shape(prog.init, jax.random.PRNGKey(0))
     assert jax.tree.structure(want) == jax.tree.structure(params)
     assert [a.shape for a in jax.tree.leaves(want)] == [a.shape for a in jax.tree.leaves(params)]
 
 
 def test_forward_logits_match_program(setup):
-    s, prog, params, tokens = setup
+    A, s, prog, params, tokens = setup
     with jax.default_matmul_precision("highest"):
         want, _ = prog.train_logits(params, {"tokens": jnp.asarray(tokens)})
-    h, tok = reference.hidden(SEED, s, tokens, jnp.float32)
+    h, tok = A.hidden(SEED, s, tokens, jnp.float32)
     got = np.asarray(jnp.einsum("ntd,vd->ntv", h, tok, precision="highest"))
     want = np.asarray(want)
     assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
 
 
 def test_served_gap_is_zero_for_reference_argmax(setup):
-    s, _, _, tokens = setup
-    h, tok = reference.hidden(SEED, s, tokens[:1, :20], jnp.float32)
+    A, s, _, _, tokens = setup
+    h, tok = A.hidden(SEED, s, tokens[:1, :20], jnp.float32)
     greedy = list(tokens[0, :20])
     lg = np.asarray(jnp.einsum("d,vd->v", h[0, -1], tok, precision="highest"))
     greedy.append(int(lg.argmax()))
-    gaps = reference.served_gaps(SEED, s, [greedy], [20], jnp.float32)
+    gaps = reference.served_gaps(A, SEED, s, [greedy], [20], jnp.float32)
     assert gaps[0] == pytest.approx(0.0, abs=1e-6)
     wrong = greedy[:-1] + [int(lg.argmin())]
-    got = reference.served_gaps(SEED, s, [wrong], [20], jnp.float32)[0]
+    got = reference.served_gaps(A, SEED, s, [wrong], [20], jnp.float32)[0]
     assert got == pytest.approx(float((lg.max() - lg.min()) / lg.std()), rel=1e-4)
 
 
 def test_loss_and_gradients_match_program(setup):
-    s, prog, params, tokens = setup
+    A, s, prog, params, tokens = setup
     batch = {"tokens": jnp.asarray(tokens[:, :-1]), "labels": jnp.asarray(tokens[:, 1:])}
     with jax.default_matmul_precision("highest"):
         (loss, _), grads = jax.value_and_grad(prog.loss_fn, has_aux=True)(params, batch)
-    rp = reference.train_params(SEED, s)
+    rp = A.train_params(SEED, s)
     n = tokens[:, 1:].size
     with jax.default_matmul_precision("highest"):
-        rloss, rgrads = jax.value_and_grad(reference._nll_sum)(
-            rp, batch["tokens"], batch["labels"], reference._sizes_key(s), False)
+        rloss, rgrads = jax.value_and_grad(A.nll_sum)(
+            rp, batch["tokens"], batch["labels"], reference.sizes_key(s), False)
     assert float(rloss) / n == pytest.approx(float(loss), rel=1e-5)
-    got = {k: float(v) for k, v in W.leaf_norms(jax.tree.map(lambda g: g / n, rgrads)).items()}
-    want = {k: float(v) for k, v in W.leaf_norms(grads).items()}
+    got = {k: float(v) for k, v in W.leaf_norms(jax.tree.map(lambda g: g / n, rgrads),
+                                                A.leaf_name).items()}
+    want = {k: float(v) for k, v in W.leaf_norms(grads, A.leaf_name).items()}
     assert set(got) == set(want)
     for k in want:
         assert got[k] == pytest.approx(want[k], rel=1e-4), k
