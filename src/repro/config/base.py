@@ -20,6 +20,7 @@ class AttentionKind(str, Enum):
     SLIDING = "sliding"          # sliding-window (SWA)
     NONE = "none"                # attention-free (SSM layer)
     CROSS = "cross"              # encoder-decoder cross attention
+    LATENT = "latent"            # multi-head latent attention (MLA)
 
 
 class FFNKind(str, Enum):
@@ -36,6 +37,65 @@ class MoEConfig:
     router_jitter: float = 0.0
     # Auxiliary load-balance loss weight (Switch-style).
     aux_loss_weight: float = 0.01
+    # Width of one routed expert; 0 -> the config's d_ff.
+    expert_ff: int = 0
+    # Shared experts, every token through them, held as one SwiGLU of
+    # width shared_experts * expert_ff (DeepSeek-MoE).
+    shared_experts: int = 0
+    # Whether the top-k weights are renormalised to sum to 1, and the
+    # factor the routed sum is scaled by.
+    renormalize: bool = True
+    routed_scale: float = 1.0
+    # The routed experts this chip holds (expert parallelism): experts
+    # first_held .. first_held + held_experts - 1 of num_experts.  The
+    # router still scores all num_experts; held_experts > 0 selects the
+    # dropless share path (models.moe.moe_share), which computes only the
+    # held experts' part.  0: every expert, capacity dispatch (Mixtral).
+    held_experts: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.held_experts:
+            if self.capacity_factor > 0:
+                raise ValueError("the expert-share path is dropless: "
+                                 "capacity_factor must be <= 0")
+            if not 0 <= self.first_held <= self.num_experts - self.held_experts:
+                raise ValueError(
+                    f"held experts {self.first_held}..+{self.held_experts} "
+                    f"lie outside the {self.num_experts} routed experts")
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values are
+    up-projected per head from one shared latent of ``kv_lora_rank``,
+    which is what the cache holds, beside one rope key shared by every
+    head.  A query head is ``qk_nope_head_dim + qk_rope_head_dim`` wide."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+    def __post_init__(self):
+        # YaRN scales cos and sin by mscale(mscale) / mscale(mscale_all_dim)
+        # and the softmax by mscale(mscale_all_dim)^2; the program
+        # implements the second alone, which is exact where they are equal.
+        if self.mscale != self.mscale_all_dim:
+            raise ValueError("YaRN with mscale != mscale_all_dim is not "
+                             "implemented")
 
 
 @dataclass(frozen=True)
@@ -70,9 +130,14 @@ class ArchConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0                # 0 -> d_model // num_heads
-    # Depth pattern: layer i uses pattern[i % len(pattern)]. Default: all-FULL.
+    # Depth: the ``lead`` layers first (e.g. DeepSeek's leading dense
+    # layer), then layer len(lead) + i uses pattern[i % len(pattern)].
+    # Default: all-FULL.
     pattern: Tuple[LayerSpec, ...] = (LayerSpec(),)
+    lead: Tuple[LayerSpec, ...] = ()
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnScaling] = None
     mamba: Optional[MambaConfig] = None
     # Encoder (enc-dec archs only).
     encoder_layers: int = 0
@@ -98,7 +163,14 @@ class ArchConfig:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
 
     def layer_spec(self, i: int) -> LayerSpec:
-        return self.pattern[i % len(self.pattern)]
+        if i < len(self.lead):
+            return self.lead[i]
+        return self.pattern[(i - len(self.lead)) % len(self.pattern)]
+
+    @property
+    def expert_ff(self) -> int:
+        """Width of one routed expert."""
+        return (self.moe.expert_ff or self.d_ff) if self.moe else self.d_ff
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks)."""
@@ -108,7 +180,17 @@ class ArchConfig:
         kv = 2 * d * self.num_kv_heads * hd
         o = self.num_heads * hd * d
         attn = q + kv + o
+        if self.mla is not None:
+            m = self.mla
+            qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+            latent_attn = (d * self.num_heads * qk
+                           + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                           + m.kv_lora_rank
+                           + m.kv_lora_rank * self.num_heads
+                           * (m.qk_nope_head_dim + m.v_head_dim)
+                           + self.num_heads * m.v_head_dim * d)
         dense_ffn = 3 * d * ff  # gated (SwiGLU)
+        expert_ffn = 3 * d * self.expert_ff
         total = v * d * (1 if self.tie_embeddings else 2)
         for i in range(self.num_layers):
             spec = self.layer_spec(i)
@@ -119,10 +201,13 @@ class ArchConfig:
                 total += d * (2 * d_in + 2 * m.d_state)  # in_proj-ish
                 total += d_in * d  # out proj
                 total += nheads * m.d_state * m.head_dim // max(nheads, 1)
+            elif spec.attention == AttentionKind.LATENT:
+                total += latent_attn
             elif spec.attention != AttentionKind.NONE:
                 total += attn
             if spec.ffn == FFNKind.MOE and self.moe is not None:
-                total += self.moe.num_experts * dense_ffn + d * self.moe.num_experts
+                total += self.moe.num_experts * expert_ffn + d * self.moe.num_experts
+                total += self.moe.shared_experts * expert_ffn
             elif spec.ffn == FFNKind.DENSE:
                 total += dense_ffn
             total += 2 * d  # norms
@@ -135,8 +220,7 @@ class ArchConfig:
         """Parameters touched per token (MoE: only top_k experts)."""
         if self.moe is None:
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        dense_ffn = 3 * d * ff
+        dense_ffn = 3 * self.d_model * self.expert_ff
         inactive_experts = self.moe.num_experts - self.moe.top_k
         n_moe_layers = sum(
             1

@@ -12,5 +12,6 @@ from repro.configs import (  # noqa: F401
     jamba_v0_1_52b,
     whisper_tiny,
     mamba2_370m,
+    deepseek_v2_lite,
 )
 from repro.configs.tcmm import TCMMConfig  # noqa: F401

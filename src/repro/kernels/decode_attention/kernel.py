@@ -38,6 +38,19 @@ row into its page in place (``input_output_aliases``), so the per-tick
 cache update is O(1) rows, not an O(S) re-materialization.  The dense
 kernel above stays the bitwise reference path (the ``vectorize=False``
 pattern of the vectorized control plane).
+
+``paged_latent_decode`` serves multi-head latent attention (MLA): the
+pool holds one row per token, the latent then the shared rope key,
+zero-padded to whole lane tiles (``[P, page, W]``, W = 640 for 512 + 64),
+and every one of the H query heads is a latent-space query of W lanes
+(``q_nope W_UK^T`` joined to ``q_rope``).  Scores are ``q @ rows^T`` over
+all W lanes, values the rows' first V lanes, the output ``[B, H, V]``.
+With one row shared by all H heads the kernel does some 30 FLOP a byte,
+so a grid step takes a block of ``pages_per_block`` pages (512 tokens),
+each its own page-table-gathered operand, to amortise the step's fixed
+cost; a block past the live length is skipped, and its scratch-page
+operands are not fetched again.  Its append reuses ``paged_kv_append``'s
+kernel on the one pool.
 """
 
 from __future__ import annotations
@@ -342,17 +355,14 @@ def paged_decode_attention_fwd(
 def _kv_append_kernel(
     pt_ref,      # scalar prefetch [B, n_pages] int32
     pos_ref,     # scalar prefetch [B] int32 (write position per sequence)
-    k_new_ref,   # [1, 1, Hkv*D]
-    v_new_ref,   # [1, 1, Hkv*D]
-    k_page_ref,  # [1, page, Hkv*D] aliased in/out (the target page)
-    v_page_ref,  # [1, page, Hkv*D] aliased in/out
-    ko_ref,
-    vo_ref,
-    *,
+    *refs,       # n new rows [1, 1, W], n target pages [1, page, W]
+                 # aliased in/out, then the n output pages
     page_size: int,
 ):
     del pt_ref  # consumed by the index maps
     ib = pl.program_id(0)
+    n = len(refs) // 3
+    new, pages, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
     # ``input_output_aliases`` is XLA buffer donation, not window
     # initialization: on TPU the Mosaic output windows are write-only and
     # start undefined (interpret mode happens to seed them from the
@@ -361,26 +371,17 @@ def _kv_append_kernel(
     # page, with the one row this token owns replaced.
     row = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
     mine = row == pos_ref[ib] % page_size
-    ko_ref[0] = jnp.where(mine, k_new_ref[0], k_page_ref[0])
-    vo_ref[0] = jnp.where(mine, v_new_ref[0], v_page_ref[0])
+    for new_ref, page_ref, out_ref in zip(new, pages, outs):
+        out_ref[0] = jnp.where(mine, new_ref[0], page_ref[0])
 
 
-def paged_kv_append_fwd(
-    k_new: jax.Array,       # [B, Hkv, D] this tick's keys
-    v_new: jax.Array,       # [B, Hkv, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv*D]
-    v_pages: jax.Array,     # [P, page_size, Hkv*D]
-    page_table: jax.Array,  # [B, n_pages] int32
-    pos: jax.Array,         # [B] int32 write positions (== kv_len pre-append)
-    interpret: bool = False,
-) -> "tuple[jax.Array, jax.Array]":
-    b = k_new.shape[0]
-    page_size, width = k_pages.shape[1], k_pages.shape[2]
+def _append_rows(rows, pools, page_table, pos, interpret):
+    """Write row ``b`` of each ``rows[i]`` ``[B, 1, W_i]`` into pool
+    ``pools[i]`` ``[P, page, W_i]`` at sequence b's position, in place."""
+    b = rows[0].shape[0]
+    page_size = pools[0].shape[1]
     n_pages = page_table.shape[1]
-    # One lane-dense row per sequence, in the pool's dtype.
-    k_row = k_new.reshape(b, 1, width).astype(k_pages.dtype)
-    v_row = v_new.reshape(b, 1, width).astype(v_pages.dtype)
-
+    n = len(pools)
     kernel = functools.partial(_kv_append_kernel, page_size=page_size)
     # One grid step per sequence; the index map routes both the aliased
     # input block and the output block to the page owning position
@@ -395,32 +396,179 @@ def paged_kv_append_fwd(
     page_idx = lambda b_, pt, ps: (
         pt[b_, jnp.minimum(ps[b_] // page_size, n_pages - 1)], 0, 0
     )
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, 1, width), lambda b_, pt, ps: (b_, 0, 0)),
-            pl.BlockSpec((1, 1, width), lambda b_, pt, ps: (b_, 0, 0)),
-            pl.BlockSpec((1, page_size, width), page_idx),
-            pl.BlockSpec((1, page_size, width), page_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, page_size, width), page_idx),
-            pl.BlockSpec((1, page_size, width), page_idx),
-        ],
+        in_specs=[pl.BlockSpec((1, 1, r.shape[2]), lambda b_, pt, ps: (b_, 0, 0))
+                  for r in rows]
+        + [pl.BlockSpec((1, page_size, p.shape[2]), page_idx) for p in pools],
+        out_specs=[pl.BlockSpec((1, page_size, p.shape[2]), page_idx)
+                   for p in pools],
     )
-
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        # Operand indices count the scalar-prefetch args: 2, 3 are k_new,
-        # v_new; 4, 5 the page pools — aliased so the update is in place.
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # Operand indices count the two scalar-prefetch args, then the n
+        # new rows: the pools follow, aliased so the update is in place.
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32),
-      k_row, v_row, k_pages, v_pages)
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), *rows, *pools)
+
+
+def paged_kv_append_fwd(
+    k_new: jax.Array,       # [B, Hkv, D] this tick's keys
+    v_new: jax.Array,       # [B, Hkv, D]
+    k_pages: jax.Array,     # [P, page_size, Hkv*D]
+    v_pages: jax.Array,     # [P, page_size, Hkv*D]
+    page_table: jax.Array,  # [B, n_pages] int32
+    pos: jax.Array,         # [B] int32 write positions (== kv_len pre-append)
+    interpret: bool = False,
+) -> "tuple[jax.Array, jax.Array]":
+    b = k_new.shape[0]
+    width = k_pages.shape[2]
+    # One lane-dense row per sequence, in the pool's dtype.
+    k_row = k_new.reshape(b, 1, width).astype(k_pages.dtype)
+    v_row = v_new.reshape(b, 1, width).astype(v_pages.dtype)
+    k_pages, v_pages = _append_rows((k_row, v_row), (k_pages, v_pages),
+                                    page_table, pos, interpret)
+    return k_pages, v_pages
+
+
+# ---------------------------------------------------------------------------
+# paged latent decode (MLA): one latent row per token, every head on it
+# ---------------------------------------------------------------------------
+
+
+def _paged_latent_decode_kernel(
+    pt_ref,      # scalar prefetch [B, n_blocks * pages_per_block] int32
+    kv_len_ref,  # scalar prefetch [B] int32
+    q_ref,       # [1, H, W] latent-space queries (absorbed q, rope q, 0)
+    *refs,       # pages_per_block pages [1, page, W], then o_ref [1, H, V],
+                 # then scratch m, l [H, LANE] f32 and acc [H, V] f32
+    sm_scale: float,
+    page_size: int,
+    pages_per_block: int,
+    value_dim: int,
+):
+    del pt_ref  # consumed by the index maps
+    pages = refs[:pages_per_block]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages_per_block:]
+    ib, ik = pl.program_id(0), pl.program_id(1)
+    tokens = page_size * pages_per_block
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kv_len = kv_len_ref[ib]
+
+    # Blocks at or past the live length are skipped (their table entries
+    # are the scratch page, fetched once and not again).
+    @pl.when(ik * tokens < kv_len)
+    def _update():
+        # The block's pages stacked row-major: [tokens, W].  Every head
+        # scores the whole row (latent and rope lanes at once, the
+        # padding lanes zero in q); values are the row's first V lanes.
+        rows = jnp.concatenate([p[0] for p in pages], axis=0)
+        q = q_ref[0]
+        precision = (jax.lax.Precision.HIGHEST
+                     if rows.dtype == jnp.float32 else None)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        ) * sm_scale  # [H, tokens]
+        k_pos = ik * tokens + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = k_pos < kv_len
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(live, jnp.exp(s - m_cur), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :value_dim],
+            preferred_element_type=jnp.float32, precision=precision,
+        )
+        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+
+    @pl.when(ik == pl.num_programs(1) - 1)
+    def _finalize():
+        denom = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def latent_pages_per_block(page_size: int) -> int:
+    """Pages one grid step of the latent kernel reads: 512 tokens' rows.
+    Fewer, larger steps: a step costs about a third of a microsecond
+    whatever it holds, and 16 heads over 512 rows is still a small MXU
+    pass."""
+    return max(1, 512 // page_size)
+
+
+def paged_latent_decode_fwd(
+    q: jax.Array,           # [B, H, W]
+    pages: jax.Array,       # [P, page_size, W] latent rows
+    page_table: jax.Array,  # [B, n_pages] int32
+    kv_len: jax.Array,      # [B] int32
+    value_dim: int,
+    sm_scale: float,
+    pages_per_block: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    b, h, w = q.shape
+    page_size = pages.shape[1]
+    ppb = pages_per_block or latent_pages_per_block(page_size)
+    n_pages = page_table.shape[1]
+    ppb = min(ppb, n_pages)
+    n_blocks = -(-n_pages // ppb)
+    # Unallocated table entries are 0, the scratch page.
+    table = jnp.pad(page_table.astype(jnp.int32),
+                    ((0, 0), (0, n_blocks * ppb - n_pages)))
+    kernel = functools.partial(
+        _paged_latent_decode_kernel, sm_scale=sm_scale, page_size=page_size,
+        pages_per_block=ppb, value_dim=value_dim,
+    )
+
+    def page_spec(j):
+        # The page-table gather: page j of block ik of sequence b_ lives
+        # in pool page pt[b_, ik * ppb + j], resolved at DMA-schedule time.
+        return pl.BlockSpec(
+            (1, page_size, w),
+            lambda b_, ik, pt, kl: (pt[b_, ik * ppb + j], 0, 0),
+        )
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_blocks),
+        in_specs=[pl.BlockSpec((1, h, w), lambda b_, ik, pt, kl: (b_, 0, 0))]
+        + [page_spec(j) for j in range(ppb)],
+        out_specs=pl.BlockSpec((1, h, value_dim),
+                               lambda b_, ik, pt, kl: (b_, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, LANE), jnp.float32),
+            pltpu.VMEM((h, LANE), jnp.float32),
+            pltpu.VMEM((h, value_dim), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        interpret=interpret,
+    )(table, kv_len.astype(jnp.int32), q, *([pages] * ppb))
+
+
+def paged_latent_append_fwd(
+    row: jax.Array,         # [B, W] this tick's latent rows
+    pages: jax.Array,       # [P, page_size, W]
+    page_table: jax.Array,  # [B, n_pages] int32
+    pos: jax.Array,         # [B] int32 write positions
+    interpret: bool = False,
+) -> jax.Array:
+    b, w = row.shape
+    (pages,) = _append_rows((row.reshape(b, 1, w).astype(pages.dtype),),
+                            (pages,), page_table, pos, interpret)
+    return pages

@@ -31,6 +31,8 @@ from repro.kernels.decode_attention.kernel import (
     decode_attention_fwd,
     paged_decode_attention_fwd,
     paged_kv_append_fwd,
+    paged_latent_append_fwd,
+    paged_latent_decode_fwd,
 )
 from repro.kernels.platform import resolve_interpret
 
@@ -216,3 +218,90 @@ def paged_kv_append(
         k_new, v_new, k_pages, v_pages, page_table, pos,
         interpret=resolve_interpret(interpret),
     )
+
+
+# ---------------------------------------------------------------------------
+# paged latent (MLA) wrappers
+# ---------------------------------------------------------------------------
+
+
+def _check_paged(name, b, pages, page_table, lens, upper_extra):
+    if page_table.ndim != 2 or page_table.shape[0] != b:
+        raise ValueError(
+            f"page_table must be [B, n_pages], got {page_table.shape} "
+            f"for batch {b}"
+        )
+    n_pages, page_size = page_table.shape[1], pages.shape[1]
+    lens = _require_int(name, lens)
+    page_table = _require_int("page_table", page_table)
+    _check_concrete_range(name, lens, n_pages * page_size - upper_extra)
+    _check_concrete_range("page_table", page_table, pages.shape[0] - 1)
+    # traced values: defensive clamps (the jitted serving path)
+    lens = jnp.clip(lens, 0, n_pages * page_size - upper_extra)
+    page_table = jnp.clip(page_table, 0, pages.shape[0] - 1)
+    return lens, page_table
+
+
+@functools.partial(
+    jax.jit, static_argnames=("value_dim", "sm_scale", "interpret")
+)
+def _paged_latent_decode_jit(q, pages, page_table, kv_len, value_dim,
+                             sm_scale, interpret):
+    return paged_latent_decode_fwd(
+        q, pages, page_table, kv_len, value_dim=value_dim,
+        sm_scale=sm_scale, interpret=interpret,
+    )
+
+
+def paged_latent_decode(
+    q: jax.Array,           # [B, H, W]
+    pages: jax.Array,       # [P, page_size, W]
+    page_table: jax.Array,  # [B, n_pages] int32
+    kv_len: jax.Array,      # [B]
+    value_dim: int,
+    sm_scale: float,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """One latent-space query per head and sequence against the latent
+    page pool: scores over each row's W lanes, the output the weighted
+    sum of its first ``value_dim`` lanes, ``[B, H, value_dim]``."""
+    if q.ndim != 3 or pages.ndim != 3 or q.shape[2] != pages.shape[2]:
+        raise ValueError(
+            f"q must be [B, H, W] and pages [P, page_size, W], got "
+            f"{q.shape} and {pages.shape}"
+        )
+    if not 0 < value_dim <= q.shape[2]:
+        raise ValueError(f"value_dim {value_dim} outside the row's "
+                         f"{q.shape[2]} lanes")
+    kv_len, page_table = _check_paged("kv_len", q.shape[0], pages,
+                                      page_table, kv_len, 0)
+    return _paged_latent_decode_jit(
+        q, pages, page_table, kv_len, value_dim=value_dim,
+        sm_scale=float(sm_scale), interpret=resolve_interpret(interpret),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _latent_append_jit(row, pages, page_table, pos, interpret):
+    return paged_latent_append_fwd(row, pages, page_table, pos,
+                                   interpret=interpret)
+
+
+def paged_latent_append(
+    row: jax.Array,         # [B, W]
+    pages: jax.Array,       # [P, page_size, W]
+    page_table: jax.Array,  # [B, n_pages] int32
+    pos: jax.Array,         # [B] write positions (kv_len before append)
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Write each sequence's new latent row into its page, in place; an
+    idle slot's runaway position is clamped into its own last table
+    entry, as ``paged_kv_append`` clamps it."""
+    if row.ndim != 2 or row.shape[1] != pages.shape[2]:
+        raise ValueError(
+            f"row must be [B, W] for pages {pages.shape}, got {row.shape}"
+        )
+    pos, page_table = _check_paged("pos", row.shape[0], pages, page_table,
+                                   pos, 1)
+    return _latent_append_jit(row, pages, page_table, pos,
+                              interpret=resolve_interpret(interpret))

@@ -92,3 +92,37 @@ def paged_kv_append_ref(
         k_pages.at[target_page, offset].set(k_new.reshape(b, -1)),
         v_pages.at[target_page, offset].set(v_new.reshape(b, -1)),
     )
+
+
+def paged_latent_decode_ref(
+    q: jax.Array,           # [B, H, W] latent-space queries
+    pages: jax.Array,       # [P, page, W] latent rows
+    page_table: jax.Array,  # [B, n] int32
+    kv_len: jax.Array,      # [B]
+    value_dim: int,
+    sm_scale: float,
+) -> jax.Array:
+    """Every head's query scored against each live row's W lanes, the
+    output the softmax-weighted sum of the rows' first ``value_dim``
+    lanes: ``[B, H, value_dim]``, in float32 arithmetic."""
+    rows = gather_pages(pages, page_table).astype(jnp.float32)  # [B, S, W]
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), rows,
+                   precision=jax.lax.Precision.HIGHEST) * sm_scale
+    live = (jnp.arange(rows.shape[1])[None, :] < kv_len[:, None])[:, None, :]
+    p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+    out = jnp.einsum("bhs,bsv->bhv", p, rows[..., :value_dim],
+                     precision=jax.lax.Precision.HIGHEST)
+    out = jnp.where(live.any(axis=-1)[..., None], out, 0.0)
+    return out.astype(q.dtype)
+
+
+def paged_latent_append_ref(
+    row: jax.Array,         # [B, W]
+    pages: jax.Array,       # [P, page, W]
+    page_table: jax.Array,  # [B, n] int32
+    pos: jax.Array,         # [B] write positions
+) -> jax.Array:
+    page = pages.shape[1]
+    rows = jnp.arange(row.shape[0])
+    return pages.at[page_table[rows, pos // page], pos % page].set(
+        row.astype(pages.dtype))

@@ -98,7 +98,12 @@ def pool_kwargs(args, cluster=None) -> dict:
         ingress_capacity=args.ingress_capacity,
         overflow=args.overflow,
         policy=args.policy,
+        # A replica's decode batch and page pool are sized for all its
+        # slots whatever its cap, so a cap below them only queues work
+        # beside idle slots: the pool keeps one whole replica open and
+        # scales by replicas.  --spike shows the slot ramp from one.
         autoscaler=AutoscalerConfig(high_watermark=4.0, low_watermark=0.5,
+                                    min_workers=1 if args.spike else args.slots,
                                     cooldown=0.0, step_fraction=1.0),
         heartbeat_timeout=5.0,
     )

@@ -1,5 +1,6 @@
-"""Core layer library: RMSNorm, RoPE, GQA attention (full / sliding /
-cross, with KV cache), SwiGLU MLP, embeddings.
+"""Core layer library: RMSNorm, RoPE (with YaRN scaling), GQA attention
+(full / sliding / cross, with KV cache), multi-head latent attention
+over a latent cache, SwiGLU MLP, embeddings.
 
 Pure functions over param pytrees.  Activations are annotated with
 *logical* axis names via ``repro.distributed.shard`` — no-ops on a single
@@ -21,13 +22,17 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.config.base import ArchConfig, AttentionKind, LayerSpec
+import numpy as np
+
+from repro.config.base import ArchConfig, AttentionKind, LayerSpec, YarnScaling
 from repro.distributed.sharding import shard
 from repro.kernels import platform
 from repro.kernels.decode_attention import (
     gather_pages,
     paged_decode_attention,
     paged_kv_append,
+    paged_latent_append,
+    paged_latent_decode,
 )
 
 Params = Dict[str, Any]
@@ -114,14 +119,42 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     return (out * (1.0 + weight.astype(jnp.float32))).astype(dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 m ln(s) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(theta: float, dim: int, y: YarnScaling) -> np.ndarray:
+    """YaRN's rotary frequencies (DeepSeek-V2's ``yarn`` rope scaling):
+    the base frequencies where a dimension turns more than ``beta_fast``
+    times over the original length, those divided by ``factor`` where it
+    turns fewer than ``beta_slow`` times, a linear ramp between."""
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns(rotations: float) -> float:
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns(y.beta_fast)), 0)
+    high = min(math.ceil(turns(y.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (base / y.factor * ramp + base * (1.0 - ramp)).astype(np.float32)
+
+
 def rope(
-    x: jax.Array, positions: jax.Array, theta: float, head_dim: int
+    x: jax.Array, positions: jax.Array, theta: float, head_dim: int,
+    scaling: Optional[YarnScaling] = None,
 ) -> jax.Array:
-    """Rotary embedding. x: [B, T, H, D], positions: [B, T]."""
+    """Rotary embedding, rotating the two halves of each head.
+    x: [B, T, H, D], positions: [B, T]."""
     half = head_dim // 2
-    freqs = jnp.exp(
-        -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
-    )
+    if scaling is None:
+        freqs = jnp.exp(
+            -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
+        )
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(theta, head_dim, scaling))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,half]
     cos = jnp.cos(angles)[:, :, None, :]  # [B,T,1,half]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -379,6 +412,23 @@ def _scatter_to_pages(pages: jax.Array, new: jax.Array,
     return flat.reshape(pages.shape)
 
 
+def _flat_slots(page_table: jax.Array, cache_pos: jax.Array, tq: int,
+                page: int) -> jax.Array:
+    """Flat pool rows (page * size + offset) of each row's next ``tq``
+    positions through its page table, ``[B * tq]``; positions past the
+    table land in the scratch page 0."""
+    b, n_slot = page_table.shape
+    rows = jnp.arange(b)
+    pos_bt = cache_pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    in_range = pos_bt < n_slot * page
+    page_ids = jnp.where(
+        in_range,
+        page_table[rows[:, None], jnp.clip(pos_bt // page, 0, n_slot - 1)],
+        0,
+    )
+    return (page_ids * page + pos_bt % page).reshape(-1)
+
+
 def _paged_attention(
     q: jax.Array,  # [B, Tq, H, hd] (post-rope)
     k: jax.Array,  # [B, Tq, Hkv, hd] (post-rope)
@@ -420,15 +470,7 @@ def _paged_attention(
         # Scatter the chunk through the page tables (prefill, or the
         # softcap / jnp decode path), then attend over the gathered
         # dense view of each slot's pages.
-        rows = jnp.arange(b)
-        pos_bt = cache_pos[:, None] + jnp.arange(tq, dtype=jnp.int32)[None, :]
-        in_range = pos_bt < s_slot  # overlong chunks: clamp to scratch page 0
-        page_ids = jnp.where(
-            in_range,
-            page_table[rows[:, None], jnp.clip(pos_bt // page, 0, n_slot - 1)],
-            0,
-        )
-        flat_idx = (page_ids * page + pos_bt % page).reshape(-1)
+        flat_idx = _flat_slots(page_table, cache_pos, tq, page)
         k_pages = _scatter_to_pages(
             k_pages, k.reshape(b * tq, hkv * hd), flat_idx
         )
@@ -488,12 +530,239 @@ def init_attention_cache(
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+#
+# Each token's keys and values come from one latent c = RMSNorm(x W_DKV)
+# of kv_lora_rank lanes, up-projected per head (k_nope = c W_UK, v = c
+# W_UV), beside one rope key k_r = rope(x W_KR) that every head shares.
+# The cache therefore holds one row per token, [c ; k_r], zero-padded to
+# whole 128-lane tiles (``latent_width``): 512 + 64 -> 640 lanes at the
+# published widths.  Prefill up-projects the rows block by block; decode
+# absorbs W_UK into the query (q_nope W_UK^T, kv_lora_rank lanes), scores
+# it with q_rope against the whole row, and keeps the output in the latent
+# space until W_UV.
+
+
+LANES = 128
+
+
+def latent_width(cfg: ArchConfig) -> int:
+    """Lanes of one cached latent row: latent then rope key, padded."""
+    m = cfg.mla
+    return -(-(m.kv_lora_rank + m.qk_rope_head_dim) // LANES) * LANES
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times YaRN's ``mscale_all_dim``
+    factor squared where the rope is scaled (DeepSeek-V2)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def init_latent_attention(rng: jax.Array, cfg: ArchConfig, dtype) -> Params:
+    m, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    r, dn, dr, dv = (m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim,
+                     m.v_head_dim)
+    ks = jax.random.split(rng, 5)
+    return {
+        "wq": dense_init(ks[0], (d, h, dn + dr), dtype, d),
+        "wkv_a": dense_init(ks[1], (d, r + dr), dtype, d),
+        "kv_norm": jnp.zeros((r,), dtype=dtype),
+        "wk_b": dense_init(ks[2], (r, h, dn), dtype, r),
+        "wv_b": dense_init(ks[3], (r, h, dv), dtype, r),
+        "wo": dense_init(ks[4], (h, dv, d), dtype, h * dv),
+    }
+
+
+def init_latent_cache(cfg: ArchConfig, batch: int, max_len: int, dtype,
+                      paged: Optional[PagedSpec] = None) -> Params:
+    """A latent row per token: a shared page pool ``[P, page, width]``
+    behind per-slot page tables, or ``[B, max_len, width]`` rows."""
+    w = latent_width(cfg)
+    if paged is not None:
+        return {
+            "latent_pages": jnp.zeros(
+                (paged.num_pages, paged.page_size, w), dtype=dtype),
+            "page_table": jnp.zeros(
+                (batch, paged.pages_per_slot(max_len)), dtype=jnp.int32),
+            "pos": jnp.zeros((batch,), dtype=jnp.int32),
+        }
+    return {"latent": jnp.zeros((batch, max_len, w), dtype=dtype),
+            "pos": jnp.zeros((batch,), dtype=jnp.int32)}
+
+
+def latent_attention(
+    params: Params,
+    x: jax.Array,  # [B, Tq, D]
+    positions: jax.Array,  # [B, Tq]
+    cfg: ArchConfig,
+    cache: Optional[Params] = None,
+) -> Tuple[jax.Array, Optional[Params]]:
+    """MLA over a latent cache (paged or rows), or over the chunk itself
+    (training).  Returns (output [B, Tq, D], updated cache or None)."""
+    m, dt = cfg.mla, x.dtype
+    r, dn, dr = m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim
+    b, tq, _ = x.shape
+    q = jnp.einsum("btd,dhk->bthk", x, params["wq"].astype(dt))
+    q_nope = q[..., :dn]
+    q_rope = rope(q[..., dn:], positions, cfg.rope_theta, dr, cfg.rope_scaling)
+    kv = jnp.einsum("btd,dk->btk", x, params["wkv_a"].astype(dt))
+    c = rms_norm(kv[..., :r], params["kv_norm"], cfg.norm_eps)
+    k_rope = rope(kv[..., None, r:], positions, cfg.rope_theta, dr,
+                  cfg.rope_scaling)[:, :, 0]
+    w = latent_width(cfg)
+    row = jnp.concatenate(
+        [c, k_rope.astype(dt), jnp.zeros((b, tq, w - r - dr), dt)], axis=-1)
+
+    new_cache: Optional[Params] = None
+    if cache is None:
+        out = _latent_attend(params, q_nope, q_rope, row, positions,
+                             positions[:, -1] + 1, cfg)
+    elif "page_table" in cache:
+        pages, table, pos = (cache["latent_pages"], cache["page_table"],
+                             cache["pos"])
+        kv_len = pos + tq
+        if tq == 1 and platform.compiled_kernels():
+            pages = paged_latent_append(row[:, 0], pages, table, pos)
+            q_lat = _absorb(params, q_nope)[:, 0]  # [B, H, r]
+            qf = jnp.concatenate(
+                [q_lat, q_rope[:, 0].astype(dt),
+                 jnp.zeros((b, q.shape[2], w - r - dr), dt)], axis=-1)
+            o_lat = paged_latent_decode(
+                qf.astype(pages.dtype), pages, table, kv_len, value_dim=r,
+                sm_scale=mla_softmax_scale(cfg))
+            out = _unabsorb(params, o_lat[:, None].astype(dt))
+        else:
+            flat = _flat_slots(table, pos, tq, pages.shape[1])
+            pages = _scatter_to_pages(
+                pages, row.reshape(b * tq, w).astype(pages.dtype), flat)
+            out = _latent_attend(params, q_nope, q_rope,
+                                 gather_pages(pages, table), positions,
+                                 kv_len, cfg)
+        new_cache = {"latent_pages": pages, "page_table": table, "pos": kv_len}
+    else:
+        pos = cache["pos"]
+        rows = jax.vmap(
+            lambda cr, nr, st: jax.lax.dynamic_update_slice(cr, nr, (st, 0))
+        )(cache["latent"], row.astype(cache["latent"].dtype), pos)
+        out = _latent_attend(params, q_nope, q_rope, rows, positions,
+                             pos + tq, cfg)
+        new_cache = {"latent": rows, "pos": pos + tq}
+
+    y = jnp.einsum("bthv,hvd->btd", out, params["wo"].astype(dt))
+    return shard(y, "batch", "seq_inner", "embed"), new_cache
+
+
+def _absorb(params: Params, q_nope: jax.Array) -> jax.Array:
+    """q_nope W_UK^T: [B, T, H, qk_nope] -> [B, T, H, kv_lora_rank]."""
+    dt = q_nope.dtype
+    return jnp.einsum("bthn,rhn->bthr", q_nope, params["wk_b"].astype(dt),
+                      preferred_element_type=jnp.float32).astype(dt)
+
+
+def _unabsorb(params: Params, o_lat: jax.Array) -> jax.Array:
+    """The latent-space output times W_UV: [B, T, H, r] -> [B, T, H, v]."""
+    dt = o_lat.dtype
+    return jnp.einsum("bthr,rhv->bthv", o_lat, params["wv_b"].astype(dt))
+
+
+def _latent_attend(params, q_nope, q_rope, rows, q_pos, kv_len, cfg):
+    """Attention of queries at ``q_pos`` over cached rows ``[B, S, width]``
+    at positions 0..S-1, of which each row's first ``kv_len`` are live:
+    the absorbed form for one query (decode), the rows up-projected
+    block by block for a longer chunk."""
+    if q_nope.shape[1] == 1:
+        return absorbed_attend(params, q_nope, q_rope, rows, kv_len, cfg)
+    return upprojected_attend(params, q_nope, q_rope, rows, q_pos, kv_len, cfg)
+
+
+def absorbed_attend(params, q_nope, q_rope, rows, kv_len, cfg):
+    """One query per row: q_nope W_UK^T and q_rope scored against each
+    row's latent and rope key, the output kept in the latent space until
+    W_UV.  What the paged latent kernel computes, in jnp."""
+    m, dt = cfg.mla, q_nope.dtype
+    r, dr = m.kv_lora_rank, m.qk_rope_head_dim
+    rows = rows.astype(dt)
+    c, k_r = rows[..., :r], rows[..., r:r + dr]
+    s = (jnp.einsum("bthr,bsr->bhts", _absorb(params, q_nope), c,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bthk,bsk->bhts", q_rope.astype(dt), k_r,
+                      preferred_element_type=jnp.float32)
+         ) * mla_softmax_scale(cfg)
+    live = (jnp.arange(rows.shape[1])[None, :] < kv_len[:, None])
+    p = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -1e30), axis=-1)
+    o_lat = jnp.einsum("bhts,bsr->bhtr", p.astype(dt), c,
+                       preferred_element_type=jnp.float32)
+    return _unabsorb(params, o_lat.swapaxes(1, 2).astype(dt))
+
+
+def upprojected_attend(params, q_nope, q_rope, rows, q_pos, kv_len, cfg,
+                       block: int = 512):
+    """Keys and values up-projected from the rows ``block`` at a time
+    under the online softmax, so a 7k-token prompt holds [H, Tq, block]
+    scores and not [H, Tq, S]; blocks past the longest row's ``kv_len``
+    are skipped."""
+    m, dt = cfg.mla, q_nope.dtype
+    r, dr = m.kv_lora_rank, m.qk_rope_head_dim
+    scale = mla_softmax_scale(cfg)
+    b, tq, h, _ = q_nope.shape
+    rows = rows.astype(dt)
+    block = min(block, rows.shape[1])
+    pad = (-rows.shape[1]) % block
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+    n_blocks = rows.shape[1] // block
+    n_live = (jnp.max(kv_len) + block - 1) // block
+    wk, wv = params["wk_b"].astype(dt), params["wv_b"].astype(dt)
+    q_rope = q_rope.astype(dt)
+
+    def update(i, carry):
+        m_prev, l_prev, acc = carry
+        blk = jax.lax.dynamic_slice_in_dim(rows, i * block, block, axis=1)
+        k_nope = jnp.einsum("bsr,rhn->bshn", blk[..., :r], wk)
+        v = jnp.einsum("bsr,rhv->bshv", blk[..., :r], wv)
+        s = (jnp.einsum("bthn,bshn->bhts", q_nope, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bthk,bsk->bhts", q_rope, blk[..., r:r + dr],
+                          preferred_element_type=jnp.float32)) * scale
+        k_pos = i * block + jnp.arange(block)
+        mask = ((k_pos[None, None, :] <= q_pos[:, :, None])
+                & (k_pos[None, None, :] < kv_len[:, None, None]))[:, None]
+        s = jnp.where(mask, s, -1e30)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur[..., None]), 0.0)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
+        pv = jnp.einsum("bhts,bshv->bthv", p.astype(dt), v,
+                        preferred_element_type=jnp.float32)
+        acc = acc * alpha.transpose(0, 2, 1)[..., None] + pv
+        return m_cur, l_new, acc
+
+    def step(carry, i):
+        return jax.lax.cond(i < n_live, lambda c: update(i, c),
+                            lambda c: c, carry), None
+
+    init = (jnp.full((b, h, tq), -1e30, jnp.float32),
+            jnp.zeros((b, h, tq), jnp.float32),
+            jnp.zeros((b, tq, h, m.v_head_dim), jnp.float32))
+    (_, l_f, acc), _ = jax.lax.scan(step, init, jnp.arange(n_blocks))
+    denom = jnp.maximum(l_f, 1e-30).transpose(0, 2, 1)[..., None]
+    return (acc / denom).astype(dt)
+
+
+# ---------------------------------------------------------------------------
 # MLP
 # ---------------------------------------------------------------------------
 
 
-def init_mlp(rng: jax.Array, cfg: ArchConfig, dtype) -> Params:
-    d, ff = cfg.d_model, cfg.d_ff
+def init_mlp(rng: jax.Array, cfg: ArchConfig, dtype,
+             ff: Optional[int] = None) -> Params:
+    d, ff = cfg.d_model, ff or cfg.d_ff
     ks = jax.random.split(rng, 3)
     return {
         "w_gate": dense_init(ks[0], (d, ff), dtype, d),

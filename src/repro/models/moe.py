@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.config.base import ArchConfig, MoEConfig
 from repro.distributed.sharding import shard
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, init_mlp, mlp
 
 Params = Dict[str, Any]
 
@@ -49,15 +49,83 @@ def moe_apply(params, x, moe, rng=None):
 
 
 def init_moe(rng: jax.Array, cfg: ArchConfig, dtype) -> Params:
-    assert cfg.moe is not None
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    """The router over every expert, and the weights of the experts this
+    chip holds (all of them unless ``held_experts`` names a share), plus
+    the shared experts as one SwiGLU where there are any."""
+    moe = cfg.moe
+    assert moe is not None
+    d, ff = cfg.d_model, cfg.expert_ff
+    e = moe.held_experts or moe.num_experts
     ks = jax.random.split(rng, 4)
-    return {
-        "router": dense_init(ks[0], (d, e), jnp.float32, d),
+    p = {
+        "router": dense_init(ks[0], (d, moe.num_experts), jnp.float32, d),
         "w_gate": dense_init(ks[1], (e, d, ff), dtype, d),
         "w_up": dense_init(ks[2], (e, d, ff), dtype, d),
         "w_down": dense_init(ks[3], (e, ff, d), dtype, ff),
     }
+    if moe.shared_experts:
+        p["shared"] = init_mlp(jax.random.fold_in(rng, 4), cfg, dtype,
+                               ff=moe.shared_experts * ff)
+    return p
+
+
+def moe_share(
+    params: Params,
+    x: jax.Array,  # [B, T, D]
+    moe: MoEConfig,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One chip's share of a DeepSeek-MoE layer (expert parallelism
+    without the exchange).
+
+    The router scores all ``num_experts`` in float32 and picks the top-k
+    greedily; the weights are renormalised only where the config says so,
+    then scaled by ``routed_scale``.  The chip computes the part of the
+    routed sum that its held experts give, dropless: every held expert
+    runs over every token, weighted by that token's gate for it (zero
+    where it was routed elsewhere), which is exact and, at decode, reads
+    each held expert's weights once, as a grouped kernel would.  The
+    shared experts are added once.  Returns (output [B, T, D], the
+    Switch-style aux loss, the (token, held expert) assignments per batch
+    row [B])."""
+    b, t, d = x.shape
+    e, k, held = moe.num_experts, moe.top_k, moe.held_experts
+    xf = x.reshape(b * t, d)
+    logits = jnp.einsum(
+        "nd,de->ne", xf.astype(jnp.float32), params["router"].astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [n, k]
+    if moe.renormalize:
+        gate_vals = gate_vals / jnp.clip(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
+        )
+    gate_vals = gate_vals * moe.routed_scale
+    local = gate_idx - moe.first_held
+    mine = (local >= 0) & (local < held)
+    # [n, held]: each token's gate for each held expert, 0 where unrouted
+    combine = jnp.sum(
+        jax.nn.one_hot(jnp.where(mine, local, held), held + 1,
+                       dtype=jnp.float32)[..., :held] * gate_vals[..., None],
+        axis=1,
+    )
+    dt = x.dtype
+    gate = jnp.einsum("nd,edf->enf", xf, params["w_gate"].astype(dt))
+    up = jnp.einsum("nd,edf->enf", xf, params["w_up"].astype(dt))
+    h = jax.nn.silu(gate) * up
+    out = jnp.einsum("enf,efd->end", h, params["w_down"].astype(dt))
+    y = jnp.einsum("end,ne->nd", out, combine.astype(dt),
+                   preferred_element_type=jnp.float32).astype(dt)
+    y = y.reshape(b, t, d)
+    if "shared" in params:
+        y = y + mlp(params["shared"], x)
+
+    me = jnp.mean(probs, axis=0)
+    frac = jnp.sum(
+        jax.nn.one_hot(gate_idx, e, dtype=jnp.float32), axis=(0, 1)
+    ) / max(b * t * k, 1)
+    aux = moe.aux_loss_weight * e * jnp.sum(frac * me)
+    rows = jnp.sum(mine.reshape(b, t * k), axis=-1).astype(jnp.int32)
+    return y, aux.astype(jnp.float32), rows
 
 
 def _fcfs_positions(gate_idx: jax.Array, e: int) -> jax.Array:

@@ -6,7 +6,9 @@ scan body; parameters are stacked ``[n_periods, ...]`` per pattern
 position, and ``num_layers % len(pattern)`` remainder layers run
 unrolled.  This keeps the HLO O(pattern) instead of O(depth) — compile
 times and program size stay flat from 2 layers to 64 (critical for the
-512-device dry-run on one CPU).
+512-device dry-run on one CPU).  The config's ``lead`` layers (DeepSeek's
+leading dense layer) run unrolled before the scan, with their own params
+and caches under ``"lead"``.
 
 Caches (attention KV / mamba conv+ssm states) are pytrees with the same
 period structure, threaded through the scan as (xs -> ys).
@@ -39,6 +41,8 @@ def init_block(rng: jax.Array, cfg: ArchConfig, spec: LayerSpec, dtype) -> Param
     p: Params = {"norm_attn": jnp.zeros((cfg.d_model,), dtype=dtype)}
     if spec.is_mamba:
         p["mamba"] = M.init_mamba(ks[0], cfg, dtype)
+    elif spec.attention == AttentionKind.LATENT:
+        p["attn"] = L.init_latent_attention(ks[0], cfg, dtype)
     elif spec.attention != AttentionKind.NONE:
         p["attn"] = L.init_attention(ks[0], cfg, dtype)
         if spec.attention == AttentionKind.CROSS:
@@ -71,6 +75,9 @@ def init_block_cache(
     ``PagePool`` does its accounting once per slot, not per layer."""
     if spec.is_mamba:
         return {"mamba": M.init_mamba_cache(cfg, batch, dtype)}
+    if spec.attention == AttentionKind.LATENT:
+        return {"attn": L.init_latent_cache(cfg, batch, max_len, dtype,
+                                            paged=paged)}
     if spec.attention != AttentionKind.NONE:
         if paged is not None and spec.attention != AttentionKind.CROSS:
             return {"attn": L.init_attention_cache(
@@ -92,9 +99,11 @@ def apply_block(
     cfg: ArchConfig,
     cache: Optional[Params],
     enc_out: Optional[jax.Array],
-) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (x', cache', aux_loss)."""
+) -> Tuple[jax.Array, Optional[Params], jax.Array, jax.Array]:
+    """Returns (x', cache', aux_loss, held), ``held`` [B] the (token,
+    expert) assignments that landed on this chip's held experts."""
     aux = jnp.zeros((), dtype=jnp.float32)
+    held = jnp.zeros((x.shape[0],), dtype=jnp.int32)
     new_cache: Optional[Params] = None
     rs = cfg.residual_scale
 
@@ -106,6 +115,14 @@ def apply_block(
         )
         x = x + rs * y
         new_cache = {"mamba": mc} if mc is not None else None
+    elif spec.attention == AttentionKind.LATENT:
+        h = L.rms_norm(x, params["norm_attn"], cfg.norm_eps)
+        y, ac = L.latent_attention(
+            params["attn"], h, positions, cfg,
+            cache=cache.get("attn") if cache else None,
+        )
+        x = x + rs * y
+        new_cache = {"attn": ac} if ac is not None else None
     elif spec.attention != AttentionKind.NONE:
         h = L.rms_norm(x, params["norm_attn"], cfg.norm_eps)
         attn_cache = cache.get("attn") if cache else None
@@ -123,7 +140,7 @@ def apply_block(
             y2 = L.mlp(params["mlp"], h)
             x = x + rs * (y + y2)
             new_cache = {"attn": ac} if ac is not None else None
-            return shard(x, "batch", "seq", "embed"), new_cache, aux
+            return shard(x, "batch", "seq", "embed"), new_cache, aux, held
         x = x + rs * y
         new_cache = {"attn": ac} if ac is not None else None
         if spec.attention == AttentionKind.CROSS and enc_out is not None:
@@ -136,14 +153,17 @@ def apply_block(
 
     if spec.ffn != FFNKind.NONE:
         h = L.rms_norm(x, params["norm_ffn"], cfg.norm_eps)
-        if spec.ffn == FFNKind.MOE:
+        if spec.ffn == FFNKind.MOE and cfg.moe.held_experts:
+            y, moe_aux, held = MOE.moe_share(params["moe"], h, cfg.moe)
+            aux = aux + moe_aux
+        elif spec.ffn == FFNKind.MOE:
             y, moe_aux = MOE.moe_apply(params["moe"], h, cfg.moe)
             aux = aux + moe_aux
         else:
             y = L.mlp(params["mlp"], h)
         x = x + rs * y
 
-    return shard(x, "batch", "seq", "embed"), new_cache, aux
+    return shard(x, "batch", "seq", "embed"), new_cache, aux, held
 
 
 # ---------------------------------------------------------------------------
@@ -152,14 +172,24 @@ def apply_block(
 
 
 def _period_counts(cfg: ArchConfig) -> Tuple[int, int]:
-    plen = len(cfg.pattern)
-    return cfg.num_layers // plen, cfg.num_layers % plen
+    """Scanned periods and unrolled remainder layers after the lead."""
+    plen, rest = len(cfg.pattern), cfg.num_layers - len(cfg.lead)
+    return rest // plen, rest % plen
+
+
+def _counts_experts(cfg: ArchConfig) -> bool:
+    return cfg.moe is not None and cfg.moe.held_experts > 0
 
 
 def init_params(rng: jax.Array, cfg: ArchConfig, dtype=jnp.float32) -> Params:
     n_periods, remainder = _period_counts(cfg)
     keys = jax.random.split(rng, 8)
     params: Params = {"embed": L.init_embedding(keys[0], cfg, dtype)}
+    if cfg.lead:
+        params["lead"] = [
+            init_block(jax.random.fold_in(keys[4], i), cfg, spec, dtype)
+            for i, spec in enumerate(cfg.lead)
+        ]
 
     # Stacked params per pattern position: [n_periods, ...]
     if n_periods > 0:
@@ -178,7 +208,7 @@ def init_params(rng: jax.Array, cfg: ArchConfig, dtype=jnp.float32) -> Params:
             init_block(
                 jax.random.fold_in(keys[2], i),
                 cfg,
-                cfg.layer_spec(n_periods * len(cfg.pattern) + i),
+                cfg.layer_spec(len(cfg.lead) + n_periods * len(cfg.pattern) + i),
                 dtype,
             )
             for i in range(remainder)
@@ -202,6 +232,15 @@ def init_cache(
 ) -> Params:
     n_periods, remainder = _period_counts(cfg)
     cache: Params = {}
+    if cfg.lead:
+        cache["lead"] = [
+            init_block_cache(cfg, spec, batch, max_len, dtype, ring=ring,
+                             paged=paged)
+            for spec in cfg.lead
+        ]
+    if _counts_experts(cfg):
+        # Per row, the (token, held expert) assignments of its last step.
+        cache["expert_rows"] = jnp.zeros((batch,), dtype=jnp.int32)
     if n_periods > 0:
         period_caches = []
         for pos, spec in enumerate(cfg.pattern):
@@ -223,7 +262,7 @@ def init_cache(
         cache["remainder"] = [
             init_block_cache(
                 cfg,
-                cfg.layer_spec(n_periods * len(cfg.pattern) + i),
+                cfg.layer_spec(len(cfg.lead) + n_periods * len(cfg.pattern) + i),
                 batch,
                 max_len,
                 dtype,
@@ -307,6 +346,17 @@ def forward(
         positions = start_pos[:, None] + jnp.arange(t_total, dtype=jnp.int32)[None]
 
     aux_total = jnp.zeros((), dtype=jnp.float32)
+    held_total = jnp.zeros((b,), dtype=jnp.int32)
+
+    # --- leading layers (unrolled) ------------------------------------------
+    new_lead = []
+    for i, spec in enumerate(cfg.lead):
+        x, nc, a, hd = apply_block(
+            params["lead"][i], spec, x, positions, cfg,
+            cache["lead"][i] if cache is not None else None, enc_out,
+        )
+        aux_total, held_total = aux_total + a, held_total + hd
+        new_lead.append(nc)
 
     # --- scanned periods --------------------------------------------------
     if n_periods > 0:
@@ -316,29 +366,30 @@ def forward(
         )
 
         def body2(carry, xs):
-            x, aux = carry
+            x, aux, held = carry
             layer_ps, layer_cs = xs
             new_cs: List[Any] = []
             for pos, spec in enumerate(cfg.pattern):
                 cache_entry = None if layer_cs is None else layer_cs[pos]
-                x, nc, a = apply_block(
+                x, nc, a, hd = apply_block(
                     layer_ps[pos], spec, x, positions, cfg,
                     cache_entry, enc_out,
                 )
-                aux = aux + a
+                aux, held = aux + a, held + hd
                 new_cs.append(nc)
-            return (x, aux), tuple(new_cs)
+            return (x, aux, held), tuple(new_cs)
 
         if cache is not None:
-            (x, aux_total), new_period_caches = jax.lax.scan(
-                body2, (x, aux_total), (tuple(period_params), tuple(period_caches))
+            (x, aux_total, held_total), new_period_caches = jax.lax.scan(
+                body2, (x, aux_total, held_total),
+                (tuple(period_params), tuple(period_caches)),
             )
         else:
             def body_nocache(carry, layer_ps):
                 x, aux = carry
                 new_cs: List[Any] = []
                 for pos, spec in enumerate(cfg.pattern):
-                    x, _, a = apply_block(
+                    x, _, a, _ = apply_block(
                         layer_ps[pos], spec, x, positions, cfg,
                         None, enc_out,
                     )
@@ -356,14 +407,14 @@ def forward(
         rem_caches = (
             cache["remainder"] if cache is not None else [None] * remainder
         )
-        base = n_periods * len(cfg.pattern)
+        base = len(cfg.lead) + n_periods * len(cfg.pattern)
         for i in range(remainder):
             spec = cfg.layer_spec(base + i)
-            x, nc, a = apply_block(
+            x, nc, a, hd = apply_block(
                 params["remainder"][i], spec, x, positions, cfg,
                 rem_caches[i], enc_out,
             )
-            aux_total = aux_total + a
+            aux_total, held_total = aux_total + a, held_total + hd
             new_remainder.append(nc)
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -376,6 +427,10 @@ def forward(
     new_cache: Optional[Params] = None
     if cache is not None:
         new_cache = {}
+        if cfg.lead:
+            new_cache["lead"] = new_lead
+        if _counts_experts(cfg):
+            new_cache["expert_rows"] = held_total
         if n_periods > 0:
             new_cache["periods"] = list(new_period_caches)
         if remainder > 0:

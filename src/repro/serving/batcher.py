@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.config.base import FFNKind
 from repro.core.messages import Mailbox, Message
 from repro.models.zoo import Model
 from repro.serving.kv_cache import PagedSpec, PagePool
@@ -168,6 +169,10 @@ class ContinuousBatcher:
         self.metrics = None
         self.rng = jax.random.PRNGKey(0)
         self.steps = 0
+        # (row, expert) assignments of the decoded rows that landed on the
+        # routed experts this chip holds, summed over the MoE layers (models
+        # with an expert share).
+        self.held_expert_rows = 0
 
     def _note(self, name: str, amount: int = 1) -> None:
         if self.metrics is not None:
@@ -304,7 +309,7 @@ class ContinuousBatcher:
             in_periods = any(
                 isinstance(p, DictKey) and p.key == "periods" for p in path[:1]
             )
-            if key in ("k_pages", "v_pages"):
+            if (key or "").endswith("_pages"):
                 # copy the scratch pages (temp ids 1..need) onto the
                 # granted shared ids — the gather map, inverted.
                 if in_periods:
@@ -437,6 +442,19 @@ class ContinuousBatcher:
             self.slot_pages[slot].extend(got)
             self._table_dirty = True
 
+    def _note_expert_rows(self, per_row: np.ndarray) -> None:
+        """Sum the decoded rows' held-expert assignments into the stats;
+        the span carries the step's count and the experts held."""
+        held = int(sum(int(per_row[s]) for s in range(self.slots)
+                       if self.active[s] is not None))
+        self.held_expert_rows += held
+        cfg = self.model.cfg
+        layers = sum(cfg.layer_spec(i).ffn == FFNKind.MOE
+                     for i in range(cfg.num_layers))
+        with span("serve.expert_rows", assignments=held,
+                  experts=cfg.moe.held_experts, layers=layers):
+            pass
+
     def _finish(self, slot: int, now: float) -> None:
         req = self.active[slot]
         with span("serve.finish", req=req.req_id if req is not None else -1,
@@ -530,7 +548,13 @@ class ContinuousBatcher:
                 self.params, tokens, self.cache, positions, self.rng
             )
         with span("serve.token_wait"):
-            next_np = np.asarray(next_tok)
+            if "expert_rows" in self.cache:
+                next_np, expert_rows = jax.device_get(
+                    (next_tok, self.cache["expert_rows"]))
+            else:
+                next_np, expert_rows = np.asarray(next_tok), None
+        if expert_rows is not None:
+            self._note_expert_rows(expert_rows)
         decoded = 0
         for slot in range(self.slots):
             if self.active[slot] is None:

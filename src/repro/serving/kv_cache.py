@@ -2,8 +2,9 @@
 
 The device side is a shared page pool per attention layer
 (``models.layers.PagedSpec``: ``k_pages``/``v_pages`` ``[P, page,
-Hkv*hd]``, each token's kv heads side by side on the lanes, plus
-per-slot page tables).  This module is the control-plane half:
+Hkv*hd]``, each token's kv heads side by side on the lanes, or for
+latent attention one ``latent_pages`` ``[P, page, width]`` row per token,
+plus per-slot page tables).  This module is the control-plane half:
 a free list over page ids, allocated when the continuous batcher admits a
 request and grown one page at a time as its decode position crosses page
 boundaries.  The same table values index every layer's pool, so the

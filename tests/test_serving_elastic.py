@@ -146,6 +146,38 @@ def test_autoscaler_scales_occupancy_up_and_back_down(stub):
         assert r.output == greedy_reference(model, params, r.prompt, 6)
 
 
+def _launcher_targets(stub, argv):
+    """The unit targets of a pool built as the serving launcher builds it,
+    through a burst of 24 requests and a few idle steps."""
+    from repro.launch import serve
+
+    model, params = stub
+    args = serve.make_parser().parse_args(["--stub", "--slots", "2",
+                                           "--max-replicas", "2", *argv])
+    pool = ElasticServingPool(model, params, **serve.pool_kwargs(args))
+    for i in range(24):
+        pool.submit(Request(prompt=[i % 5 + 1], max_new_tokens=6), now=0.0)
+    now = 1.0
+    while pool.queue_depth() or pool.occupancy():
+        pool.step(now)
+        now += 1.0
+    for _ in range(3):
+        pool.step(now)
+        now += 1.0
+    assert len(pool.completed) == 24
+    return [t for (_, t, _, _) in pool.occupancy_log]
+
+
+def test_launcher_keeps_one_replicas_slots_open(stub):
+    """The launcher's pool scales by whole replicas and never caps a
+    replica below its slots; --spike keeps the ramp from one slot."""
+    targets = _launcher_targets(stub, [])
+    assert min(targets) == 2 and max(targets) == 4, targets
+    assert targets[-1] == 2, targets
+    spike = _launcher_targets(stub, ["--spike"])
+    assert min(spike) == 1 and max(spike) == 4, spike
+
+
 def test_scale_in_drains_without_cancelling(stub):
     pool = make_pool(stub, initial_units=4)  # start wide: 2 replicas
     assert len(pool.active_replicas()) == 2

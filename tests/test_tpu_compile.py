@@ -29,6 +29,8 @@ from repro.kernels import platform
 from repro.kernels.decode_attention.kernel import (
     paged_decode_attention_fwd,
     paged_kv_append_fwd,
+    paged_latent_append_fwd,
+    paged_latent_decode_fwd,
 )
 from repro.kernels.tcmm_assign.kernel import tcmm_assign_fwd
 from repro.models.layers import PagedSpec
@@ -38,6 +40,9 @@ from repro.training.train_step import init_train_state, make_train_step
 
 HBM_BYTES = 16e9  # one v5e chip
 MINICPM = dict(slots=8, max_len=1024, page=16, pages=513)  # chip_smoke.py
+# bench/configs/deepseek-v2-lite-serve.json: 640-lane latent rows
+DEEPSEEK = dict(slots=16, max_len=8192, page=16, pages=5121, heads=16,
+                width=640, latent=512)
 
 
 @pytest.fixture(scope="module")
@@ -109,6 +114,31 @@ def test_paged_kv_append_compiles_for_v5e(one_chip):
         _sds(one_chip, pool, jnp.bfloat16),
         _sds(one_chip, (b, n), jnp.int32),
         _sds(one_chip, (b,), jnp.int32),
+    )
+
+
+def test_paged_latent_decode_kernel_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's latent decode: 16 heads over 640-lane rows in
+    pages of 16, 32 pages a grid step, 16 slots x 8192 tokens."""
+    c = DEEPSEEK
+    n = c["max_len"] // c["page"]
+    _compile(
+        lambda q, p, t, k: paged_latent_decode_fwd(q, p, t, k, c["latent"], 0.1),
+        _sds(one_chip, (c["slots"], c["heads"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["pages"], c["page"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["slots"], n), jnp.int32),
+        _sds(one_chip, (c["slots"],), jnp.int32),
+    )
+
+
+def test_paged_latent_append_compiles_for_v5e(one_chip):
+    c = DEEPSEEK
+    _compile(
+        paged_latent_append_fwd,
+        _sds(one_chip, (c["slots"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["pages"], c["page"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["slots"], c["max_len"] // c["page"]), jnp.int32),
+        _sds(one_chip, (c["slots"],), jnp.int32),
     )
 
 
@@ -205,6 +235,43 @@ def test_minicpm_decode_step_keeps_the_pool_row_major(one_chip,
                 or name.startswith(("copy", "transpose"))):
             moved.append(line.strip()[:160])
     assert not moved, moved
+
+
+def test_deepseek_full_width_decode_step_compiles_for_v5e(one_chip,
+                                                          monkeypatch):
+    """DeepSeek-V2-Lite's served decode step (27 layers, 8 of 64 experts
+    held, bf16) over the benchmark's latent pool holds the latent
+    kernels, fits one chip with the pool twice (the step is not
+    donated), and takes every layer's pool row-major."""
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    c = DEEPSEEK
+    model = build_model(get_arch("deepseek-v2-lite"), compute_dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = PagedSpec(num_pages=c["pages"], page_size=c["page"])
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(c["slots"], c["max_len"], paged=spec)))
+    b = c["slots"]
+    compiled = make_decode_step(model).lower(
+        params, _sds(one_chip, (b, 1), jnp.int32), cache,
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (2,), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    assert "_paged_latent_decode_jit" in text and "_latent_append_jit" in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 1e9:.2f} GB does not fit one v5e"
+    pools = [fmt for path, fmt in jax.tree_util.tree_flatten_with_path(
+        compiled.input_formats[0][2])[0] if path[-1].key == "latent_pages"]
+    assert len(pools) == 2  # the leading dense layer's and the scanned ones'
+    for fmt in pools:
+        m2m = fmt.layout.major_to_minor
+        assert m2m == tuple(range(len(m2m))), m2m
 
 
 @pytest.mark.parametrize("arch", list_archs())
