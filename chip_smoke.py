@@ -105,7 +105,9 @@ def check_paged_kernel(seed: int, batch: int, heads: int, head_dim: int,
     lens[: min(batch, 3)] = [1, page, page + 1][: min(batch, 3)]
     kv_len = jnp.asarray(lens)
 
-    out = paged_decode_attention(q, k_pages, v_pages, table, kv_len)
+    # one layer's pool: the stacked pool's free view, at layer 0
+    out = paged_decode_attention(q, k_pages[None], v_pages[None], table,
+                                 kv_len, 0)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(paged_decode_attention_ref)(
             q, k_pages, v_pages, table, kv_len

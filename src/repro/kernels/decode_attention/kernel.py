@@ -14,15 +14,19 @@ kv_len masking handles ragged batches (continuous batching feeds
 sequences of different lengths).
 
 The **paged** variants replace the per-sequence dense cache
-``[B, S, Hkv, D]`` with a shared page pool ``[P, page_size, Hkv*D]``
-plus a per-sequence page table ``[B, pages_per_seq]`` — the serving
-layer allocates pages per token tick (continuous batching) instead of
-reserving max_len rows per slot.  The page table and kv_len ride in as
-scalar-prefetch operands (``pltpu.PrefetchScalarGridSpec``) so the
-BlockSpec index maps gather the right K/V page for every grid step —
-the gather happens in the DMA schedule, never as a materialized
-``k_pages[page_table]`` copy.  Grid = (B, pages per sequence), and one
-K/V block is a whole page, ``(1, page, Hkv*D)``.
+``[B, S, Hkv, D]`` with a shared page pool plus a per-sequence page
+table ``[B, pages_per_seq]`` — the serving layer allocates pages per
+token tick (continuous batching) instead of reserving max_len rows per
+slot.  The pool is every layer's, stacked ``[L, P, page_size, Hkv*D]``,
+and a call names its layer: the decode scan carries the stacked pool
+and updates it in place, where slicing a layer's pool out and writing
+it back would copy it twice a layer.  The layer index, the page table
+and kv_len ride in as scalar-prefetch operands
+(``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps gather
+the right K/V page of the right layer for every grid step — the gather
+happens in the DMA schedule, never as a materialized
+``k_pages[layer, page_table]`` copy.  Grid = (B, pages per sequence),
+and one K/V block is a whole page, ``(page, Hkv*D)``.
 
 The pool is **lane-dense**: a token's row holds every kv head's D
 lanes side by side.  With D = 64 a ``[.., page, Hkv, D]`` pool would
@@ -41,7 +45,7 @@ pattern of the vectorized control plane).
 
 ``paged_latent_decode`` serves multi-head latent attention (MLA): the
 pool holds one row per token, the latent then the shared rope key,
-zero-padded to whole lane tiles (``[P, page, W]``, W = 640 for 512 + 64),
+zero-padded to whole lane tiles (``[L, P, page, W]``, W = 640 for 512 + 64),
 and every one of the H query heads is a latent-space query of W lanes
 (``q_nope W_UK^T`` joined to ``q_rope``).  Scores are ``q @ rows^T`` over
 all W lanes, values the rows' first V lanes, the output ``[B, H, V]``.
@@ -182,6 +186,7 @@ def _head_chunk(head_dim: int, width: int) -> int:
 
 
 def _paged_decode_kernel(
+    layer_ref,   # scalar prefetch [1] int32 layer of the stacked pool
     pt_ref,      # scalar prefetch [B, n_pages] int32 page table
     kv_len_ref,  # scalar prefetch [B] int32
     q_ref,       # [1, G, Hkv*d]
@@ -201,7 +206,7 @@ def _paged_decode_kernel(
     head_dim: int,
     chunk: int,
 ):
-    del pt_ref  # consumed by the index maps
+    del layer_ref, pt_ref  # consumed by the index maps
     ib = pl.program_id(0)
     ik = pl.program_id(1)
     n_chunks = acc_ref.shape[1] // chunk
@@ -275,18 +280,24 @@ def _paged_decode_kernel(
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
+def _layer_operand(layer) -> jax.Array:
+    """The layer index as the ``[1]`` int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, dtype=jnp.int32).reshape(1)
+
+
 def paged_decode_attention_fwd(
     q: jax.Array,           # [B, H, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv*D] shared page pool
-    v_pages: jax.Array,     # [P, page_size, Hkv*D]
+    k_pages: jax.Array,     # [L, P, page_size, Hkv*D] stacked page pool
+    v_pages: jax.Array,     # [L, P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32 (page id per logical page)
     kv_len: jax.Array,      # [B] int32
+    layer: jax.Array,       # int32 scalar, the pool's layer to read
     window: int = 0,
     sm_scale: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
     b, h, d = q.shape
-    page_size, width = k_pages.shape[1], k_pages.shape[2]
+    page_size, width = k_pages.shape[2], k_pages.shape[3]
     hkv = width // d
     n_pages = page_table.shape[1]
     g = h // hkv
@@ -307,26 +318,24 @@ def paged_decode_attention_fwd(
         chunk=chunk,
     )
 
+    # The page-table gather: logical page ik of sequence b_ lives in
+    # pool page pt[b_, ik] of the layer's pool — resolved at DMA-schedule
+    # time.  One block is a whole page row-major, every kv head on the
+    # lanes: the pool's own layout, so nothing is transposed.
+    page_spec = pl.BlockSpec(
+        (None, 1, page_size, width),
+        lambda b_, ik, ly, pt, kl: (ly[0], pt[b_, ik], 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_pages),
         in_specs=[
-            pl.BlockSpec((1, g, width), lambda b_, ik, pt, kl: (b_, 0, 0)),
-            # The page-table gather: logical page ik of sequence b_ lives
-            # in pool page pt[b_, ik] — resolved at DMA-schedule time.
-            # One block is a whole page row-major, every kv head on the
-            # lanes: the pool's own layout, so nothing is transposed.
-            pl.BlockSpec(
-                (1, page_size, width),
-                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, page_size, width),
-                lambda b_, ik, pt, kl: (pt[b_, ik], 0, 0),
-            ),
+            pl.BlockSpec((1, g, width), lambda b_, ik, ly, pt, kl: (b_, 0, 0)),
+            page_spec,
+            page_spec,
         ],
         out_specs=pl.BlockSpec(
-            (1, g, width), lambda b_, ik, pt, kl: (b_, 0, 0)
+            (1, g, width), lambda b_, ik, ly, pt, kl: (b_, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((g * (width // chunk), chunk, chunk),
@@ -342,8 +351,8 @@ def paged_decode_attention_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, width), q.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qg,
-      k_pages, v_pages)
+    )(_layer_operand(layer), page_table.astype(jnp.int32),
+      kv_len.astype(jnp.int32), qg, k_pages, v_pages)
     return out.reshape(b, g, hkv, d).swapaxes(1, 2).reshape(b, h, d)
 
 
@@ -353,13 +362,14 @@ def paged_decode_attention_fwd(
 
 
 def _kv_append_kernel(
+    layer_ref,   # scalar prefetch [1] int32 layer of the stacked pools
     pt_ref,      # scalar prefetch [B, n_pages] int32
     pos_ref,     # scalar prefetch [B] int32 (write position per sequence)
     *refs,       # n new rows [1, 1, W], n target pages [1, page, W]
                  # aliased in/out, then the n output pages
     page_size: int,
 ):
-    del pt_ref  # consumed by the index maps
+    del layer_ref, pt_ref  # consumed by the index maps
     ib = pl.program_id(0)
     n = len(refs) // 3
     new, pages, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
@@ -375,11 +385,13 @@ def _kv_append_kernel(
         out_ref[0] = jnp.where(mine, new_ref[0], page_ref[0])
 
 
-def _append_rows(rows, pools, page_table, pos, interpret):
-    """Write row ``b`` of each ``rows[i]`` ``[B, 1, W_i]`` into pool
-    ``pools[i]`` ``[P, page, W_i]`` at sequence b's position, in place."""
+def _append_rows(rows, pools, page_table, pos, layer, interpret):
+    """Write row ``b`` of each ``rows[i]`` ``[B, 1, W_i]`` into layer
+    ``layer`` of pool ``pools[i]`` ``[L, P, page, W_i]`` at sequence b's
+    position, in place: the whole stacked pool is aliased, and only the
+    one page a sequence writes moves."""
     b = rows[0].shape[0]
-    page_size = pools[0].shape[1]
+    page_size = pools[0].shape[2]
     n_pages = page_table.shape[1]
     n = len(pools)
     kernel = functools.partial(_kv_append_kernel, page_size=page_size)
@@ -393,45 +405,49 @@ def _append_rows(rows, pools, page_table, pos, interpret):
     # garbage write into a live request's page.  Clamped, the write
     # lands in the slot's own last table entry (the scratch page 0 for
     # an idle, all-zero table row).
-    page_idx = lambda b_, pt, ps: (
-        pt[b_, jnp.minimum(ps[b_] // page_size, n_pages - 1)], 0, 0
+    page_idx = lambda b_, ly, pt, ps: (
+        ly[0], pt[b_, jnp.minimum(ps[b_] // page_size, n_pages - 1)], 0, 0
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, 1, r.shape[2]), lambda b_, pt, ps: (b_, 0, 0))
+        in_specs=[pl.BlockSpec((1, 1, r.shape[2]),
+                               lambda b_, ly, pt, ps: (b_, 0, 0))
                   for r in rows]
-        + [pl.BlockSpec((1, page_size, p.shape[2]), page_idx) for p in pools],
-        out_specs=[pl.BlockSpec((1, page_size, p.shape[2]), page_idx)
+        + [pl.BlockSpec((None, 1, page_size, p.shape[3]), page_idx)
+           for p in pools],
+        out_specs=[pl.BlockSpec((None, 1, page_size, p.shape[3]), page_idx)
                    for p in pools],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
-        # Operand indices count the two scalar-prefetch args, then the n
-        # new rows: the pools follow, aliased so the update is in place.
-        input_output_aliases={2 + n + i: i for i in range(n)},
+        # Operand indices count the three scalar-prefetch args, then the
+        # n new rows: the pools follow, aliased so the update is in place.
+        input_output_aliases={3 + n + i: i for i in range(n)},
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), *rows, *pools)
+    )(_layer_operand(layer), page_table.astype(jnp.int32),
+      pos.astype(jnp.int32), *rows, *pools)
 
 
 def paged_kv_append_fwd(
     k_new: jax.Array,       # [B, Hkv, D] this tick's keys
     v_new: jax.Array,       # [B, Hkv, D]
-    k_pages: jax.Array,     # [P, page_size, Hkv*D]
-    v_pages: jax.Array,     # [P, page_size, Hkv*D]
+    k_pages: jax.Array,     # [L, P, page_size, Hkv*D]
+    v_pages: jax.Array,     # [L, P, page_size, Hkv*D]
     page_table: jax.Array,  # [B, n_pages] int32
     pos: jax.Array,         # [B] int32 write positions (== kv_len pre-append)
+    layer: jax.Array,       # int32 scalar, the pool's layer to write
     interpret: bool = False,
 ) -> "tuple[jax.Array, jax.Array]":
     b = k_new.shape[0]
-    width = k_pages.shape[2]
+    width = k_pages.shape[3]
     # One lane-dense row per sequence, in the pool's dtype.
     k_row = k_new.reshape(b, 1, width).astype(k_pages.dtype)
     v_row = v_new.reshape(b, 1, width).astype(v_pages.dtype)
     k_pages, v_pages = _append_rows((k_row, v_row), (k_pages, v_pages),
-                                    page_table, pos, interpret)
+                                    page_table, pos, layer, interpret)
     return k_pages, v_pages
 
 
@@ -441,6 +457,7 @@ def paged_kv_append_fwd(
 
 
 def _paged_latent_decode_kernel(
+    layer_ref,   # scalar prefetch [1] int32 layer of the stacked pool
     pt_ref,      # scalar prefetch [B, n_blocks * pages_per_block] int32
     kv_len_ref,  # scalar prefetch [B] int32
     q_ref,       # [1, H, W] latent-space queries (absorbed q, rope q, 0)
@@ -451,7 +468,7 @@ def _paged_latent_decode_kernel(
     pages_per_block: int,
     value_dim: int,
 ):
-    del pt_ref  # consumed by the index maps
+    del layer_ref, pt_ref  # consumed by the index maps
     pages = refs[:pages_per_block]
     o_ref, m_ref, l_ref, acc_ref = refs[pages_per_block:]
     ib, ik = pl.program_id(0), pl.program_id(1)
@@ -510,16 +527,17 @@ def latent_pages_per_block(page_size: int) -> int:
 
 def paged_latent_decode_fwd(
     q: jax.Array,           # [B, H, W]
-    pages: jax.Array,       # [P, page_size, W] latent rows
+    pages: jax.Array,       # [L, P, page_size, W] latent rows
     page_table: jax.Array,  # [B, n_pages] int32
     kv_len: jax.Array,      # [B] int32
+    layer: jax.Array,       # int32 scalar, the pool's layer to read
     value_dim: int,
     sm_scale: float,
     pages_per_block: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     b, h, w = q.shape
-    page_size = pages.shape[1]
+    page_size = pages.shape[2]
     ppb = pages_per_block or latent_pages_per_block(page_size)
     n_pages = page_table.shape[1]
     ppb = min(ppb, n_pages)
@@ -534,19 +552,21 @@ def paged_latent_decode_fwd(
 
     def page_spec(j):
         # The page-table gather: page j of block ik of sequence b_ lives
-        # in pool page pt[b_, ik * ppb + j], resolved at DMA-schedule time.
+        # in pool page pt[b_, ik * ppb + j] of the layer's pool, resolved
+        # at DMA-schedule time.
         return pl.BlockSpec(
-            (1, page_size, w),
-            lambda b_, ik, pt, kl: (pt[b_, ik * ppb + j], 0, 0),
+            (None, 1, page_size, w),
+            lambda b_, ik, ly, pt, kl: (ly[0], pt[b_, ik * ppb + j], 0, 0),
         )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_blocks),
-        in_specs=[pl.BlockSpec((1, h, w), lambda b_, ik, pt, kl: (b_, 0, 0))]
+        in_specs=[pl.BlockSpec((1, h, w),
+                               lambda b_, ik, ly, pt, kl: (b_, 0, 0))]
         + [page_spec(j) for j in range(ppb)],
         out_specs=pl.BlockSpec((1, h, value_dim),
-                               lambda b_, ik, pt, kl: (b_, 0, 0)),
+                               lambda b_, ik, ly, pt, kl: (b_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, LANE), jnp.float32),
             pltpu.VMEM((h, LANE), jnp.float32),
@@ -558,17 +578,19 @@ def paged_latent_decode_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
         interpret=interpret,
-    )(table, kv_len.astype(jnp.int32), q, *([pages] * ppb))
+    )(_layer_operand(layer), table, kv_len.astype(jnp.int32), q,
+      *([pages] * ppb))
 
 
 def paged_latent_append_fwd(
     row: jax.Array,         # [B, W] this tick's latent rows
-    pages: jax.Array,       # [P, page_size, W]
+    pages: jax.Array,       # [L, P, page_size, W]
     page_table: jax.Array,  # [B, n_pages] int32
     pos: jax.Array,         # [B] int32 write positions
+    layer: jax.Array,       # int32 scalar, the pool's layer to write
     interpret: bool = False,
 ) -> jax.Array:
     b, w = row.shape
     (pages,) = _append_rows((row.reshape(b, 1, w).astype(pages.dtype),),
-                            (pages,), page_table, pos, interpret)
+                            (pages,), page_table, pos, layer, interpret)
     return pages

@@ -28,7 +28,6 @@ from repro.config.base import ArchConfig, AttentionKind, LayerSpec, YarnScaling
 from repro.distributed.sharding import shard
 from repro.kernels import platform
 from repro.kernels.decode_attention import (
-    gather_pages,
     paged_decode_attention,
     paged_kv_append,
     paged_latent_append,
@@ -44,7 +43,10 @@ class PagedSpec:
 
     Each attention layer's ``k_pages`` and ``v_pages`` are ``[num_pages,
     page_size, Hkv * head_dim]``: one row per token with every kv head's
-    lanes side by side, row-major, as the paged kernels read them.
+    lanes side by side, row-major, as the paged kernels read them.  The
+    scanned layers' pools are stacked ``[n_periods, ...]``, and the
+    decode scan hands each layer the whole stack with its index under
+    the cache's ``"layer"`` key (see ``_pool_at_layer``).
 
     ``num_pages`` counts the whole pool including page 0, which is
     reserved as a scratch page: inactive batcher slots keep an all-zero
@@ -402,14 +404,35 @@ def _blockwise_attention(qg, k, v, positions, kv_pos, valid, cfg, window,
     return (acc_f / denom).astype(v.dtype)
 
 
-def _scatter_to_pages(pages: jax.Array, new: jax.Array,
-                      flat_idx: jax.Array) -> jax.Array:
-    """Write token rows into a page pool at flat (page*size+offset) slots.
+def _pool_at_layer(cache: Params, key: str) -> Tuple[jax.Array, Any, bool]:
+    """``(pool, layer, stacked)`` for the paged cache entry's pool
+    ``cache[key]``: a scanned layer's entry holds the stacked ``[L, P,
+    page, W]`` pool and its index under ``"layer"``; any other layer's
+    pool ``[P, page, W]`` is the free view ``pool[None]`` at layer 0,
+    so every pool takes one path."""
+    pool = cache[key]
+    if "layer" in cache:
+        return pool, cache["layer"], True
+    return pool[None], 0, False
 
-    pages [P, page, Hkv*hd], new [N, Hkv*hd], flat_idx [N]."""
-    p, page, width = pages.shape
-    flat = pages.reshape(p * page, width).at[flat_idx].set(new)
-    return flat.reshape(pages.shape)
+
+def _scatter_to_pages(pages: jax.Array, layer, new: jax.Array,
+                      flat_idx: jax.Array) -> jax.Array:
+    """Write token rows into layer ``layer`` of a stacked page pool at
+    flat (page*size+offset) slots, in place in the pool.
+
+    pages [L, P, page, Hkv*hd], new [N, Hkv*hd], flat_idx [N]."""
+    page = pages.shape[2]
+    return pages.at[layer, flat_idx // page, flat_idx % page].set(new)
+
+
+def _gather_layer(pages: jax.Array, layer, page_table: jax.Array) -> jax.Array:
+    """The dense rows layer ``layer``'s page table describes: pages [L,
+    P, page, W] + table [B, n] -> [B, n*page, W], gathered straight from
+    the stacked pool (the layer's pool is never sliced out whole)."""
+    b, n = page_table.shape
+    return pages[layer, page_table].reshape(b, n * pages.shape[2],
+                                            pages.shape[3])
 
 
 def _flat_slots(page_table: jax.Array, cache_pos: jax.Array, tq: int,
@@ -450,9 +473,10 @@ def _paged_attention(
     """
     b, tq, h, hd = q.shape
     hkv = k.shape[2]
-    k_pages, v_pages = cache["k_pages"], cache["v_pages"]
+    k_pages, layer, stacked = _pool_at_layer(cache, "k_pages")
+    v_pages, _, _ = _pool_at_layer(cache, "v_pages")
     page_table, cache_pos = cache["page_table"], cache["pos"]
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_slot = page_table.shape[1]
     s_slot = n_slot * page
     window = spec.window if spec.attention == AttentionKind.SLIDING else 0
@@ -460,10 +484,11 @@ def _paged_attention(
 
     if tq == 1 and platform.compiled_kernels() and cfg.logit_softcap == 0:
         k_pages, v_pages = paged_kv_append(
-            k[:, 0], v[:, 0], k_pages, v_pages, page_table, cache_pos
+            k[:, 0], v[:, 0], k_pages, v_pages, page_table, cache_pos, layer
         )
         out = paged_decode_attention(
-            q[:, 0], k_pages, v_pages, page_table, kv_len, window=window
+            q[:, 0], k_pages, v_pages, page_table, kv_len, layer,
+            window=window,
         )
         out = out[:, None].astype(v.dtype)  # [B, 1, H, hd]
     else:
@@ -472,13 +497,15 @@ def _paged_attention(
         # dense view of each slot's pages.
         flat_idx = _flat_slots(page_table, cache_pos, tq, page)
         k_pages = _scatter_to_pages(
-            k_pages, k.reshape(b * tq, hkv * hd), flat_idx
+            k_pages, layer, k.reshape(b * tq, hkv * hd), flat_idx
         )
         v_pages = _scatter_to_pages(
-            v_pages, v.reshape(b * tq, hkv * hd), flat_idx
+            v_pages, layer, v.reshape(b * tq, hkv * hd), flat_idx
         )
-        k_dense = gather_pages(k_pages, page_table).reshape(b, s_slot, hkv, hd)
-        v_dense = gather_pages(v_pages, page_table).reshape(b, s_slot, hkv, hd)
+        k_dense = _gather_layer(k_pages, layer, page_table).reshape(
+            b, s_slot, hkv, hd)
+        v_dense = _gather_layer(v_pages, layer, page_table).reshape(
+            b, s_slot, hkv, hd)
         kv_pos = jnp.broadcast_to(
             jnp.arange(s_slot, dtype=positions.dtype)[None, :], (b, s_slot)
         )
@@ -489,8 +516,8 @@ def _paged_attention(
         )
 
     new_cache = {
-        "k_pages": k_pages,
-        "v_pages": v_pages,
+        "k_pages": k_pages if stacked else k_pages[0],
+        "v_pages": v_pages if stacked else v_pages[0],
         "page_table": page_table,
         "pos": kv_len,
     }
@@ -624,27 +651,28 @@ def latent_attention(
         out = _latent_attend(params, q_nope, q_rope, row, positions,
                              positions[:, -1] + 1, cfg)
     elif "page_table" in cache:
-        pages, table, pos = (cache["latent_pages"], cache["page_table"],
-                             cache["pos"])
+        pages, layer, stacked = _pool_at_layer(cache, "latent_pages")
+        table, pos = cache["page_table"], cache["pos"]
         kv_len = pos + tq
         if tq == 1 and platform.compiled_kernels():
-            pages = paged_latent_append(row[:, 0], pages, table, pos)
+            pages = paged_latent_append(row[:, 0], pages, table, pos, layer)
             q_lat = _absorb(params, q_nope)[:, 0]  # [B, H, r]
             qf = jnp.concatenate(
                 [q_lat, q_rope[:, 0].astype(dt),
                  jnp.zeros((b, q.shape[2], w - r - dr), dt)], axis=-1)
             o_lat = paged_latent_decode(
-                qf.astype(pages.dtype), pages, table, kv_len, value_dim=r,
-                sm_scale=mla_softmax_scale(cfg))
+                qf.astype(pages.dtype), pages, table, kv_len, layer,
+                value_dim=r, sm_scale=mla_softmax_scale(cfg))
             out = _unabsorb(params, o_lat[:, None].astype(dt))
         else:
-            flat = _flat_slots(table, pos, tq, pages.shape[1])
+            flat = _flat_slots(table, pos, tq, pages.shape[2])
             pages = _scatter_to_pages(
-                pages, row.reshape(b * tq, w).astype(pages.dtype), flat)
+                pages, layer, row.reshape(b * tq, w).astype(pages.dtype), flat)
             out = _latent_attend(params, q_nope, q_rope,
-                                 gather_pages(pages, table), positions,
+                                 _gather_layer(pages, layer, table), positions,
                                  kv_len, cfg)
-        new_cache = {"latent_pages": pages, "page_table": table, "pos": kv_len}
+        new_cache = {"latent_pages": pages if stacked else pages[0],
+                     "page_table": table, "pos": kv_len}
     else:
         pos = cache["pos"]
         rows = jax.vmap(

@@ -11,7 +11,11 @@ leading dense layer) run unrolled before the scan, with their own params
 and caches under ``"lead"``.
 
 Caches (attention KV / mamba conv+ssm states) are pytrees with the same
-period structure, threaded through the scan as (xs -> ys).
+period structure, threaded through the scan as (xs -> ys), except the
+page pools (every ``*_pages`` leaf): those ride in the scan's carry,
+stacked ``[n_periods, P, page, W]``, and each layer updates its own
+layer of them in place, so no layer's pool is sliced out and written
+back (see ``_split_pools``).
 """
 
 from __future__ import annotations
@@ -169,6 +173,44 @@ def apply_block(
 # ---------------------------------------------------------------------------
 # the full model
 # ---------------------------------------------------------------------------
+
+
+def _is_pool_key(key: Any) -> bool:
+    """A page pool's key in a cache: ``k_pages``, ``v_pages``,
+    ``latent_pages``."""
+    return str(key).endswith("_pages")
+
+
+def _split_pools(tree: Any) -> Tuple[List[Any], List[Any], Any, List[bool]]:
+    """``(pools, rest, treedef, is_pool)``: the tree's page-pool leaves
+    apart from its other leaves, and what ``_join_pools`` needs to put
+    them back."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    is_pool = [isinstance(path[-1], jax.tree_util.DictKey)
+               and _is_pool_key(path[-1].key) for path, _ in flat]
+    pools = [leaf for (_, leaf), m in zip(flat, is_pool) if m]
+    rest = [leaf for (_, leaf), m in zip(flat, is_pool) if not m]
+    return pools, rest, treedef, is_pool
+
+
+def _join_pools(treedef: Any, is_pool: List[bool], pools: List[Any],
+                rest: List[Any]) -> Any:
+    it_pools, it_rest = iter(pools), iter(rest)
+    return treedef.unflatten(
+        [next(it_pools) if m else next(it_rest) for m in is_pool])
+
+
+def _at_layer(tree: Any, layer: jax.Array) -> Any:
+    """The cache tree with ``"layer": layer`` beside every page pool: the
+    index into the stacked pool that the paged attention layers take."""
+    if isinstance(tree, dict):
+        out = {k: _at_layer(v, layer) for k, v in tree.items()}
+        if any(_is_pool_key(k) for k in tree):
+            out["layer"] = layer
+        return out
+    if isinstance(tree, (list, tuple)):
+        return type(tree)(_at_layer(v, layer) for v in tree)
+    return tree
 
 
 def _period_counts(cfg: ArchConfig) -> Tuple[int, int]:
@@ -365,25 +407,34 @@ def forward(
             cache["periods"] if cache is not None else [None] * len(cfg.pattern)
         )
 
-        def body2(carry, xs):
-            x, aux, held = carry
-            layer_ps, layer_cs = xs
-            new_cs: List[Any] = []
-            for pos, spec in enumerate(cfg.pattern):
-                cache_entry = None if layer_cs is None else layer_cs[pos]
-                x, nc, a, hd = apply_block(
-                    layer_ps[pos], spec, x, positions, cfg,
-                    cache_entry, enc_out,
-                )
-                aux, held = aux + a, held + hd
-                new_cs.append(nc)
-            return (x, aux, held), tuple(new_cs)
-
         if cache is not None:
-            (x, aux_total, held_total), new_period_caches = jax.lax.scan(
-                body2, (x, aux_total, held_total),
-                (tuple(period_params), tuple(period_caches)),
+            # The stacked pools ride in the carry, whole; the per-layer
+            # leaves (page tables, positions, dense and ring caches) are
+            # sliced per layer as xs and stacked back as ys.
+            pools, rest, treedef, is_pool = _split_pools(tuple(period_caches))
+
+            def body2(carry, xs):
+                x, aux, held, pools = carry
+                layer_ps, rest, layer = xs
+                layer_cs = _at_layer(
+                    _join_pools(treedef, is_pool, pools, rest), layer)
+                new_cs: List[Any] = []
+                for pos, spec in enumerate(cfg.pattern):
+                    x, nc, a, hd = apply_block(
+                        layer_ps[pos], spec, x, positions, cfg,
+                        layer_cs[pos], enc_out,
+                    )
+                    aux, held = aux + a, held + hd
+                    new_cs.append(nc)
+                new_pools, new_rest, _, _ = _split_pools(tuple(new_cs))
+                return (x, aux, held, new_pools), new_rest
+
+            (x, aux_total, held_total, pools), rest = jax.lax.scan(
+                body2, (x, aux_total, held_total, pools),
+                (tuple(period_params), rest,
+                 jnp.arange(n_periods, dtype=jnp.int32)),
             )
+            new_period_caches = _join_pools(treedef, is_pool, pools, rest)
         else:
             def body_nocache(carry, layer_ps):
                 x, aux = carry

@@ -370,12 +370,14 @@ class ContinuousBatcher:
             return
         from jax.tree_util import DictKey, tree_map_with_path
 
-        table = jnp.asarray(self._page_table)
-
         def set_table(path, leaf):
             last = path[-1]
             if isinstance(last, DictKey) and last.key == "page_table":
-                return jnp.broadcast_to(table, leaf.shape).astype(leaf.dtype)
+                # Uploaded anew for every leaf, so no two leaves share a
+                # buffer: the decode step donates the cache, and a buffer
+                # cannot be donated twice.
+                table = jnp.asarray(self._page_table, dtype=leaf.dtype)
+                return jnp.broadcast_to(table, leaf.shape)
             return leaf
 
         self.cache = tree_map_with_path(set_table, self.cache)

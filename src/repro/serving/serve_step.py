@@ -32,7 +32,12 @@ def make_prefill_step(model: Model) -> Callable:
 
 
 def make_decode_step(model: Model, temperature: float = 0.0) -> Callable:
-    @jax.jit
+    """The jitted decode step.  It donates ``cache``: the returned cache
+    takes over its buffers, and the paged kernels update the stacked page
+    pools in place, so the caller rebinds its cache to the step's output
+    and reads no leaf of the one it passed in."""
+
+    @functools.partial(jax.jit, donate_argnames="cache")
     def decode_step(
         params: Params,
         tokens: jax.Array,    # [B, 1] current tokens

@@ -174,14 +174,21 @@ def test_absorbed_decode_matches_upprojected_per_layer(setup):
                                    atol=1e-5 * float(jnp.abs(u).max()))
 
 
+@pytest.mark.parametrize("layer", [pytest.param(0, id="first"),
+                                   pytest.param(1, id="middle"),
+                                   pytest.param(2, id="last")])
 @pytest.mark.parametrize("pages_per_block", [None, 2])
-def test_paged_latent_decode_and_append_match_reference(pages_per_block):
-    """(c) The kernel and its append in interpret mode against the jnp
-    references: a ragged page table, rows of 1, 20 and 48 live tokens, a
-    grid of one block and of several."""
+def test_paged_latent_decode_and_append_match_reference(pages_per_block, layer):
+    """(c) The kernel and its append in interpret mode on one layer of a
+    stacked pool of three against the jnp references on that layer's
+    pool: a ragged page table, rows of 1, 20 and 48 live tokens, a grid
+    of one block and of several; the append leaves the other layers
+    bitwise as they were."""
     rng = np.random.default_rng(2)
-    n_pool, page, w, h, v = 20, 8, 128, 4, 32
-    pages = jnp.asarray(rng.standard_normal((n_pool, page, w)), jnp.float32)
+    n_layers, n_pool, page, w, h, v = 3, 20, 8, 128, 4, 32
+    stack = jnp.asarray(rng.standard_normal((n_layers, n_pool, page, w)),
+                        jnp.float32)
+    pages = stack[layer]
     table = jnp.asarray([[3, 7, 1, 0, 0, 0],
                          [2, 0, 0, 0, 0, 0],
                          [5, 6, 8, 9, 10, 11]], jnp.int32)
@@ -189,19 +196,23 @@ def test_paged_latent_decode_and_append_match_reference(pages_per_block):
     q = jnp.asarray(rng.standard_normal((3, h, w)), jnp.float32)
     want = paged_latent_decode_ref(q, pages, table, kv_len, v, 0.3)
     if pages_per_block is None:
-        got = paged_latent_decode(q, pages, table, kv_len, value_dim=v,
+        got = paged_latent_decode(q, stack, table, kv_len, layer, value_dim=v,
                                   sm_scale=0.3, interpret=True)
     else:
-        got = paged_latent_decode_fwd(q, pages, table, kv_len, v, 0.3,
+        got = paged_latent_decode_fwd(q, stack, table, kv_len, layer, v, 0.3,
                                       pages_per_block=pages_per_block,
                                       interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
     row = jnp.asarray(rng.standard_normal((3, w)), jnp.float32)
     pos = jnp.asarray([20, 1, 47], jnp.int32)
-    got = paged_latent_append(row, pages, table, pos, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(paged_latent_append_ref(row, pages, table, pos)))
+    want = np.asarray(paged_latent_append_ref(row, pages, table, pos))
+    before = np.asarray(stack)
+    got = np.asarray(paged_latent_append(row, stack, table, pos, layer,
+                                         interpret=True))
+    np.testing.assert_array_equal(got[layer], want)
+    others = [i for i in range(n_layers) if i != layer]
+    np.testing.assert_array_equal(got[others], before[others])
 
 
 def _share(params, first, held):

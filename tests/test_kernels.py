@@ -217,13 +217,21 @@ def test_decode_attention_wrapper_validation():
 # ---------------------------------------------------------------------------
 
 
+# Layers of the stacked pools the paged kernels take, and the ones a
+# test runs them on: the first, a middle and the last.
+LAYERS = 3
+LAYER_CASES = [pytest.param(0, id="first"), pytest.param(1, id="middle"),
+               pytest.param(2, id="last")]
+
+
 def _random_paged_cache(seed, b, n_slot_pages, page, hkv, d, pool_pages,
-                        dtype=jnp.float32):
-    """Lane-dense pool tensors ``[P, page, Hkv*d]`` + a page table of
-    distinct ids >= 1 (page 0 is the reserved scratch page — real slots
-    never map to it)."""
+                        dtype=jnp.float32, layers=LAYERS):
+    """Lane-dense stacked pool tensors ``[L, P, page, Hkv*d]``, every
+    layer its own random pages, + a page table of distinct ids >= 1
+    (page 0 is the reserved scratch page — real slots never map to
+    it)."""
     ks = jax.random.split(K(seed), 3)
-    shape = (pool_pages, page, hkv * d)
+    shape = (layers, pool_pages, page, hkv * d)
     k_pages = jax.random.normal(ks[0], shape, dtype)
     v_pages = jax.random.normal(ks[1], shape, dtype)
     perm = jax.random.permutation(ks[2], jnp.arange(1, pool_pages))
@@ -252,21 +260,25 @@ def _dense(pages, table, d):
         pytest.param([32, 9, 2], 5, 8, 2, 64, jnp.bfloat16, id="bf16"),
     ],
 )
+@pytest.mark.parametrize("layer", LAYER_CASES)
 def test_paged_decode_matches_dense_gather(kv_len, window, h, hkv, d,
-                                           dtype):
-    """Paged kernel == dense kernel == oracle over the gathered cache.
-    The table is a random permutation, so a row's pages are scattered
-    through the pool (the gather really is exercised)."""
+                                           dtype, layer):
+    """Paged kernel == dense kernel == oracle over the gathered cache of
+    the named layer of the stacked pool.  The table is a random
+    permutation, so a row's pages are scattered through the pool (the
+    gather really is exercised), and every layer's pages differ, so a
+    read of the wrong layer cannot pass."""
     b, page, n = 3, 8, 4  # n*page = 32 tokens/slot
     kp, vp, table = _random_paged_cache(23, b, n, page, hkv, d, 1 + b * n,
                                         dtype)
     q = jax.random.normal(K(24), (b, h, d), dtype)
     kv = jnp.asarray(kv_len, dtype=jnp.int32)
-    out = paged_decode_attention(q, kp, vp, table, kv, window=window,
+    out = paged_decode_attention(q, kp, vp, table, kv, layer, window=window,
                                  interpret=True)
-    dense = decode_attention(q, _dense(kp, table, d), _dense(vp, table, d),
+    kl, vl = kp[layer], vp[layer]
+    dense = decode_attention(q, _dense(kl, table, d), _dense(vl, table, d),
                              kv, window=window, block_k=128, interpret=True)
-    ref = paged_decode_attention_ref(q, kp, vp, table, kv, window=window)
+    ref = paged_decode_attention_ref(q, kl, vl, table, kv, window=window)
     tol = TOLS[dtype]
     f32 = lambda x: np.asarray(x, dtype=np.float32)
     np.testing.assert_allclose(f32(out), f32(ref), **tol)
@@ -284,23 +296,28 @@ def test_paged_vs_dense_decode_property(seed):
     rng = np.random.RandomState(seed % (2**31 - 1))
     kv = jnp.asarray(rng.randint(0, n * page + 1, size=b), dtype=jnp.int32)
     window = int(rng.choice([0, 5, page + 1]))
+    layer = int(rng.randint(0, LAYERS))
     q = jax.random.normal(K(seed % 997), (b, h, d))
-    out = paged_decode_attention(q, kp, vp, table, kv, window=window,
+    out = paged_decode_attention(q, kp, vp, table, kv, layer, window=window,
                                  interpret=True)
-    dense = decode_attention(q, _dense(kp, table, d), _dense(vp, table, d),
+    dense = decode_attention(q, _dense(kp[layer], table, d),
+                             _dense(vp[layer], table, d),
                              kv, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_paged_kv_append_matches_ref_at_page_boundaries():
-    """Append at a page's first row, last row, and mid-page; each new
-    row is written whole into its page, and every other row of that page
-    and every other page stays bitwise identical (in-place aliasing is
+@pytest.mark.parametrize("layer", LAYER_CASES)
+def test_paged_kv_append_matches_ref_at_page_boundaries(layer):
+    """Append into the named layer of the stacked pools at a page's
+    first row, last row, and mid-page; each new row is written whole
+    into its page, and every other row of that page, every other page
+    and every other layer stays bitwise identical (in-place aliasing is
     exact).  Rows of two heads of 64, one of 128 and three of 32."""
     b, page, n = 4, 8, 3
     # start / last-of-0 / first-of-1 / mid-page of 2
     pos = jnp.asarray([0, 7, 8, 19], dtype=jnp.int32)
+    others = [i for i in range(LAYERS) if i != layer]
     for hkv, d in [(2, 64), (1, 128), (3, 32)]:
         kp, vp, table = _random_paged_cache(25, b, n, page, hkv, d,
                                             1 + b * n)
@@ -309,13 +326,17 @@ def test_paged_kv_append_matches_ref_at_page_boundaries():
         new = {"k": jax.random.normal(ks[0], (b, hkv, d)),
                "v": jax.random.normal(ks[1], (b, hkv, d))}
         # ref first: the kernel donates (aliases) the pool buffers.
-        rk, rv = paged_kv_append_ref(new["k"], new["v"], kp, vp, table, pos)
+        rk, rv = paged_kv_append_ref(new["k"], new["v"], kp[layer],
+                                     vp[layer], table, pos)
         k2, v2 = paged_kv_append(new["k"], new["v"], kp, vp, table, pos,
-                                 interpret=True)
-        np.testing.assert_array_equal(np.asarray(k2), np.asarray(rk))
-        np.testing.assert_array_equal(np.asarray(v2), np.asarray(rv))
+                                 layer, interpret=True)
+        np.testing.assert_array_equal(np.asarray(k2)[layer], np.asarray(rk))
+        np.testing.assert_array_equal(np.asarray(v2)[layer], np.asarray(rv))
         tab = np.asarray(table)
-        for name, after in (("k", np.asarray(k2)), ("v", np.asarray(v2))):
+        for name, stack in (("k", np.asarray(k2)), ("v", np.asarray(v2))):
+            np.testing.assert_array_equal(stack[others],
+                                          before[name][others])
+            after, prior = stack[layer], before[name][layer]
             written = np.zeros(after.shape[:2], dtype=bool)
             for row in range(b):
                 p = int(pos[row])
@@ -324,8 +345,7 @@ def test_paged_kv_append_matches_ref_at_page_boundaries():
                 np.testing.assert_array_equal(
                     after[pid, off], np.asarray(new[name])[row].reshape(-1)
                 )
-            np.testing.assert_array_equal(after[~written],
-                                          before[name][~written])
+            np.testing.assert_array_equal(after[~written], prior[~written])
 
 
 def test_paged_kv_append_traced_oob_pos_lands_in_own_last_page():
@@ -336,18 +356,19 @@ def test_paged_kv_append_traced_oob_pos_lands_in_own_last_page():
     scratch page 0 for an idle, all-zero table row — never via an
     undefined OOB table read into a live request's pages."""
     b, hkv, d, page, n = 2, 2, 32, 4, 2
-    kp, vp, table = _random_paged_cache(31, b, n, page, hkv, d, 1 + b * n)
+    kp, vp, table = _random_paged_cache(31, b, n, page, hkv, d, 1 + b * n,
+                                        layers=1)
     table = table.at[1].set(0)  # row 1 idle: back to the scratch page
     ks = jax.random.split(K(32), 2)
     kn = jax.random.normal(ks[0], (b, hkv, d))
     vn = jax.random.normal(ks[1], (b, hkv, d))
     pos = jnp.asarray([2, n * page + 57], dtype=jnp.int32)
-    before_k = np.asarray(kp)
+    before_k = np.asarray(kp)[0]
     append = jax.jit(
         lambda *a: paged_kv_append(*a, interpret=True)
     )  # traced operands: the concrete range-check cannot fire
-    k2, v2 = append(kn, vn, kp, vp, table, pos)
-    k2 = np.asarray(k2)
+    k2, v2 = append(kn, vn, kp, vp, table, pos, 0)
+    k2 = np.asarray(k2)[0]
     tab = np.asarray(table)
     # live row 0: written exactly where expected
     np.testing.assert_array_equal(k2[tab[0, 0], 2],
@@ -355,7 +376,7 @@ def test_paged_kv_append_traced_oob_pos_lands_in_own_last_page():
     # idle row 1: only the scratch page may have changed — every other
     # pool page is bitwise identical apart from row 0's single write
     untouched = [
-        pid for pid in range(1, kp.shape[0]) if pid != tab[0, 0]
+        pid for pid in range(1, kp.shape[1]) if pid != tab[0, 0]
     ]
     np.testing.assert_array_equal(k2[untouched], before_k[untouched])
 
@@ -366,32 +387,47 @@ def test_paged_wrapper_validation():
     q = jax.random.normal(K(28), (b, 4, d))
     kv = jnp.asarray([3, 5], dtype=jnp.int32)
     with pytest.raises(TypeError, match="integer-typed"):
-        paged_decode_attention(q, kp, vp, table.astype(jnp.float32), kv,
+        paged_decode_attention(q, kp, vp, table.astype(jnp.float32), kv, 0,
                                interpret=True)
     with pytest.raises(ValueError, match="exceeds the cache"):
         # kv_len beyond what the table can address
         paged_decode_attention(q, kp, vp, table,
-                               jnp.asarray([n * page + 1, 0]), interpret=True)
+                               jnp.asarray([n * page + 1, 0]), 0,
+                               interpret=True)
     with pytest.raises(ValueError, match="exceeds the cache"):
         # page id beyond the pool
-        bad = table.at[0, 0].set(kp.shape[0])
-        paged_decode_attention(q, kp, vp, bad, kv, interpret=True)
-    with pytest.raises(ValueError, match="page_table must be"):
-        paged_decode_attention(q, kp, vp, table[0], kv, interpret=True)
-    with pytest.raises(ValueError, match=r"Hkv\*D"):
-        # the retired [P, page, Hkv, D] pool
-        paged_decode_attention(q, kp.reshape(-1, page, hkv, d),
-                               vp.reshape(-1, page, hkv, d), table, kv,
+        bad = table.at[0, 0].set(kp.shape[1])
+        paged_decode_attention(q, kp, vp, bad, kv, 0, interpret=True)
+    with pytest.raises(ValueError, match="exceeds the cache"):
+        # layer beyond the stack
+        paged_decode_attention(q, kp, vp, table, kv, LAYERS, interpret=True)
+    with pytest.raises(TypeError, match="integer-typed"):
+        paged_decode_attention(q, kp, vp, table, kv, 1.0, interpret=True)
+    with pytest.raises(ValueError, match="layer must be a scalar"):
+        paged_decode_attention(q, kp, vp, table, kv, jnp.zeros(1, jnp.int32),
                                interpret=True)
+    with pytest.raises(ValueError, match="page_table must be"):
+        paged_decode_attention(q, kp, vp, table[0], kv, 0, interpret=True)
+    with pytest.raises(ValueError, match=r"Hkv\*D"):
+        # the retired [L, P, page, Hkv, D] pool
+        paged_decode_attention(q, kp.reshape(LAYERS, -1, page, hkv, d),
+                               vp.reshape(LAYERS, -1, page, hkv, d), table,
+                               kv, 0, interpret=True)
+    with pytest.raises(ValueError, match=r"Hkv\*D"):
+        # one layer's pool, not the stack: its view pool[None] is
+        paged_decode_attention(q, kp[0], vp[0], table, kv, 0, interpret=True)
     kn = jax.random.normal(K(29), (b, hkv, d))
     with pytest.raises(ValueError, match="exceeds the cache"):
         # concrete append position past the slot's table capacity
         paged_kv_append(kn, kn, kp, vp, table,
-                        jnp.asarray([0, n * page]), interpret=True)
+                        jnp.asarray([0, n * page]), 0, interpret=True)
+    with pytest.raises(ValueError, match="negative"):
+        paged_kv_append(kn, kn, kp, vp, table, jnp.asarray([0, 1]), -1,
+                        interpret=True)
     with pytest.raises(ValueError, match=r"Hkv\*D"):
         # a new row as wide as one head, not the pool's row
         paged_kv_append(kn[:, :1], kn[:, :1], kp, vp, table,
-                        jnp.asarray([0, 1]), interpret=True)
+                        jnp.asarray([0, 1]), 0, interpret=True)
 
 
 # ---------------------------------------------------------------------------

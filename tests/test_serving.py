@@ -166,3 +166,121 @@ def test_paged_release_resets_device_cache_pos(setup):
     assert pos_leaves, "paged transformer cache must carry pos leaves"
     for leaf in pos_leaves:
         assert int(jnp.max(jnp.abs(leaf))) == 0
+
+
+def _spy_on_donation(batcher):
+    """Wrap ``batcher``'s decode step to keep the page-pool leaves of
+    every cache it was handed."""
+    from jax.tree_util import tree_flatten_with_path
+
+    step, handed = batcher.decode_step, []
+
+    def spy(params, tokens, cache, positions, rng):
+        out = step(params, tokens, cache, positions, rng)
+        handed.extend(leaf for path, leaf in tree_flatten_with_path(cache)[0]
+                      if str(path[-1].key).endswith("_pages"))
+        return out
+
+    batcher.decode_step = spy
+    return handed
+
+
+@pytest.mark.parametrize("kernels", ["jnp", "pallas"])
+def test_donated_decode_survives_preemption_release_and_merge(
+        setup, monkeypatch, kernels):
+    """The decode step donates the cache, so the pools it was handed are
+    gone after it and the batcher goes on from the step's output.  Two
+    slots, four requests and five usable pages of four tokens: between
+    donated steps a running slot is preempted, finished slots release
+    their pages and reset their positions, and later requests are
+    admitted through the eager page merge; every request still gets the
+    dense reference's tokens, on the jnp path and through the Pallas
+    kernels (interpreted)."""
+    from repro.kernels import platform
+    from repro.kernels.decode_attention import ops
+    from repro.serving.kv_cache import PagedSpec
+
+    if kernels == "pallas":
+        monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+        monkeypatch.setattr(ops, "resolve_interpret", lambda interpret: True)
+    cfg, model, params = setup
+    prompts = [[5, 9, 2], [7, 1, 3], [11, 4, 6], [3, 8, 2]]
+    n_new = 8
+    b = ContinuousBatcher(model, params, slots=2, max_len=32,
+                          paged=PagedSpec(num_pages=1 + 5, page_size=4))
+    handed = _spy_on_donation(b)
+    for p in prompts:
+        b.submit(Request(prompt=p, max_new_tokens=n_new))
+    b.run_until_drained()
+    assert b.preemptions > 0, "the pool was never tight"
+    assert len(b.completed) == len(prompts)
+    assert handed and all(leaf.is_deleted() for leaf in handed)
+    by_prompt = {tuple(r.prompt): r.output for r in b.completed}
+    for p in prompts:
+        assert by_prompt[tuple(p)] == greedy_reference(model, params, p, n_new)
+    assert b.page_pool.in_use == 0 and b.page_pool.leaked() == 0
+
+
+def test_replicas_sharing_one_donated_decode_step_serve_their_own(setup):
+    """Two replicas of one ``ElasticServingPool`` share its compiled,
+    donating decode step, each over its own paged cache: each serves
+    requests, and every request gets the dense reference's tokens."""
+    from repro.core.elastic import AutoscalerConfig
+    from repro.serving import ElasticServingPool
+    from repro.serving.kv_cache import PagedSpec
+
+    cfg, model, params = setup
+    pool = ElasticServingPool(
+        model, params, slots_per_replica=2, max_len=32, max_replicas=2,
+        initial_units=4, paged=PagedSpec(num_pages=1 + 8, page_size=8),
+        autoscaler=AutoscalerConfig(min_workers=4, max_workers=4,
+                                    cooldown=0.0),
+    )
+    prompts = [[i % 7 + 2, i % 5 + 1, 3] for i in range(6)]
+    n_new = 5
+    now = 0.0
+    for p in prompts:
+        pool.submit(Request(prompt=p, max_new_tokens=n_new), now=now)
+    while pool.queue_depth() or pool.occupancy():
+        pool.step(now)
+        now += 1.0
+    replicas = pool.replicas
+    assert len(replicas) == 2
+    assert replicas[0].decode_step is replicas[1].decode_step
+    assert all(r.steps > 0 for r in replicas), [r.steps for r in replicas]
+    assert len(pool.completed) == len(prompts)
+    for r in pool.completed:
+        assert r.output == greedy_reference(model, params, r.prompt, n_new)
+
+
+def test_donated_decode_serves_unscanned_layers(monkeypatch):
+    """Layers outside the scan (here two remainder layers beside one
+    scanned period of three) keep a pool of their own each, which the
+    kernels take as the stacked pool's free view at layer 0.  Their page
+    tables must not share a buffer, since the donating decode step
+    cannot take one buffer twice; the batcher still gives the dense
+    reference's tokens through the Pallas kernels (interpreted)."""
+    import dataclasses
+
+    from repro.config.base import LayerSpec
+    from repro.kernels import platform
+    from repro.kernels.decode_attention import ops
+    from repro.serving.kv_cache import PagedSpec
+
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    monkeypatch.setattr(ops, "resolve_interpret", lambda interpret: True)
+    cfg = dataclasses.replace(get_arch("llama3.2-1b", smoke=True),
+                              num_layers=5, pattern=(LayerSpec(),) * 3)
+    model = build_model(cfg, compute_dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(1))
+    assert len(model.init_cache(1, 32)["remainder"]) == 2
+    prompts = [[5, 9, 2], [7, 1, 3]]
+    n_new = 4
+    b = ContinuousBatcher(model, params, slots=2, max_len=32,
+                          paged=PagedSpec(num_pages=1 + 8, page_size=8))
+    for p in prompts:
+        b.submit(Request(prompt=p, max_new_tokens=n_new))
+    b.run_until_drained()
+    by_prompt = {tuple(r.prompt): r.output for r in b.completed}
+    for p in prompts:
+        assert by_prompt[tuple(p)] == greedy_reference(model, params, p, n_new)

@@ -16,6 +16,7 @@ prefill, its decode over dense and paged caches, and its train step.
 
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -39,10 +40,12 @@ from repro.serving.serve_step import make_decode_step
 from repro.training.train_step import init_train_state, make_train_step
 
 HBM_BYTES = 16e9  # one v5e chip
-MINICPM = dict(slots=8, max_len=1024, page=16, pages=513)  # chip_smoke.py
-# bench/configs/deepseek-v2-lite-serve.json: 640-lane latent rows
+MINICPM = dict(slots=8, max_len=1024, page=16, pages=513,  # chip_smoke.py
+               layers=40)
+# bench/configs/deepseek-v2-lite-serve.json: 640-lane latent rows, the
+# leading dense layer's pool and the 26 scanned layers' stacked one
 DEEPSEEK = dict(slots=16, max_len=8192, page=16, pages=5121, heads=16,
-                width=640, latent=512)
+                width=640, latent=512, layers=26)
 
 
 @pytest.fixture(scope="module")
@@ -90,7 +93,7 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups, d):
     head_dim 64 and 128, pages of 16: each block is a whole lane-dense
     page, every kv head side by side."""
     b, page = MINICPM["slots"], MINICPM["page"]
-    pool = (MINICPM["pages"], page, hkv * d)
+    pool = (MINICPM["layers"], MINICPM["pages"], page, hkv * d)
     n = MINICPM["max_len"] // page
     _compile(
         paged_decode_attention_fwd,
@@ -99,12 +102,13 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, hkv, groups, d):
         _sds(one_chip, pool, jnp.bfloat16),
         _sds(one_chip, (b, n), jnp.int32),
         _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (), jnp.int32),
     )
 
 
 def test_paged_kv_append_compiles_for_v5e(one_chip):
     b, hkv, d, page = MINICPM["slots"], 36, 64, MINICPM["page"]
-    pool = (MINICPM["pages"], page, hkv * d)
+    pool = (MINICPM["layers"], MINICPM["pages"], page, hkv * d)
     n = MINICPM["max_len"] // page
     _compile(
         paged_kv_append_fwd,
@@ -114,20 +118,25 @@ def test_paged_kv_append_compiles_for_v5e(one_chip):
         _sds(one_chip, pool, jnp.bfloat16),
         _sds(one_chip, (b, n), jnp.int32),
         _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (), jnp.int32),
     )
 
 
 def test_paged_latent_decode_kernel_compiles_for_v5e(one_chip):
     """DeepSeek-V2-Lite's latent decode: 16 heads over 640-lane rows in
-    pages of 16, 32 pages a grid step, 16 slots x 8192 tokens."""
+    pages of 16, 32 pages a grid step, 16 slots x 8192 tokens, one layer
+    of the 26 scanned layers' stacked pool."""
     c = DEEPSEEK
     n = c["max_len"] // c["page"]
     _compile(
-        lambda q, p, t, k: paged_latent_decode_fwd(q, p, t, k, c["latent"], 0.1),
+        lambda q, p, t, k, ly: paged_latent_decode_fwd(q, p, t, k, ly,
+                                                       c["latent"], 0.1),
         _sds(one_chip, (c["slots"], c["heads"], c["width"]), jnp.bfloat16),
-        _sds(one_chip, (c["pages"], c["page"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["layers"], c["pages"], c["page"], c["width"]),
+             jnp.bfloat16),
         _sds(one_chip, (c["slots"], n), jnp.int32),
         _sds(one_chip, (c["slots"],), jnp.int32),
+        _sds(one_chip, (), jnp.int32),
     )
 
 
@@ -136,9 +145,11 @@ def test_paged_latent_append_compiles_for_v5e(one_chip):
     _compile(
         paged_latent_append_fwd,
         _sds(one_chip, (c["slots"], c["width"]), jnp.bfloat16),
-        _sds(one_chip, (c["pages"], c["page"], c["width"]), jnp.bfloat16),
+        _sds(one_chip, (c["layers"], c["pages"], c["page"], c["width"]),
+             jnp.bfloat16),
         _sds(one_chip, (c["slots"], c["max_len"] // c["page"]), jnp.int32),
         _sds(one_chip, (c["slots"],), jnp.int32),
+        _sds(one_chip, (), jnp.int32),
     )
 
 
@@ -154,12 +165,13 @@ def test_tcmm_assign_compiles_for_v5e(one_chip, centroids):
     )
 
 
-def _minicpm_decode_step(one_chip, pages: int):
-    """The served decode step at MiniCPM-2B's published widths, bf16,
-    8 slots x 1024 tokens in pages of 16, compiled for one v5e.  The
-    described chip is not the default backend, so the platform check is
-    steered to the TPU branch by the calling test."""
-    cfg = get_arch("minicpm-2b")
+def _decode_step(one_chip, arch: str, sizes: dict, pages: int):
+    """The served decode step of ``arch`` at its published widths, bf16,
+    ``sizes``' slots x max_len tokens in pages of 16, compiled for one
+    v5e.  Returns the config, the cache's shapes and the compiled step.
+    The described chip is not the default backend, so the platform check
+    is steered to the TPU branch by the calling test."""
+    cfg = get_arch(arch)
     model = build_model(cfg, compute_dtype=jnp.bfloat16,
                         param_dtype=jnp.bfloat16)
 
@@ -169,12 +181,12 @@ def _minicpm_decode_step(one_chip, pages: int):
         )
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    spec = PagedSpec(num_pages=pages, page_size=MINICPM["page"])
+    spec = PagedSpec(num_pages=pages, page_size=sizes["page"])
     cache = on_chip(jax.eval_shape(
-        lambda: model.init_cache(MINICPM["slots"], MINICPM["max_len"],
+        lambda: model.init_cache(sizes["slots"], sizes["max_len"],
                                  paged=spec)
     ))
-    b = MINICPM["slots"]
+    b = sizes["slots"]
     compiled = make_decode_step(model).lower(
         params,
         _sds(one_chip, (b, 1), jnp.int32),
@@ -183,6 +195,11 @@ def _minicpm_decode_step(one_chip, pages: int):
         _sds(one_chip, (2,), jnp.uint32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return cfg, cache, compiled
+
+
+def _minicpm_decode_step(one_chip, pages: int):
+    cfg, _, compiled = _decode_step(one_chip, "minicpm-2b", MINICPM, pages)
     return cfg, compiled
 
 
@@ -206,7 +223,9 @@ def test_minicpm_decode_step_keeps_the_pool_row_major(one_chip,
     layer's pool slice is copied or transposed on its way to or from
     them.  A pool ``[.., P, page, Hkv, D]`` with D = 64 took the page
     axis as its minor-most dim instead, and every layer paid a transpose
-    of its slice each way around the kernels."""
+    of its slice each way around the kernels.  (That nothing slices a
+    layer's pool out at all is
+    ``test_decode_step_updates_the_page_pools_in_place``'s.)"""
     monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
     pages, page = 385, MINICPM["page"]
     cfg, compiled = _minicpm_decode_step(one_chip, pages)
@@ -241,25 +260,12 @@ def test_deepseek_full_width_decode_step_compiles_for_v5e(one_chip,
                                                           monkeypatch):
     """DeepSeek-V2-Lite's served decode step (27 layers, 8 of 64 experts
     held, bf16) over the benchmark's latent pool holds the latent
-    kernels, fits one chip with the pool twice (the step is not
-    donated), and takes every layer's pool row-major."""
+    kernels, fits one chip (the step donates the cache, so its output
+    pool is its input's buffer), and takes every layer's pool
+    row-major."""
     monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
-    c = DEEPSEEK
-    model = build_model(get_arch("deepseek-v2-lite"), compute_dtype=jnp.bfloat16,
-                        param_dtype=jnp.bfloat16)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
-
-    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    spec = PagedSpec(num_pages=c["pages"], page_size=c["page"])
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_cache(c["slots"], c["max_len"], paged=spec)))
-    b = c["slots"]
-    compiled = make_decode_step(model).lower(
-        params, _sds(one_chip, (b, 1), jnp.int32), cache,
-        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (2,), jnp.uint32),
-    ).compile()
+    _, _, compiled = _decode_step(one_chip, "deepseek-v2-lite", DEEPSEEK,
+                                  DEEPSEEK["pages"])
     text = compiled.as_text()
     assert "_paged_latent_decode_jit" in text and "_latent_append_jit" in text
     mem = compiled.memory_analysis()
@@ -272,6 +278,53 @@ def test_deepseek_full_width_decode_step_compiles_for_v5e(one_chip,
     for fmt in pools:
         m2m = fmt.layout.major_to_minor
         assert m2m == tuple(range(len(m2m))), m2m
+
+
+@pytest.mark.parametrize("arch,sizes,pages", [
+    pytest.param("minicpm-2b", MINICPM, 385, id="minicpm-385"),
+    pytest.param("deepseek-v2-lite", DEEPSEEK, DEEPSEEK["pages"],
+                 id="deepseek-5121"),
+])
+def test_decode_step_updates_the_page_pools_in_place(one_chip, monkeypatch,
+                                                     arch, sizes, pages):
+    """At the serving benchmark's pools, the decode step updates every
+    page pool in place: the scan carries the stacked pools and the
+    kernels index them by layer, so no op makes a layer's pool slice
+    ``[P, page, W]`` or a whole pool ``[L, P, page, W]`` by a copy,
+    transpose, dynamic-slice or dynamic-update-slice, fused or not (the
+    fusions are named for the op they do, as
+    ``dynamic-slice_bitcast_fusion``).  The step donates the cache: its
+    module aliases every ``*_pages`` parameter to an output, and the
+    aliased bytes hold at least one whole pool.  Sliced per layer, the
+    pools cost MiniCPM-2B's step about a third of its device time."""
+    monkeypatch.setattr(platform, "compiled_kernels", lambda: True)
+    cfg, cache, compiled = _decode_step(one_chip, arch, sizes, pages)
+    pools = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+             if str(path[-1].key).endswith("_pages")]
+    assert pools
+    width = pools[0].shape[-1]
+    pool_shape = re.compile(rf"\[(?:\d+,)?{pages},{sizes['page']},{width}\]")
+    moves = ("copy", "transpose", "dynamic-slice", "dynamic-update-slice")
+    op = re.compile(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w-]+)\(")
+    text = compiled.as_text()
+    moved = []
+    for line in text.splitlines():
+        m = op.match(line)
+        if m is None or not pool_shape.search(m.group(2)):
+            continue
+        name, kind = m.group(1), m.group(3)
+        if kind.startswith(moves) or any(mv in name for mv in moves):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+
+    header = text.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\(\s*(\d+), \{\}",
+                                          header.split("entry_computation")[0])}
+    params = re.findall(r"%(\S*_pages\S*) = \S+ parameter\((\d+)\)", text)
+    assert len(params) == len(pools), params
+    assert all(int(n) in aliased for _, n in params), (params, aliased)
+    one_pool = max(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= one_pool
 
 
 @pytest.mark.parametrize("arch", list_archs())
